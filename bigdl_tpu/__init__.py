@@ -25,3 +25,8 @@ __version__ = "0.1.0"
 
 from bigdl_tpu import nn, optim, dataset, parallel, utils, models, tensor  # noqa: F401,E402
 from bigdl_tpu import observability  # noqa: F401,E402
+from bigdl_tpu.utils import compile_cache as _compile_cache  # noqa: E402
+
+# jax's persistent compilation cache: honour JAX_COMPILATION_CACHE_DIR,
+# else a fixed directory inside the checkout (utils/compile_cache.py)
+_compile_cache.configure()

@@ -282,6 +282,7 @@ class PagedKVCache:
         self.num_pages, self.page_size = num_pages, page_size
         self.kv_heads, self.head_dim = kv_heads, head_dim
         self.num_layers = num_layers
+        self.dtype = jnp.dtype(dtype)
         shape = (num_pages, page_size, kv_heads, head_dim)
         self.kp = tuple(jnp.zeros(shape, dtype) for _ in range(num_layers))
         self.vp = tuple(jnp.zeros(shape, dtype) for _ in range(num_layers))
@@ -348,7 +349,8 @@ def _pool_kernel_supported(cache) -> bool:
     """auto-switch legality for this pool's geometry on the compiled
     TPU path (the interpret path has no constraints)."""
     from bigdl_tpu.ops.pallas.paged_attention import paged_supported
-    return paged_supported(cache.head_dim, cache.page_size)
+    return paged_supported(cache.head_dim, cache.page_size,
+                           cache.kv_heads, cache.dtype)
 
 
 def _attend_paged(q, kp, vp, table, q_start, upto, num_heads, scale,
@@ -830,6 +832,16 @@ class PagedStepCompilers:
                                    args)
         return compiled(*args)
 
+    def executables(self) -> list:
+        """``[(step name, statics dict, quick key, compiled)]`` for
+        every step built so far — the statics carry the RESOLVED
+        ``paged_kernel``, the compiled program its text."""
+        with self._lock:
+            compilers = dict(self._compilers)
+        return [(name, dict(skey), quick, compiled)
+                for (name, skey), sc in compilers.items()
+                for quick, compiled in sc.executables().items()]
+
     @property
     def hits(self) -> int:
         return self.cache.hits if self.cache is not None else 0
@@ -1241,10 +1253,12 @@ def speculative_generate(model, draft_model, prompts, *,
     def _both_supported():
         from bigdl_tpu.ops.pallas.paged_attention import \
             dense_cache_supported
-        dims = (t_params["0"]["tok"].shape[1] // t_meta["num_heads"],
-                d_params["0"]["tok"].shape[1] // d_meta["num_heads"])
-        return all(dense_cache_supported(hd, max_len_eff)
-                   for hd in dims)
+        return all(
+            dense_cache_supported(
+                p["0"]["tok"].shape[1] // m["num_heads"], max_len_eff,
+                m.get("num_kv_heads") or m["num_heads"],
+                activation_dtype())
+            for p, m in ((t_params, t_meta), (d_params, d_meta)))
 
     kernel = _resolve_paged_kernel(paged_kernel, _both_supported)
     out, acc, proposed, rounds = _speculative_impl(

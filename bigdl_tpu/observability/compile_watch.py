@@ -114,8 +114,6 @@ def executable_stats(executable) -> dict:
         cost = executable.cost_analysis()
     except Exception:
         cost = None
-    if isinstance(cost, (list, tuple)):     # older jax returns [dict]
-        cost = cost[0] if cost else None
     if cost:
         for key, name in (("flops", "flops"),
                           ("bytes accessed", "bytes_accessed")):

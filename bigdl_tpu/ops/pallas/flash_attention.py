@@ -199,6 +199,7 @@ def _fwd(q, k, v, scale, causal, interpret):
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return o, lse
 
@@ -312,6 +313,7 @@ def _bwd(scale, causal, interpret, res, g):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(q, k, v, g, lse, delta)
 
     # dk/dv: grid walks q blocks innermost for each k block
@@ -331,6 +333,7 @@ def _bwd(scale, causal, interpret, res, g):
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dkdv",
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 

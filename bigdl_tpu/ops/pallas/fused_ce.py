@@ -42,6 +42,13 @@ logger = logging.getLogger("bigdl_tpu.ops")
 # (tokens, vocab, device kind) wins when one exists and is legal.
 _T_BLOCKS = (512, 256, 128)
 _V_BLOCKS = (1024, 512, 256, 128)
+# Mosaic's default scoped-VMEM limit (16 MiB of the v5e's 128) refuses
+# the 512x1024 menu tile from D=1024 up (the dW kernel alone holds
+# double-buffered W and dW tiles plus an f32 accumulator); with this
+# limit the menu compiles up to _MAX_ROW_BYTES per feature row (bf16
+# D=4096, f32 D=2048 — compiled for the v5e, PR 21)
+_VMEM_LIMIT_BYTES = 64 * 2 ** 20
+_MAX_ROW_BYTES = 8192
 
 
 def _pick(n, menu):
@@ -69,12 +76,13 @@ def _pick_tiles(n: int, v: int) -> tuple[int, int]:
 def _tiles_ok(h, w) -> bool:
     return (h.shape[0] % _T_BLOCKS[-1] == 0
             and w.shape[0] % _V_BLOCKS[-1] == 0
-            and h.shape[1] % 128 == 0)
+            and h.shape[1] % 128 == 0
+            and h.shape[1] * jnp.dtype(h.dtype).itemsize <= _MAX_ROW_BYTES)
 
 
 def linear_ce_supported(h, w) -> bool:
     """TPU backend with tile-divisible token count / vocab and a
-    lane-tileable feature dim."""
+    lane-tileable feature dim narrow enough for the tiles to fit VMEM."""
     return jax.default_backend() == "tpu" and _tiles_ok(h, w)
 
 
@@ -190,6 +198,9 @@ def _forward(h, w, b, targets, interpret):
                    jax.ShapeDtypeStruct((n, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bt, 1), jnp.float32)] * 3,
         interpret=interpret,
+        name="fused_ce_fwd",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
     )(h, w, b.reshape(1, v), targets.reshape(n, 1).astype(jnp.int32))
     return nll[:, 0], lse
 
@@ -210,6 +221,7 @@ def _linear_ce_bwd(interpret, res, g):
     g2 = g.reshape(n, 1).astype(jnp.float32)
     t2 = targets.reshape(n, 1).astype(jnp.int32)
     b2 = b.reshape(1, v)
+    params = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
     dh = pl.pallas_call(
         functools.partial(_dh_kernel, nv=nv, bt=bt, bv=bv),
@@ -219,6 +231,8 @@ def _linear_ce_bwd(interpret, res, g):
         out_shape=jax.ShapeDtypeStruct(h.shape, h.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_dh",
+        compiler_params=params,
     )(h, w, b2, t2, lse, g2)
 
     # vocab tiles outer, token tiles inner — dW/db accumulate over tokens
@@ -238,6 +252,8 @@ def _linear_ce_bwd(interpret, res, g):
         scratch_shapes=[pltpu.VMEM((bv, d), jnp.float32),
                         pltpu.VMEM((1, bv), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_dw",
+        compiler_params=params,
     )(h, w, b2, t2, lse, g2)
     return dh, dw, db.reshape(v), None
 

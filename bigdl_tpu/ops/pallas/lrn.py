@@ -142,7 +142,7 @@ def _bwd_kernel(g_ref, x_ref, band_ref, adj_ref, dx_ref, *,
     dx_ref[...] = dx.astype(dx_ref.dtype)
 
 
-def _call(kernel, args, bands, hw, c, n, dtype, interpret):
+def _call(name, kernel, args, bands, hw, c, n, dtype, interpret):
     ht = _pick_hw_tile(c, n)
     grid = (pl.cdiv(hw, ht),)
     spec = pl.BlockSpec((ht, c, n), lambda t: (t, 0, 0))
@@ -154,6 +154,7 @@ def _call(kernel, args, bands, hw, c, n, dtype, interpret):
         in_specs=[spec] * len(args) + [band_spec] * len(bands),
         out_specs=spec,
         interpret=interpret,
+        name=name,
     )(*args, *[jnp.asarray(b) for b in bands])
 
 
@@ -177,7 +178,7 @@ def lrn(x, size=5, alpha=1.0, beta=0.75, k=1.0, interpret=False,
     n, c, h, w = x.shape
     kern = functools.partial(_fwd_kernel, size=size, alpha=alpha, beta=beta,
                              k=k, relu=relu)
-    y = _call(kern, (_to_view(x),), (_band_matrix(c, size),),
+    y = _call("lrn_fwd", kern, (_to_view(x),), (_band_matrix(c, size),),
               h * w, c, n, x.dtype, interpret)
     return _from_view(y, x.shape)
 
@@ -190,7 +191,7 @@ def _lrn_bwd(size, alpha, beta, k, interpret, relu, x, g):
     n, c, h, w = x.shape
     kern = functools.partial(_bwd_kernel, size=size, alpha=alpha, beta=beta,
                              k=k, relu=relu)
-    dx = _call(kern, (_to_view(g), _to_view(x)),
+    dx = _call("lrn_bwd", kern, (_to_view(g), _to_view(x)),
                (_band_matrix(c, size), _band_matrix(c, size, adjoint=True)),
                h * w, c, n, x.dtype, interpret)
     return (_from_view(dx, x.shape),)

@@ -89,15 +89,27 @@ def _pick_tiles(t: int, g: int, s: int, d: int) -> tuple[int, int]:
     return _static_tiles(t, g)
 
 
-def paged_supported(head_dim: int, page_size: int) -> bool:
-    """Compiled-kernel constraints for the auto switch: TPU backend, a
-    head dim Mosaic tiles cleanly (multiple of 64, like flash), and a
-    page size on the f32 sublane grid. ``interpret=True`` has no such
-    constraints — the interpreter runs any geometry (the CPU parity
-    path)."""
+def paged_supported(head_dim: int, page_size: int, kv_heads: int,
+                    dtype) -> bool:
+    """Whether the compiled kernel accepts this pool geometry — the
+    auto switch picks "pallas" exactly where this is true and the
+    dense view everywhere else. What Mosaic on the v5e accepts
+    (compiled and checked against the dense view on the chip, PR 21):
+
+    - bf16 and f32 pools (it compiles no float16);
+    - a kv head is a ``head_dim``-wide lane block of the
+      (num_pages, S, KV*D) pool view, so more than one kv head needs
+      ``head_dim % 128 == 0`` — a single head IS the whole lane dim and
+      any width passes;
+    - any page that fills at least one 32-bit sublane row: one row of
+      f32, two of bf16. Pages need NOT be whole (8|16, 128) tiles.
+
+    ``interpret=True`` has no such constraints (the CPU parity path)."""
+    dtype = jnp.dtype(dtype)
     return (jax.default_backend() == "tpu"
-            and head_dim % 64 == 0
-            and page_size % 8 == 0)
+            and dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+            and (kv_heads == 1 or head_dim % 128 == 0)
+            and page_size * dtype.itemsize >= 4)
 
 
 def dense_cache_page_size(max_len: int, cap: int = 128,
@@ -111,10 +123,12 @@ def dense_cache_page_size(max_len: int, cap: int = 128,
                  if max_len % s == 0), max_len)
 
 
-def dense_cache_supported(head_dim: int, max_len: int) -> bool:
+def dense_cache_supported(head_dim: int, max_len: int, kv_heads: int,
+                          dtype) -> bool:
     """Auto-switch legality for the dense-cache (ragged/speculative)
     view on the compiled path."""
-    return paged_supported(head_dim, dense_cache_page_size(max_len))
+    return paged_supported(head_dim, dense_cache_page_size(max_len),
+                           kv_heads, dtype)
 
 
 def _kernel(table_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
@@ -134,8 +148,8 @@ def _kernel(table_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
     def _compute():
         d = q_ref.shape[-1]
         q = q_ref[0].reshape(bt * gp, d)            # (R, D)
-        k = k_ref[0, :, 0, :]                       # (S, D)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0]                                # (S, D)
+        v = v_ref[0]
         # matmuls stay in the pool dtype (bf16 full-rate on the MXU),
         # f32 accumulation — the flash kernel's round-3 lesson
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -209,15 +223,16 @@ def paged_attention(q, kp, vp, table, q_start, *, scale=None,
 
     def kvmap(bk, ti, j, table_ref, qstart_ref):
         # the logical->physical hop: one scalar-prefetched table probe
-        # per block, never a gathered view
-        return (table_ref[bk // kv, j], 0, bk % kv, 0)
+        # per block, never a gathered view; the kv head is a D-wide
+        # lane block of the (num_pages, S, KV*D) pool view
+        return (table_ref[bk // kv, j], 0, bk % kv)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b * kv, t // bt, p),
         in_specs=[pl.BlockSpec((1, bt, gp, d), qmap),
-                  pl.BlockSpec((1, s, 1, d), kvmap),
-                  pl.BlockSpec((1, s, 1, d), kvmap)],
+                  pl.BlockSpec((1, s, d), kvmap),
+                  pl.BlockSpec((1, s, d), kvmap)],
         out_specs=pl.BlockSpec((1, bt, gp, d), qmap),
         scratch_shapes=[pltpu.VMEM((bt * gp, 1), jnp.float32),
                         pltpu.VMEM((bt * gp, 1), jnp.float32),
@@ -228,7 +243,9 @@ def paged_attention(q, kp, vp, table, q_start, *, scale=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * kv, t, gp, d), jnp.float32),
         interpret=interpret,
-    )(table.astype(jnp.int32), q_start.astype(jnp.int32), qf, kp, vp)
+        name="paged_attention",
+    )(table.astype(jnp.int32), q_start.astype(jnp.int32), qf,
+      kp.reshape(n_pool, s, kv * d), vp.reshape(n_pool, s, kv * d))
     return (out.reshape(b, kv, t, gp, d)[:, :, :, :g]
             .transpose(0, 2, 1, 3, 4).reshape(b, t, h, d))
 

@@ -44,6 +44,26 @@ logger = logging.getLogger("bigdl_tpu.optim")
 __all__ = ["DistriOptimizer"]
 
 
+class _UnderMesh:
+    """A jitted function traced under jax's ambient mesh
+    (``jax.set_mesh``): that is where a Pallas kernel dispatch learns
+    it must run per shard (ops/pallas/per_shard.py). Only the trace
+    needs it, so the context is entered around ``lower`` and around a
+    direct call — never around the training loop, whose eager ops would
+    each recompile under the changed trace context."""
+
+    def __init__(self, jit_fn, mesh):
+        self.jit_fn, self.mesh = jit_fn, mesh
+
+    def lower(self, *args):
+        with jax.set_mesh(self.mesh):
+            return self.jit_fn.lower(*args)
+
+    def __call__(self, *args):
+        with jax.set_mesh(self.mesh):
+            return self.jit_fn(*args)
+
+
 class DistriOptimizer(Optimizer):
     """(reference optim/DistriOptimizer.scala)"""
 
@@ -481,9 +501,10 @@ class DistriOptimizer(Optimizer):
         # collective accounting reads the first executable's HLO
         from bigdl_tpu.tuning.aot_cache import StepCompiler
         step_pipeline = StepCompiler(
-            jit_step, name="distri_train_step",
+            _UnderMesh(jit_step, mesh), name="distri_train_step",
             cache=self._aot_cache() or False, mesh=mesh,
             donate_argnums=(0, 1, 2), extra=self._step_key_extra())
+        self.step_compiler = step_pipeline
 
         def eval_apply(params, mstate, data):
             if self.input_transform is not None:
@@ -506,10 +527,10 @@ class DistriOptimizer(Optimizer):
             eval_fn = local_sharded_eval(eval_apply)
         else:
             from bigdl_tpu.optim.validator import _padded_eval
-            jit_eval = jax.jit(eval_apply,
-                               in_shardings=(eval_param_shard, repl,
-                                             batch_shard),
-                               out_shardings=batch_shard)
+            jit_eval = _UnderMesh(
+                jax.jit(eval_apply,
+                        in_shardings=(eval_param_shard, repl, batch_shard),
+                        out_shardings=batch_shard), mesh)
             # params stay in their training placement (param_shard may be
             # ZeRO-sharded) — only the batch is padded/placed/trimmed
             eval_fn = _padded_eval(jit_eval, batch_shard, n_shards)

@@ -197,6 +197,10 @@ class Optimizer:
         # "env" = $BIGDL_TPU_AOT_CACHE_DIR when set, else off;
         # set_aot_cache() overrides either way
         self._aot_cache_cfg = "env"
+        # the run's lower -> compile -> cache pipeline (set by
+        # _optimize_impl); its executables() are the compiled train
+        # steps, e.g. to read the optimized program text
+        self.step_compiler = None
         # overlapped input pipeline (dataset/prefetch.py): batches are
         # assembled + device-placed on a worker thread, `depth` ahead of
         # the loop; 0 = the synchronous path (docs/PERFORMANCE.md)
@@ -1208,6 +1212,7 @@ class LocalOptimizer(Optimizer):
             name="local_train_step", cache=self._aot_cache() or False,
             donate_argnums=(0, 1, 2), extra=self._step_key_extra(),
             count_calls=True)
+        self.step_compiler = step_pipeline
 
         def eval_apply(params, mstate, data):
             if self.input_transform is not None:

@@ -19,16 +19,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-try:                                   # jax >= 0.8
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-except ImportError:                    # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map as _shard_map
 
 from bigdl_tpu.parallel.engine import get_mesh
+
+
+def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
+    """``jax.shard_map`` under the repo's ``check_rep`` spelling."""
+    return _shard_map(f, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=check_rep)
+
 
 __all__ = ["all_reduce", "all_gather", "reduce_scatter", "ppermute",
            "all_to_all", "psum_tree", "pmean_tree",

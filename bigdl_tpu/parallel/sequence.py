@@ -25,6 +25,8 @@ Shapes follow (batch, seq, heads, head_dim) throughout.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
@@ -77,7 +79,15 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
                 f"seq % 128 == 0, head_dim % 64 == 0, zero offsets when "
                 f"causal)")
         if supported:
-            return flash_attention(q, k, v, causal=causal, scale=scale)
+            from bigdl_tpu.ops.pallas.per_shard import kernel_shards
+            kernel = functools.partial(flash_attention, causal=causal,
+                                       scale=scale)
+            # under a multi-device mesh the kernel runs once per
+            # (batch, head) shard — jax refuses to partition it itself
+            shards = kernel_shards(q.shape, head_dim=2)
+            if shards is None:
+                return kernel(q, k, v)
+            return shards.run(kernel, q, k, v)
     f32 = jnp.float32
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(f32), k.astype(f32)) * scale

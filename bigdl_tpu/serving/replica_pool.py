@@ -75,6 +75,10 @@ class Replica:
         self._poll = float(poll_interval)
         self._state = ACTIVE
         self._stop = False
+        # the last exception batcher.step raised on the driver thread;
+        # Router.wait_all raises it instead of timing out on requests
+        # a failing step will never finish
+        self.step_error: BaseException | None = None
         self._wake = threading.Event()
         self._health = health if health is not None else default_health()
         self._health.register(f"serving_replica_{self.name}",
@@ -100,11 +104,13 @@ class Replica:
                 with self.lock:
                     if not self._stop and not self.batcher.idle:
                         stepped = self.batcher.step(self._burst)
-            except Exception:
-                # a crashing step must not silently kill the driver —
-                # log and keep serving (the health check reports the
-                # batcher's own admitting/saturated verdict)
+            except Exception as e:
+                # a crashing step must not kill the driver, and must
+                # not pass for slowness either: keep it where the
+                # router's wait_all raises it
                 log.exception("replica %s step failed", self.name)
+                with self.lock:
+                    self.step_error = e
                 stepped = 0
             if not stepped:
                 # idle, or queued work that cannot admit yet: park

@@ -631,13 +631,22 @@ class Router:
             return len(self._pending)
 
     def wait_all(self, timeout: float = 120.0) -> None:
-        """Block until every accepted request has completed."""
+        """Block until every accepted request has completed. Raises
+        ``RuntimeError`` (chained to the replica's exception) as soon
+        as a replica's step has failed while requests are outstanding —
+        those requests would otherwise only ever time out."""
         deadline = time.monotonic() + timeout
         while True:
             with self._state_lock:
                 busy = len(self._inflight) + len(self._pending)
             if not busy:
                 return
+            for rep in self.pool:
+                err = rep.step_error
+                if err is not None:
+                    raise RuntimeError(
+                        f"replica {rep.name} step failed with {busy} "
+                        f"requests outstanding: {err!r}") from err
             if time.monotonic() > deadline:
                 raise TimeoutError(
                     f"{busy} requests still outstanding after "

@@ -71,23 +71,12 @@ def fingerprint() -> dict:
     upgrades, backends, or chip generations)."""
     import jax
     import jaxlib
-    try:
-        d = jax.devices()[0]
-        backend = d.platform
-        kind = str(getattr(d, "device_kind", "") or backend)
-    except Exception:
-        backend, kind = "uninitialized", "unknown"
+    # a backend that cannot be read raises here, in the process that is
+    # about to compile for it — it is never a cache key
+    d = jax.devices()[0]
     return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
-            "backend": backend, "device_kind": kind,
-            "processes": _process_count()}
-
-
-def _process_count() -> int:
-    try:
-        import jax
-        return int(jax.process_count())
-    except Exception:
-        return 1
+            "backend": d.platform, "device_kind": d.device_kind,
+            "processes": int(jax.process_count())}
 
 
 def mesh_descriptor(mesh) -> tuple | None:
@@ -171,8 +160,15 @@ class AOTCache:
             payload, in_tree, out_tree = (blob["payload"],
                                           blob["in_tree"],
                                           blob["out_tree"])
+            import jax
             from jax.experimental import serialize_executable as se
-            compiled = se.deserialize_and_load(payload, in_tree, out_tree)
+            # load onto the devices the executable was compiled for;
+            # the default is every device of the backend, which a
+            # one-device executable rejects at its first call
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in blob["device_ids"]])
         except Exception as e:
             # the backstop: a bad blob is a miss, not a crash — fresh
             # compilation follows and overwrites it
@@ -195,9 +191,11 @@ class AOTCache:
         try:
             from jax.experimental import serialize_executable as se
             payload, in_tree, out_tree = se.serialize(compiled)
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
             blob = {"payload": payload, "in_tree": in_tree,
-                    "out_tree": out_tree, "meta": dict(meta or {},
-                                                       name=name)}
+                    "out_tree": out_tree, "device_ids": device_ids,
+                    "meta": dict(meta or {}, name=name)}
             tmp = self._file(key) + f".tmp.{os.getpid()}"
             with open(tmp, "wb") as f:
                 pickle.dump(blob, f, protocol=pickle.HIGHEST_PROTOCOL)
@@ -297,6 +295,10 @@ class StepCompiler:
         except Exception:
             pass
         return compiled, not loaded
+
+    def executables(self) -> dict:
+        """``{quick_key: compiled}`` built so far (a copy)."""
+        return dict(self._executables)
 
     def __len__(self):
         return len(self._executables)

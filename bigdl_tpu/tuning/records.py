@@ -13,7 +13,7 @@ File format (JSON, one file for the whole fleet to share):
 
     {"version": 1,
      "records": {
-       "flash_attention|TPU v5e|skv=4096,sq=4096": {
+       "flash_attention|TPU v5 lite|skv=4096,sq=4096": {
          "config": {"bq": 512, "bk": 1024},
          "score": 0.00132, "meta": {"iters": 5}}}}
 
@@ -49,15 +49,11 @@ _VERSION = 1
 
 
 def device_kind() -> str:
-    """Accelerator name the records are keyed by (e.g. ``TPU v5e``).
-    Best-effort: an uninitializable backend reports ``unknown`` rather
-    than failing the lookup path."""
-    try:
-        import jax
-        d = jax.devices()[0]
-        return str(getattr(d, "device_kind", None) or d.platform)
-    except Exception:
-        return "unknown"
+    """Accelerator name the records are keyed by (e.g. ``TPU v5
+    lite``). A backend that cannot be read raises here — the caller is
+    about to trace a kernel for it."""
+    import jax
+    return jax.devices()[0].device_kind
 
 
 def signature_str(sig) -> str:

@@ -624,6 +624,19 @@ class _FlowWalker:
         pass
 
 
+def _bound_names(target):
+    """Names an assignment target BINDS. ``obj.attr = v`` and
+    ``obj[i] = v`` bind nothing: they neither make ``obj`` a device
+    value nor clear it."""
+    if isinstance(target, ast.Name):
+        yield target.id
+    elif isinstance(target, (ast.Tuple, ast.List)):
+        for elt in target.elts:
+            yield from _bound_names(elt)
+    elif isinstance(target, ast.Starred):
+        yield from _bound_names(target.value)
+
+
 class _SyncWalker(_FlowWalker):
     """JX1 — host syncs on device values.
 
@@ -706,12 +719,11 @@ class _SyncWalker(_FlowWalker):
 
     def assign_target(self, target, value):
         is_dev = self._is_device_expr(value)
-        for node in ast.walk(target) if target is not None else ():
-            if isinstance(node, ast.Name):
-                if is_dev:
-                    self.device.add(node.id)
-                else:
-                    self.device.discard(node.id)
+        for name in _bound_names(target):
+            if is_dev:
+                self.device.add(name)
+            else:
+                self.device.discard(name)
 
     def snapshot(self):
         return set(self.device)

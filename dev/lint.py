@@ -26,7 +26,7 @@ Both analyzer passes share one suppression syntax
 too, so the baseline only ever shrinks. See docs/STATIC_ANALYSIS.md.
 
 Run: ``python dev/lint.py`` (exit 1 on findings). Scans bigdl_tpu/,
-tests/, dev/, scripts/, bench.py, __graft_entry__.py. ``--rules JX``
+tests/, dev/, scripts/, bench.py, chip_smoke.py. ``--rules JX``
 or ``--rules TS`` runs one analyzer family alone (the classic
 E/F/W/B checks always run).
 
@@ -49,7 +49,7 @@ from analysis import raceguard  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TARGETS = ["bigdl_tpu", "tests", "dev", "scripts", "bench.py",
-           "__graft_entry__.py"]
+           "chip_smoke.py"]
 MAX_LEN = 79
 
 

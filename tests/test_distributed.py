@@ -320,7 +320,7 @@ def test_spatial_bn_cross_device_unbiased_running_var():
         _, st = sbn.apply(sbn.params, sbn.state, xs, training=True)
         return st["running_var"]
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     with mesh:
         rv = shard_map(body, mesh=mesh, in_specs=P("data"),
                        out_specs=P())(jnp.asarray(xg))

@@ -1,0 +1,171 @@
+"""Cross-lowering of every live Pallas kernel to the TPU from the CPU.
+
+``jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",))`` runs the
+Pallas TPU lowering without a chip, so a block shape the lowering refuses
+(the last two block dims must be divisible by (8, 128) or span the array)
+fails here in seconds. This does NOT replace the Mosaic compile on the
+chip: Mosaic itself — VMEM limits, unsupported reshapes, unaligned slices —
+only runs when ``chip_smoke.py`` compiles the same kernels on a TPU. The
+geometries are exactly the smoke's.
+"""
+import itertools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from bigdl_tpu.ops.pallas import lrn as plrn
+from bigdl_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                  flash_supported)
+from bigdl_tpu.ops.pallas.fused_ce import _linear_ce, linear_ce_supported
+from bigdl_tpu.ops.pallas.paged_attention import (dense_cache_attention,
+                                                  dense_cache_supported,
+                                                  paged_attention,
+                                                  paged_supported)
+
+BF16 = jnp.bfloat16
+
+
+def _sds(shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _lowers(fn, *args) -> str:
+    """StableHLO text of ``fn`` lowered for the TPU (raises where the
+    Pallas TPU lowering refuses)."""
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def _scalar_grads(fn, n_args):
+    """fwd + bwd in one program: sum(fn) and its grads w.r.t. every
+    array (the value keeps a forward kernel alive whose backward
+    recomputes instead of reading it)."""
+    return jax.value_and_grad(lambda *a: fn(*a).astype(jnp.float32).sum(),
+                              argnums=tuple(range(n_args)))
+
+
+# linear_cross_entropy gates the compiled kernel on the backend; lower
+# the custom-vjp kernel entry itself (interpret=False), fwd + bwd
+_CE_GRADS = jax.value_and_grad(
+    lambda h, w, b, t: _linear_ce(h, w, b, t, False).sum(),
+    argnums=(0, 1, 2))
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The ``*_supported`` predicates as they answer on a TPU."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _paged_args(b, t, h, kv, d, s, pages_per_seq, dtype=BF16):
+    n_pool = b * pages_per_seq + 1
+    return (_sds((b, t, h, d), dtype), _sds((n_pool, s, kv, d), dtype),
+            _sds((n_pool, s, kv, d), dtype),
+            _sds((b, pages_per_seq), jnp.int32), _sds((b,), jnp.int32))
+
+
+class TestSmokeGeometriesLower:
+    @pytest.mark.parametrize("shape", [(2, 2048, 4, 128),
+                                       (2, 320, 4, 128)])
+    def test_flash_fwd_bwd(self, shape):
+        fn = _scalar_grads(
+            lambda q, k, v: flash_attention(q, k, v, causal=True), 3)
+        text = _lowers(fn, *[_sds(shape)] * 3)
+        # forward, dq and fused dk/dv
+        assert text.count("tpu_custom_call") == 3
+
+    def test_lrn_fwd_bwd(self):
+        fn = _scalar_grads(lambda x: plrn.lrn(x, 5, 1e-4, 0.75, 1.0), 1)
+        assert _lowers(fn, _sds((256, 64, 56, 56))).count(
+            "tpu_custom_call") == 2
+
+    def test_fused_ce_fwd_bwd(self):
+        n, d, v = 8192, 1024, 32768
+        text = _lowers(_CE_GRADS, _sds((n, d)), _sds((v, d)), _sds((v,)),
+                       _sds((n,), jnp.int32))
+        # forward, dh and dw/db
+        assert text.count("tpu_custom_call") == 3
+
+    @pytest.mark.parametrize("kv,t,s", list(itertools.product(
+        (1, 8), (1, 64), (16, 128))))
+    def test_paged_attention(self, kv, t, s):
+        args = _paged_args(4, t, 8, kv, 128, s, 16)
+        assert _lowers(paged_attention, *args).count(
+            "tpu_custom_call") == 1
+
+
+class TestPredicatesMatchTheLowering:
+    """Whatever a ``*_supported`` predicate accepts on a TPU lowers; what
+    the lowering refuses reads as unsupported."""
+
+    def test_paged_accepted_geometries_lower(self, on_tpu):
+        seen = 0
+        for dtype, kv, d, s in itertools.product(
+                (BF16, jnp.float32), (1, 8), (64, 128, 256), (2, 12, 128)):
+            if not paged_supported(d, s, kv, dtype):
+                continue
+            seen += 1
+            _lowers(paged_attention,
+                    *_paged_args(2, 8, 8, kv, d, s, 4, dtype))
+        assert seen == 30      # all but KV=8 at D=64
+
+    def test_paged_refused_geometries_read_unsupported(self, on_tpu):
+        # more than one kv head needs a 128-multiple head dim: the kv
+        # head is a lane block of the (num_pages, S, KV*D) pool view
+        assert not paged_supported(64, 16, 4, BF16)
+        assert not paged_supported(64, 16, 8, jnp.float32)
+        with pytest.raises(ValueError, match="last two dimensions"):
+            _lowers(paged_attention, *_paged_args(2, 1, 8, 4, 64, 16, 4))
+        # one kv head spans the lane dim at any width
+        assert paged_supported(64, 16, 1, BF16)
+        # a page fills at least one 32-bit sublane row; it need not be a
+        # whole tile (bf16 pages of 8 rows run on the chip)
+        assert paged_supported(128, 8, 8, BF16)
+        assert paged_supported(128, 1, 8, jnp.float32)
+        assert not paged_supported(128, 1, 8, BF16)
+        # Mosaic on the v5e compiles no float16
+        assert not paged_supported(128, 16, 8, jnp.float16)
+
+    def test_dense_cache_view_follows_paged(self, on_tpu):
+        for m, kv, d in ((2048, 8, 128), (640, 1, 64), (96, 2, 128)):
+            ok = dense_cache_supported(d, m, kv, BF16)
+            args = (_sds((2, 1, 8, d)), _sds((2, m, kv, d)),
+                    _sds((2, m, kv, d)), _sds((2,), jnp.int32))
+            if ok:
+                _lowers(dense_cache_attention, *args)
+        assert dense_cache_supported(128, 2048, 8, BF16)
+        assert not dense_cache_supported(64, 2048, 4, BF16)
+
+    def test_flash_accepted_geometries_lower(self, on_tpu):
+        fn = _scalar_grads(
+            lambda q, k, v: flash_attention(q, k, v, causal=True), 3)
+        for s, d in itertools.product((128, 320, 640, 2048), (64, 128)):
+            q = _sds((1, s, 2, d))
+            assert flash_supported(q, q)
+            _lowers(fn, q, q, q)
+        assert not flash_supported(_sds((1, 100, 2, 64)),
+                                   _sds((1, 100, 2, 64)))
+        assert not flash_supported(_sds((1, 128, 2, 32)),
+                                   _sds((1, 128, 2, 32)))
+
+    def test_lrn_accepted_geometries_lower(self, on_tpu):
+        fn = _scalar_grads(lambda x: plrn.lrn(x, 5, 1e-4, 0.75, 1.0), 1)
+        for shape, dtype in (((256, 192, 56, 56), BF16),
+                             ((64, 64, 28, 28), BF16),
+                             ((128, 8, 14, 14), jnp.float32)):
+            x = _sds(shape, dtype)
+            assert plrn.lrn_supported(x)
+            _lowers(fn, x)
+        # bf16 packs 16 rows a sublane tile; a small batch leaves the
+        # lane axis mostly empty
+        assert not plrn.lrn_supported(_sds((256, 8, 14, 14), BF16))
+        assert not plrn.lrn_supported(_sds((32, 64, 28, 28), BF16))
+
+    def test_fused_ce_accepted_geometries_lower(self, on_tpu):
+        for n, d, v in ((256, 128, 512), (1024, 256, 4096)):
+            h, w = _sds((n, d)), _sds((v, d))
+            assert linear_ce_supported(h, w)
+            _lowers(_CE_GRADS, h, w, _sds((v,)), _sds((n,), jnp.int32))
+        assert not linear_ce_supported(_sds((100, 128)), _sds((512, 128)))
+        assert not linear_ce_supported(_sds((256, 96)), _sds((512, 96)))

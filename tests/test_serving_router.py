@@ -104,6 +104,7 @@ class TestEquivalence:
             # zero drops, zero duplicates
             assert sorted(res) == list(range(8))
             assert router.inflight_count == 0
+            assert all(rep.step_error is None for rep in pool)
             for i, p in enumerate(prompts):
                 np.testing.assert_array_equal(res[i], single[i],
                                               err_msg=f"req {i}")
@@ -476,6 +477,34 @@ class TestDrain:
                 np.testing.assert_array_equal(
                     res[i], _greedy(model, prompts[i]),
                     err_msg=f"req {i}")
+        finally:
+            router.close()
+            pool.close()
+
+
+class TestStepFailure:
+    def test_wait_all_raises_the_replicas_exception(self, model):
+        """A step that fails on the replica's driver thread must not
+        read as slowness: ``wait_all`` raises at once, chained to the
+        replica's own exception, instead of sitting out its timeout
+        and raising a bare TimeoutError."""
+        import time
+        health, reg, pool, router = _plane(model, n=1)
+        try:
+            boom = ValueError("Mosaic refused the block shape")
+
+            def failing_step(burst=None):
+                raise boom
+            pool["r0"].batcher.step = failing_step
+            router.submit("doomed", _prompts([5])[0])
+            t0 = time.monotonic()
+            with pytest.raises(RuntimeError,
+                               match="replica r0 step failed.*Mosaic "
+                                     "refused") as ei:
+                router.wait_all(timeout=60)
+            assert time.monotonic() - t0 < 10
+            assert ei.value.__cause__ is boom
+            assert pool["r0"].step_error is boom
         finally:
             router.close()
             pool.close()

@@ -421,6 +421,35 @@ class TestAOTCache:
         assert float(loaded(x, x)) == float(comp(x, x))
         assert cache.hits == 1 and cache.misses == 0
 
+    def test_loads_onto_the_executables_own_devices(self, tmp_path):
+        """With eight devices visible, a one-device executable (on a
+        device that is not the default one) and a four-device sharded
+        one both load onto the devices they were compiled for and run.
+        Loading onto every device of the backend — the library default
+        — made the first call fail with "Expected args ... to have 8
+        shards"."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        devs = jax.devices()
+        assert len(devs) == 8
+        cache = AOTCache(str(tmp_path))
+        x1 = jax.device_put(jnp.arange(8.0), devs[3])
+        one = jax.jit(lambda x: x * 2 + 1).lower(x1).compile()
+        sh = NamedSharding(Mesh(np.array(devs[2:6]), ("data",)),
+                           P("data"))
+        x4 = jax.device_put(jnp.arange(8.0), sh)
+        four = jax.jit(lambda x: x * 3, in_shardings=sh,
+                       out_shardings=sh).lower(x4).compile()
+        for name, comp, x in (("one", one, x1), ("four", four, x4)):
+            key = cache_key(name, "sig", fp=_FP)
+            assert cache.store(key, comp, name=name)
+            loaded = cache.load(key, name=name)
+            assert loaded is not None
+            out = loaded(x)
+            np.testing.assert_array_equal(np.asarray(out),
+                                          np.asarray(comp(x)))
+            assert out.sharding.device_set == x.sharding.device_set
+        assert cache.hits == 2 and cache.misses == 0
+
     def test_absent_and_corrupt_are_counted_misses(self, tmp_path):
         from bigdl_tpu.observability.compile_watch import CompileWatch
         from bigdl_tpu.observability.registry import MetricRegistry
