@@ -18,13 +18,20 @@ __all__ = ["Sequential", "Concat", "ConcatTable", "ParallelTable", "Bottle",
 
 
 class Sequential(Container):
-    """Chain children (reference nn/Sequential.scala:28-52)."""
+    """Chain children (reference nn/Sequential.scala:28-52). Each child
+    runs under a ``jax.named_scope`` — its ``set_name`` name, else
+    ``<index>_<ClassName>`` — so a profiler trace of a compiled step
+    names device operations by module (``jvp(model)/block_3/...``):
+    the reference's per-module forward/backward time in the form a
+    compiled step can give it."""
 
     def apply(self, params, state, x, *, training=False, rng=None):
         new_state = {}
         for i, m in enumerate(self.modules):
-            x, s = m.apply(params[str(i)], state[str(i)], x,
-                           training=training, rng=_fold(rng, i))
+            # never get_name()'s default: it holds id(self)
+            with jax.named_scope(m._name or f"{i}_{type(m).__name__}"):
+                x, s = m.apply(params[str(i)], state[str(i)], x,
+                               training=training, rng=_fold(rng, i))
             new_state[str(i)] = s
         return x, new_state
 

@@ -17,25 +17,183 @@ drain, ``np.asarray(tokens)``), pass ``host_sync="why"`` to
 trace instead of an invisible stall. dev/lint.py enforces that this
 package never imports jax at module top level.
 
-A process-wide tracer (disabled by default — disabled spans are a
-single attribute check) sits behind module-level ``span`` / ``instant``
-/ ``counter`` / ``enable`` / ``export`` so call sites just do::
+A process-wide tracer (disabled by default) sits behind module-level
+``span`` / ``instant`` / ``enable`` / ``export`` so call sites just do::
 
     from bigdl_tpu.observability import trace
     with trace.span("loss drain", host_sync="packed loss readback"):
         ...
+
+THE PROFILER'S CLOCK. Every span and instant is ALSO a
+``jax.profiler.TraceAnnotation`` named ``bigdl:<cat>:<name>`` (spaces as
+``_``: ``bigdl:host:loss_drain``, ``bigdl:serving:decode_burst``) with
+the span's scalar ``args`` as its stats — whether or not this tracer is
+enabled. An annotation is a no-op unless a profiler session is running
+(``Optimizer.set_profiler``, the benchmark's traced run), so it needs no
+switch; inside one, the program's spans sit on ``/host:CPU`` on the same
+clock as the device's operations, nested per thread. jax is imported on
+first use, never at module import.
 """
 from __future__ import annotations
 
 import contextlib
 import json
+import logging
 import os
+import re
 import threading
 import time
 
-__all__ = ["Tracer", "get_tracer", "set_tracer", "enable", "disable",
-           "enabled", "span", "instant", "counter", "host_sync",
-           "export", "to_dict", "clear"]
+__all__ = ["Tracer", "get_tracer", "enable", "disable", "enabled",
+           "span", "instant", "host_sync", "export", "to_dict", "clear",
+           "ProgramScopes"]
+
+_annotation_cls = None
+
+
+class _NoAnnotation(contextlib.nullcontext):
+    """Stands in for the profiler annotation where jax is not installed
+    (the package's host-only contract)."""
+
+    def __init__(self, label: str, **stats):
+        super().__init__()
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return False
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation``, imported on first use."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:       # a host-only process without jax
+            TraceAnnotation = _NoAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
+def _annotation(name: str, cat: str, args: dict):
+    """The span as a profiler annotation. Outside a profiler session
+    that is an empty context manager, and the stats are not even
+    built."""
+    cls = _annotation_class()
+    label = f"bigdl:{cat}:{name.replace(' ', '_')}"
+    if args and cls.is_enabled():
+        return cls(label, **{k: v for k, v in args.items()
+                             if isinstance(v, (bool, int, float, str))})
+    return cls(label)
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%?([\w.\-]+) = .*? ([a-z][\w\-]*)\(")
+_HLO_CALLED = re.compile(r" (?:calls|to_apply)=%?([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r' metadata=\{[^}]*op_name="([^"]*)"')
+#: instructions that never run as a device operation of their own
+_HLO_NO_OP = frozenset({"parameter", "get-tuple-element", "tuple",
+                        "constant", "bitcast"})
+
+
+def _program_scopes(hlo_text: str) -> dict:
+    """``{"program": <module name>, "scopes": {op_name: [instruction
+    names]}, "inside": {scope: [instruction names]}}`` of a compiled
+    program's text (``compiled.as_text()``).
+
+    The TPU's profiler names a device operation by its HLO instruction
+    and carries nothing of ``jax.named_scope``; the compiled text does,
+    as each instruction's ``op_name`` (``jit(train_step)/transpose(
+    jvp(model))/block_3/...``). ``scopes`` is the join between the two.
+    Only instructions that run as operations of their own are kept: what
+    sits inside a fusion, a reduction's body or an asynchronous wrapper
+    runs under the calling instruction's name, which is its ROOT's
+    ``op_name``. ``inside`` says what else such an operation holds: the
+    scopes (``op_name`` less its last part, the primitive) of the
+    instructions in the computations it calls, other than its own — XLA
+    fuses a weight's optimizer update into that weight's gradient
+    matmul, and the fusion reads as backward."""
+    program, current = "", ""
+    rows: dict[str, list] = {}          # computation -> its instructions
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            if line.startswith("HloModule "):
+                program = line.split()[1].rstrip(",")
+            else:
+                m = _HLO_COMPUTATION.match(line)
+                if m:
+                    current = m.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            op_name = _HLO_OP_NAME.search(line)
+            rows.setdefault(current, []).append(
+                (m.group(1), m.group(2), op_name and op_name.group(1),
+                 _HLO_CALLED.findall(line)))
+    called = {c for body in rows.values() for row in body for c in row[3]}
+
+    def scopes_in(computation, seen):
+        for _, _, op_name, callees in rows.get(computation, ()):
+            if op_name and "/" in op_name:
+                seen.add(op_name.rpartition("/")[0])
+            for c in callees:
+                scopes_in(c, seen)
+        return seen
+
+    scopes: dict[str, list] = {}
+    inside: dict[str, list] = {}
+    for computation, body in rows.items():
+        if computation in called:
+            continue
+        for name, opcode, op_name, callees in body:
+            if not op_name or opcode in _HLO_NO_OP:
+                continue
+            scopes.setdefault(op_name, []).append(name)
+            held = set()
+            for c in callees:
+                scopes_in(c, held)
+            for scope in sorted(held - {op_name.rpartition("/")[0]}):
+                inside.setdefault(scope, []).append(name)
+    return {"program": program, "scopes": scopes, "inside": inside}
+
+
+class ProgramScopes:
+    """The instruction -> scope tables of the programs a training loop
+    compiled, handed to every profiler session once.
+
+    ``add(compiled)`` at compile time (a profiler session starts long
+    after it); ``annotate()`` once an iteration: in the first one that
+    finds a session running it writes each table as ONE annotation,
+    ``bigdl:compile:step_scopes``, so whoever reads the trace can name
+    each device operation of that program's runs by the part of the step
+    it belongs to. The table is that annotation's ``long_name`` stat, as
+    JSON, serialised in ``add`` and not inside the session. (The
+    profiler's encoding ends an annotation's stats at ``#``.)"""
+
+    def __init__(self):
+        self._tables: list[str] = []
+        self._written = 0
+
+    def add(self, compiled) -> None:
+        try:
+            table = _program_scopes(compiled.as_text())
+            self._tables.append(json.dumps(
+                table, separators=(",", ":")).replace("#", "_"))
+        except Exception as e:    # a trace aid must not stop training
+            logging.getLogger(__name__).warning(
+                "no scope table for a compiled step, so a profiler "
+                "trace cannot name its device operations by scope: %r", e)
+
+    def annotate(self) -> None:
+        if not _annotation_class().is_enabled():
+            self._written = 0
+        elif self._written < len(self._tables):
+            for table in self._tables[self._written:]:
+                with _annotation("step scopes", "compile",
+                                 {"long_name": table}):
+                    pass
+            self._written = len(self._tables)
 
 
 class Tracer:
@@ -78,8 +236,8 @@ class Tracer:
 
     # -- taps (flight recorder et al.) --
     def add_tap(self, fn) -> None:
-        """Subscribe ``fn(event_dict)`` to every span/instant/counter
-        event, INDEPENDENT of the enabled flag — a disabled tracer with
+        """Subscribe ``fn(event_dict)`` to every span/instant event,
+        INDEPENDENT of the enabled flag — a disabled tracer with
         a tap still builds events (but buffers nothing). Tap errors are
         swallowed: observability must never take down the loop."""
         with self._lock:
@@ -109,17 +267,23 @@ class Tracer:
                 return
             self._events.append(ev)
 
-    @contextlib.contextmanager
     def span(self, name: str, cat: str = "host", **args):
         """Complete-event context manager. Extra kwargs land in the
         event's ``args`` (use ``host_sync="why"`` to mark that the
-        wrapped code intentionally blocks on a device value)."""
+        wrapped code intentionally blocks on a device value). The span
+        is a profiler annotation too (module docstring); with the
+        tracer disabled and untapped it is nothing else."""
+        annotation = _annotation(name, cat, args)
         if not self._enabled and not self._taps:
-            yield
-            return
+            return annotation
+        return self._recorded_span(annotation, name, cat, args)
+
+    @contextlib.contextmanager
+    def _recorded_span(self, annotation, name: str, cat: str, args: dict):
         t0 = self._now_us()
         try:
-            yield
+            with annotation:
+                yield
         finally:
             t1 = self._now_us()
             ev = {"name": name, "cat": cat, "ph": "X", "ts": t0,
@@ -130,6 +294,8 @@ class Tracer:
             self._emit(ev)
 
     def instant(self, name: str, cat: str = "host", **args):
+        with _annotation(name, cat, args):
+            pass
         if not self._enabled and not self._taps:
             return
         ev = {"name": name, "cat": cat, "ph": "i", "s": "t",
@@ -138,15 +304,6 @@ class Tracer:
         if args:
             ev["args"] = args
         self._emit(ev)
-
-    def counter(self, name: str, value: float, cat: str = "host"):
-        """Counter-track event (renders as a value-over-time track)."""
-        if not self._enabled and not self._taps:
-            return
-        self._emit({"name": name, "cat": cat, "ph": "C",
-                    "ts": self._now_us(), "pid": self._pid,
-                    "tid": threading.get_ident(),
-                    "args": {"value": float(value)}})
 
     def host_sync(self, what: str, **args):
         """Annotate an INTENTIONAL host<-device sync point (loss
@@ -198,12 +355,6 @@ def get_tracer() -> Tracer:
     return _TRACER
 
 
-def set_tracer(tracer: Tracer) -> Tracer:
-    global _TRACER
-    _TRACER = tracer
-    return tracer
-
-
 def enable():
     return _TRACER.enable()
 
@@ -222,10 +373,6 @@ def span(name: str, cat: str = "host", **args):
 
 def instant(name: str, cat: str = "host", **args):
     return _TRACER.instant(name, cat=cat, **args)
-
-
-def counter(name: str, value: float, cat: str = "host"):
-    return _TRACER.counter(name, value, cat=cat)
 
 
 def host_sync(what: str, **args):

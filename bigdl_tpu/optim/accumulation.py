@@ -178,6 +178,14 @@ def make_train_step(*, fwd, criterion, masked=None, input_transform=None,
     and the AOT executable cache are untouched. ``> 1`` scans strided
     microbatches with the gradient accumulated in donated carry
     buffers and runs ``update_fn`` once.
+
+    Both branches name the step's parts with ``jax.named_scope`` —
+    ``model``, ``criterion``, ``grad_clip``, ``optimizer_update`` — so a
+    profiler trace names every device operation by the part it belongs
+    to: ``jvp(model)/...`` forward, ``transpose(jvp(model))/...``
+    backward. A scope is debug metadata only: the lowered program is
+    the same text with and without it
+    (tests/test_program_spans.py).
     """
     from bigdl_tpu.optim.optimizer import _clip_gradients
     k = int(num_microbatches)
@@ -197,25 +205,29 @@ def make_train_step(*, fwd, criterion, masked=None, input_transform=None,
                 data = input_transform(data)
 
             def loss_fn(p):
-                y, new_mstate = fwd(p, mstate, data, training=True,
-                                    rng=rng)
-                if use_mask:
-                    # validity mask materialized in-step from the real
-                    # row count: padded rows contribute exactly zero to
-                    # loss and gradient (nn.MaskedCriterion)
-                    mask = jnp.arange(data.shape[0]) < n_valid
-                    return masked.apply(y, labels, mask), new_mstate
-                loss = criterion.apply(y, labels)
-                if aux_loss is not None:
-                    loss = loss + aux_loss(new_mstate)
+                with jax.named_scope("model"):
+                    y, new_mstate = fwd(p, mstate, data, training=True,
+                                        rng=rng)
+                with jax.named_scope("criterion"):
+                    if use_mask:
+                        # validity mask materialized in-step from the
+                        # real row count: padded rows contribute exactly
+                        # zero to loss and gradient (nn.MaskedCriterion)
+                        mask = jnp.arange(data.shape[0]) < n_valid
+                        return masked.apply(y, labels, mask), new_mstate
+                    loss = criterion.apply(y, labels)
+                    if aux_loss is not None:
+                        loss = loss + aux_loss(new_mstate)
                 return loss, new_mstate
 
             (loss, new_mstate), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-            grads = _clip_gradients(grads, grad_clip)
+            with jax.named_scope("grad_clip"):
+                grads = _clip_gradients(grads, grad_clip)
             opt_state = dict(opt_state, epoch=epoch)
-            new_params, new_opt_state = update_fn(grads, params,
-                                                  opt_state)
+            with jax.named_scope("optimizer_update"):
+                new_params, new_opt_state = update_fn(grads, params,
+                                                      opt_state)
             return new_params, new_mstate, new_opt_state, loss
 
         return train_step
@@ -230,19 +242,22 @@ def make_train_step(*, fwd, criterion, masked=None, input_transform=None,
                 d = input_transform(d)
 
             def loss_fn(pp):
-                y, new_mstate = fwd(pp, mstate, d, training=True,
-                                    rng=key)
-                if use_mask:
-                    mask = microbatch_valid_mask(j, d.shape[0], k,
-                                                 n_valid)
-                    num, cnt = masked.masked_sum(y, l, mask)
-                else:
-                    num = criterion.apply(y, l)
-                    if aux_loss is not None:
-                        # per-microbatch aux joins the numerator; the
-                        # final /k restores its mean like the loss
-                        num = num + aux_loss(new_mstate)
-                    cnt = jnp.ones((), num.dtype)
+                with jax.named_scope("model"):
+                    y, new_mstate = fwd(pp, mstate, d, training=True,
+                                        rng=key)
+                with jax.named_scope("criterion"):
+                    if use_mask:
+                        mask = microbatch_valid_mask(j, d.shape[0], k,
+                                                     n_valid)
+                        num, cnt = masked.masked_sum(y, l, mask)
+                    else:
+                        num = criterion.apply(y, l)
+                        if aux_loss is not None:
+                            # per-microbatch aux joins the numerator;
+                            # the final /k restores its mean like the
+                            # loss
+                            num = num + aux_loss(new_mstate)
+                        cnt = jnp.ones((), num.dtype)
                 return num, (cnt, new_mstate)
 
             (num, (cnt, new_mstate)), grads = jax.value_and_grad(
@@ -254,9 +269,12 @@ def make_train_step(*, fwd, criterion, masked=None, input_transform=None,
         loss, grads = finalize_accumulated(num, w, grads, k=k,
                                            size_average=size_avg,
                                            masked=use_mask)
-        grads = _clip_gradients(grads, grad_clip)
+        with jax.named_scope("grad_clip"):
+            grads = _clip_gradients(grads, grad_clip)
         opt_state = dict(opt_state, epoch=epoch)
-        new_params, new_opt_state = update_fn(grads, params, opt_state)
+        with jax.named_scope("optimizer_update"):
+            new_params, new_opt_state = update_fn(grads, params,
+                                                  opt_state)
         return new_params, new_mstate, new_opt_state, loss
 
     return train_step

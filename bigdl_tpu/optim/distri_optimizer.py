@@ -581,115 +581,127 @@ class DistriOptimizer(Optimizer):
         wallclock_start = time.perf_counter()
 
         try:
-            while self.end_when is None or not self.end_when(driver_state):
-                driver_state["is_epoch_end"] = False
+            while True:
+                # the profiler hook first, so that a set_profiler trace
+                # holds the whole of its first iteration's span
                 self._profile_hook(driver_state["neval"])
-                t0 = time.perf_counter()
-                with trace.span("input wait"):
-                    # queue pop at depth >= 1: the batch was assembled,
-                    # checked, and mesh-placed on the worker thread
-                    # ("input produce")
-                    batch = next(pipeline)
-                t1 = time.perf_counter()
-                data_time = t1 - t0
-                data, labels = batch.data, batch.labels
-                if batch.valid is not None:
-                    # padded batch: count the REAL rows (single
-                    # controller — _init_pad_stage refuses multi-host)
-                    global_n = int(batch.valid)
-                else:
-                    global_n = int(data.shape[0])
-                rng, step_rng = jax.random.split(rng)
-                epoch_arr = jnp.asarray(driver_state["epoch"], jnp.int32)
-                step_args = (step_rng, data, labels, epoch_arr)
-                if use_mask:
-                    step_args += (jnp.asarray(global_n, jnp.int32),)
-                shape_key = (data.shape, labels.shape)
-                compiled_this_iter = shape_key not in step_pipeline
-                # lower/compile (or AOT-cache load) on first sight of a
-                # shape; compile counts, executable FLOPs and peak HBM
-                # land in the registry either way
-                # (observability/compile_watch.py)
-                compiled, _ = step_pipeline.get(
-                    shape_key, (params, mstate, opt_state) + step_args)
-                if compiled_this_iter and len(step_pipeline) == 1:
-                    self._account_collectives(compiled, n_shards)
-                with trace.span("device step"):
-                    # dispatch only — loss stays on device; the packed
-                    # readback happens at drain time (docs/PERFORMANCE.md).
-                    # Honest phase metrics: the reference's get-weights/
-                    # compute/aggregate phases fuse inside the jitted
-                    # step, so what's measurable is input wait vs device
-                    # step (see metrics.py)
-                    params, mstate, opt_state, loss = compiled(
-                        params, mstate, opt_state, *step_args)
-                t2 = time.perf_counter()
-                self._telemetry_step()
-                n = global_n  # records consumed across all hosts
-                count_this_epoch += n
-                batches_this_epoch += 1
-                pending.append({"epoch": driver_state["epoch"],
-                                "count": count_this_epoch,
-                                "epoch_size": epoch_size,
-                                "neval": driver_state["neval"],
-                                "wallclock": time.perf_counter()
-                                - wallclock_start,
-                                "loss": loss, "n": n,
-                                "step_time": t2 - t0,
-                                "data_time": data_time,
-                                "device_time": t2 - t1,
-                                "compiled": compiled_this_iter})
-                if len(pending) >= window:
-                    self._drain_pending(pending, driver_state,
-                                        lockstep or "window full")
-                driver_state["neval"] += 1
-                if count_this_epoch >= epoch_size:
-                    self._drain_pending(pending, driver_state, "epoch end")
-                    self._emit_input_wait_fraction(driver_state["neval"])
-                    # epoch-end checkpoint barrier: pending async saves
-                    # commit before the next epoch dispatches
-                    self._ckpt_barrier()
-                    driver_state["epoch"] += 1
-                    driver_state["is_epoch_end"] = True
-                    count_this_epoch = 0
-                    batches_this_epoch = 0
-                    # join the worker BEFORE shuffle() mutates the order
-                    # it iterates (thread-safety contract,
-                    # dataset/prefetch.py), then restart on the fresh
-                    # epoch's iterator
-                    pipeline.close()
-                    self.dataset.shuffle()
-                    epoch_start_host_rng = self._host_rng_snapshot()
-                    pipeline = self._open_train_pipeline(
-                        place, records_scale=jax.process_count())
-                    # MoE dispatch telemetry -> registry, once per
-                    # epoch (one batched readback, never per-step)
-                    self._publish_expert_telemetry(mstate)
-                fire_val, fire_ckpt = self._fires(driver_state)
-                ptree, opt_export = params, opt_state
-                if fire_val or fire_ckpt:
-                    # validation/checkpoint read host-visible state: flush
-                    # the window first, then publish params (host-side
-                    # tree walk is overhead on deep models). Sharded
-                    # update: gather the f32 masters and re-shape the
-                    # bucketed optimizer state back to the params-shaped
-                    # (ZeRO-1-compatible) checkpoint layout
-                    self._drain_pending(pending, driver_state,
-                                        "validation/checkpoint trigger")
-                    if su is not None:
-                        ptree = su.gather_params(params)
-                        if fire_ckpt:
-                            opt_export = su.export_opt_state(opt_state)
-                    elif pp is not None:
-                        ptree = pp.gather_params(params)
-                        if fire_ckpt:
-                            opt_export = pp.export_opt_state(opt_state)
-                    model.sync(ptree, mstate)
-                self._validate(eval_fn, ptree, mstate, driver_state,
-                               fire=fire_val)
-                self._checkpoint(driver_state, opt_export, rng,
-                                 count_this_epoch, batches_this_epoch,
-                                 epoch_start_host_rng, fire=fire_ckpt)
+                with trace.span("train iteration",
+                                step=driver_state["neval"]):
+                    if self.end_when is not None and \
+                            self.end_when(driver_state):
+                        break
+                    driver_state["is_epoch_end"] = False
+                    self._step_scopes.annotate()
+                    t0 = time.perf_counter()
+                    with trace.span("input wait"):
+                        # queue pop at depth >= 1: the batch was assembled,
+                        # checked, and mesh-placed on the worker thread
+                        # ("input produce")
+                        batch = next(pipeline)
+                    t1 = time.perf_counter()
+                    data_time = t1 - t0
+                    data, labels = batch.data, batch.labels
+                    if batch.valid is not None:
+                        # padded batch: count the REAL rows (single
+                        # controller — _init_pad_stage refuses multi-host)
+                        global_n = int(batch.valid)
+                    else:
+                        global_n = int(data.shape[0])
+                    with trace.span("step lookup"):
+                        rng, step_rng = jax.random.split(rng)
+                        epoch_arr = jnp.asarray(driver_state["epoch"],
+                                                jnp.int32)
+                        step_args = (step_rng, data, labels, epoch_arr)
+                        if use_mask:
+                            step_args += (jnp.asarray(global_n, jnp.int32),)
+                        # lower/compile (or AOT-cache load) on first sight
+                        # of a shape; compile counts, executable FLOPs and
+                        # peak HBM land in the registry either way
+                        # (observability/compile_watch.py)
+                        compiled, compiled_this_iter = self._lookup_step(
+                            step_pipeline, (data.shape, labels.shape),
+                            (params, mstate, opt_state) + step_args)
+                        if compiled_this_iter and len(step_pipeline) == 1:
+                            self._account_collectives(compiled, n_shards)
+                    with trace.span("device step"):
+                        # dispatch only — loss stays on device; the packed
+                        # readback happens at drain time (docs/PERFORMANCE.md).
+                        # Honest phase metrics: the reference's get-weights/
+                        # compute/aggregate phases fuse inside the jitted
+                        # step, so what's measurable is input wait vs device
+                        # step (see metrics.py)
+                        params, mstate, opt_state, loss = compiled(
+                            params, mstate, opt_state, *step_args)
+                    t2 = time.perf_counter()
+                    self._telemetry_step()
+                    n = global_n  # records consumed across all hosts
+                    count_this_epoch += n
+                    batches_this_epoch += 1
+                    pending.append({"epoch": driver_state["epoch"],
+                                    "count": count_this_epoch,
+                                    "epoch_size": epoch_size,
+                                    "neval": driver_state["neval"],
+                                    "wallclock": time.perf_counter()
+                                    - wallclock_start,
+                                    "loss": loss, "n": n,
+                                    "step_time": t2 - t0,
+                                    "data_time": data_time,
+                                    "device_time": t2 - t1,
+                                    "compiled": compiled_this_iter})
+                    if len(pending) >= window:
+                        self._drain_pending(pending, driver_state,
+                                            lockstep or "window full")
+                    driver_state["neval"] += 1
+                    if count_this_epoch >= epoch_size:
+                        self._drain_pending(pending, driver_state, "epoch end")
+                        self._emit_input_wait_fraction(driver_state["neval"])
+                        # epoch-end checkpoint barrier: pending async saves
+                        # commit before the next epoch dispatches
+                        self._ckpt_barrier()
+                        driver_state["epoch"] += 1
+                        driver_state["is_epoch_end"] = True
+                        count_this_epoch = 0
+                        batches_this_epoch = 0
+                        # join the worker BEFORE shuffle() mutates the order
+                        # it iterates (thread-safety contract,
+                        # dataset/prefetch.py), then restart on the fresh
+                        # epoch's iterator
+                        pipeline.close()
+                        self.dataset.shuffle()
+                        epoch_start_host_rng = self._host_rng_snapshot()
+                        pipeline = self._open_train_pipeline(
+                            place, records_scale=jax.process_count())
+                        # MoE dispatch telemetry -> registry, once per
+                        # epoch (one batched readback, never per-step)
+                        self._publish_expert_telemetry(mstate)
+                    fire_val, fire_ckpt = self._fires(driver_state)
+                    ptree, opt_export = params, opt_state
+                    if fire_val or fire_ckpt:
+                        # validation/checkpoint read host-visible state: flush
+                        # the window first, then publish params (host-side
+                        # tree walk is overhead on deep models). Sharded
+                        # update: gather the f32 masters and re-shape the
+                        # bucketed optimizer state back to the params-shaped
+                        # (ZeRO-1-compatible) checkpoint layout
+                        self._drain_pending(pending, driver_state,
+                                            "validation/checkpoint trigger")
+                        with trace.span("model sync"):
+                            if su is not None:
+                                ptree = su.gather_params(params)
+                                if fire_ckpt:
+                                    opt_export = su.export_opt_state(
+                                        opt_state)
+                            elif pp is not None:
+                                ptree = pp.gather_params(params)
+                                if fire_ckpt:
+                                    opt_export = pp.export_opt_state(
+                                        opt_state)
+                            model.sync(ptree, mstate)
+                    self._validate(eval_fn, ptree, mstate, driver_state,
+                                   fire=fire_val)
+                    self._checkpoint(driver_state, opt_export, rng,
+                                     count_this_epoch, batches_this_epoch,
+                                     epoch_start_host_rng, fire=fire_ckpt)
         finally:
             pipeline.close()
 

@@ -40,9 +40,11 @@ module stays importable in jax-free tooling.
 # raceguard: order state_lock < replica.lock
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 
+from bigdl_tpu.observability import trace
 from bigdl_tpu.observability.exporter import default_health
 from bigdl_tpu.observability.registry import MetricRegistry
 from bigdl_tpu.serving.slo import ReplicaStats
@@ -95,13 +97,32 @@ class Replica:
             self._thread.start()
         return self
 
+    def _lock_wait(self, rid, spanned: bool = True):
+        """A ``replica lock wait`` span, OPEN: the caller ``close()``s it
+        as its first statement under ``with self.lock:`` (which stays a
+        plain with-statement for raceguard). The driver holds the lock
+        for a whole burst and takes it again at once; a submission's
+        span beside the driver's re-take shows whether it waits behind
+        that."""
+        wait = contextlib.ExitStack()
+        if spanned:
+            wait.enter_context(trace.span(
+                "replica lock wait", cat="serving", replica=self.name,
+                rid=rid))
+        return wait
+
     def _run(self):
         import logging
         log = logging.getLogger(__name__)
+        stepped = 0
         while not self._stop:
+            # the re-take right after a burst is the one in question; an
+            # idle driver's poll ticks would only fill the trace
+            wait = self._lock_wait("driver", spanned=stepped > 0)
             stepped = 0
             try:
                 with self.lock:
+                    wait.close()
                     if not self._stop and not self.batcher.idle:
                         stepped = self.batcher.step(self._burst)
             except Exception as e:
@@ -109,7 +130,9 @@ class Replica:
                 # not pass for slowness either: keep it where the
                 # router's wait_all raises it
                 log.exception("replica %s step failed", self.name)
+                wait = self._lock_wait("driver")
                 with self.lock:
+                    wait.close()
                     self.step_error = e
                 stepped = 0
             if not stepped:
@@ -185,7 +208,9 @@ class Replica:
     # -- request plane (router-facing; all under the replica lock) --
     def submit(self, request_id, prompt=None, *, snapshot=None,
                prefill_from=None) -> None:
+        wait = self._lock_wait(request_id)
         with self.lock:
+            wait.close()
             if self._state != ACTIVE:
                 raise RuntimeError(
                     f"replica {self.name} is {self._state}: not "
