@@ -60,8 +60,9 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
 
     ``flash="auto"`` routes to the fused Pallas kernel
     (ops/pallas/flash_attention.py) on TPU whenever shapes allow —
-    O(S·D) memory instead of the (B,H,S,S) score matrix, measured 2.3x
-    faster at S=4096 on v5e and the only path that fits S>=8192.
+    O(S·D) memory instead of the (B,H,S,S) score matrix; what it runs
+    (tiles, K/V in VMEM or streamed, one backward pass or two) follows
+    from the shapes there, and its measured times are in PERF.md.
     The XLA fallback below is the reference semantics (and the CPU/test
     path); both share bf16-operand matmul rounding, so they agree to
     ~1e-3 under a temperate softmax.

@@ -224,14 +224,28 @@ def flash_candidates(sq: int, skv: int, *, q_cap: int = 512,
             for bk in tile_divisors(skv, k_cap)]
 
 
-def flash_est_vmem(d: int, dtype_bytes: int = 2):
-    """Forward-kernel footprint at head dim ``d``: s+p f32 tiles
-    (bq, bk), f32 acc (bq, d), double-buffered q/k/v blocks."""
+def flash_est_vmem(d: int, dtype_bytes: int = 2, sq: int | None = None):
+    """Footprint at head dim ``d`` of the fattest kernel a (bq, bk) pair
+    runs, the backward: four f32 tiles (s, p, dp, ds), the f32 dq/dk/dv
+    accumulators, double-buffered q/dO/k/v blocks, lanes padded to 128.
+    ``sq``: causal self-attention of that length — the looped kernels
+    then keep a head in VMEM (K, V and the dk/dv outputs double-buffered,
+    the f32 dk/dv scratch) beside a tile of bq x bk, counted as
+    ``ops/pallas/flash_attention._schedule`` counts it and dropped where
+    it exceeds that module's budget: the kernels stream then."""
+    lanes = -(-d // 128) * 128
+    resident = 0
+    if sq is not None:
+        from bigdl_tpu.ops.pallas.flash_attention import _RESIDENT_BUDGET
+        resident = 4 * 2 * sq * lanes * dtype_bytes + 2 * sq * lanes * 4
+        if resident > _RESIDENT_BUDGET:
+            resident = 0
+
     def est(c: dict) -> int:
         bq, bk = c["bq"], c["bk"]
         f32 = 4
-        return (2 * bq * bk * f32 + bq * d * f32
-                + 2 * (bq * d + 2 * bk * d) * dtype_bytes)
+        return (4 * bq * bk * f32 + (bq + 2 * bk) * lanes * f32
+                + 2 * 2 * (bq + bk) * lanes * dtype_bytes + resident)
     return est
 
 

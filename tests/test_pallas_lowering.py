@@ -72,7 +72,14 @@ class TestSmokeGeometriesLower:
         fn = _scalar_grads(
             lambda q, k, v: flash_attention(q, k, v, causal=True), 3)
         text = _lowers(fn, *[_sds(shape)] * 3)
-        # forward, dq and fused dk/dv
+        # causal self-attention that fits VMEM: forward and ONE backward
+        assert text.count("tpu_custom_call") == 2
+
+    def test_flash_streamed_fwd_bwd(self):
+        # no diagonal: forward, dq and fused dk/dv over the grid
+        fn = _scalar_grads(lambda q, k, v: flash_attention(q, k, v), 3)
+        text = _lowers(fn, _sds((2, 512, 4, 128)),
+                       *[_sds((2, 2048, 4, 128))] * 2)
         assert text.count("tpu_custom_call") == 3
 
     def test_lrn_fwd_bwd(self):
@@ -93,6 +100,55 @@ class TestSmokeGeometriesLower:
         args = _paged_args(4, t, 8, kv, 128, s, 16)
         assert _lowers(paged_attention, *args).count(
             "tpu_custom_call") == 1
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip: compiling for it runs XLA:TPU
+    and Mosaic — VMEM limits, unaligned slices, unsupported loops — with
+    no chip. Described inside the fixture, never at import: only the
+    worker that runs this file may load the TPU's library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+class TestFlashCompilesForTheV5e:
+    """Mosaic's own verdict on the flash kernels, forward and backward in
+    one program, at the shapes that decide the schedule."""
+
+    # (B, S, H, D) -> (K/V resident, one backward pass, Mosaic calls)
+    @pytest.mark.parametrize("shape,resident,one_pass,calls", [
+        ((4, 2048, 32, 64), True, True, 2),     # the benchmark cells', a chip
+        ((2, 2048, 32, 128), True, True, 2),    # opt-6.7b's head width
+        ((1, 8192, 4, 128), True, True, 2),     # the most a head holds
+        ((1, 16384, 2, 128), True, False, 3),   # dq accumulator too large
+        ((1, 32768, 1, 128), False, False, 3),  # a long ring shard: streamed
+    ], ids=["cell-s2048-d64", "s2048-d128", "s8192-d128", "s16384-d128",
+            "s32768-d128"])
+    def test_causal_fwd_bwd(self, one_chip, shape, resident, one_pass,
+                            calls):
+        from bigdl_tpu.ops.pallas.flash_attention import _schedule
+        sched = _schedule(True, shape[1], shape[1], shape[3], 2)
+        assert (sched.kv_resident, sched.one_pass_backward) == (
+            resident, one_pass)
+        fn = _scalar_grads(
+            lambda q, k, v: flash_attention(q, k, v, causal=True), 3)
+        x = jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+        text = jax.jit(fn).lower(x, x, x).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == calls
+
+    def test_streamed_cross_attention(self, one_chip):
+        fn = _scalar_grads(lambda q, k, v: flash_attention(q, k, v), 3)
+        q = jax.ShapeDtypeStruct((4, 512, 32, 64), BF16, sharding=one_chip)
+        kv = jax.ShapeDtypeStruct((4, 2048, 32, 64), BF16,
+                                  sharding=one_chip)
+        jax.jit(fn).lower(q, kv, kv).compile()
 
 
 class TestPredicatesMatchTheLowering:

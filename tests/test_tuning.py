@@ -164,6 +164,16 @@ class TestTune:
         assert {"bq": 160, "bk": 128} in cands
         est = flash_est_vmem(d=64)
         assert est({"bq": 512, "bk": 1024}) > est({"bq": 128, "bk": 128})
+        # causal self-attention of a length: what the looped kernels keep
+        # of a head (K, V, the dk/dv outputs double-buffered, the f32
+        # dk/dv scratch) rides on every candidate — until it exceeds the
+        # kernels' own budget and they stream
+        held = flash_est_vmem(d=64, sq=2048)
+        assert held({"bq": 512, "bk": 1024}) - est({"bq": 512, "bk": 1024}) \
+            == 4 * 2 * 2048 * 128 * 2 + 2 * 2048 * 128 * 4
+        streamed = flash_est_vmem(d=128, sq=32768)
+        assert streamed({"bq": 512, "bk": 1024}) \
+            == flash_est_vmem(d=128)({"bq": 512, "bk": 1024})
         assert {"bucket_mb": 4.0} in bucket_mb_candidates()
 
     def test_step_memory_candidates_and_est(self):
