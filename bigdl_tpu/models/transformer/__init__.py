@@ -1,7 +1,9 @@
 from bigdl_tpu.models.transformer.generate import (GenerationConfig,
                                                     beam_search, generate)
-from bigdl_tpu.models.transformer.model import (TransformerBlock,
+from bigdl_tpu.models.transformer.model import (EvaByteLM, PreNormBlock,
+                                                TransformerBlock,
                                                 TransformerLM)
 
-__all__ = ["TransformerBlock", "TransformerLM", "GenerationConfig",
+__all__ = ["TransformerBlock", "TransformerLM", "PreNormBlock", "EvaByteLM",
+           "GenerationConfig",
            "generate", "beam_search"]

@@ -24,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from bigdl_tpu.models.transformer.model import decode_meta
 from bigdl_tpu.tensor import activation_dtype, compute_dtype
 
 __all__ = ["generate", "beam_search", "GenerationConfig"]
@@ -198,10 +199,7 @@ def _decode_setup(model, prompt, n_new, params):
     between calls retrace instead of silently reusing stale-dtype
     executables)."""
     params = model.params if params is None else params
-    meta = getattr(model, "lm_meta", None)
-    if meta is None:
-        raise ValueError("model has no lm_meta — build it with "
-                         "TransformerLM(...) to generate")
+    meta = decode_meta(model)
     prompt = jnp.asarray(prompt)
     if prompt.shape[1] + n_new > meta["max_len"]:
         raise ValueError(f"prompt {prompt.shape[1]} + new {n_new} exceeds "

@@ -18,21 +18,40 @@ from bigdl_tpu.nn import init as init_mod
 from bigdl_tpu.nn.module import Container, Module
 from bigdl_tpu.tensor import activation_dtype, default_dtype
 
-__all__ = ["TransformerLM", "TransformerBlock"]
+__all__ = ["TransformerLM", "TransformerBlock", "PreNormBlock", "EvaByteLM",
+           "decode_meta"]
 
 
 class _Residual(Container):
-    """y = x + inner(norm(x)) — pre-LN residual wrapper."""
+    """y = x + inner(norm(x)) — pre-norm residual wrapper. With a
+    ``residual_dtype`` the sum, and so the stream the blocks hand on, is
+    kept in that dtype (float32 under bf16 activations: the branch's
+    output is widened, the stream never rounded)."""
 
-    def __init__(self, d_model: int, inner: Module):
-        super().__init__(nn.LayerNorm(d_model), inner)
+    def __init__(self, norm: Module, inner: Module, residual_dtype=None):
+        super().__init__(norm, inner)
+        self.residual_dtype = residual_dtype
 
     def apply(self, params, state, x, *, training=False, rng=None):
         h, s0 = self.modules[0].apply(params["0"], state["0"], x,
                                       training=training)
         h, s1 = self.modules[1].apply(params["1"], state["1"], h,
                                       training=training, rng=rng)
+        if self.residual_dtype is not None:
+            x, h = x.astype(self.residual_dtype), h.astype(self.residual_dtype)
         return x + h, {"0": s0, "1": s1}
+
+
+def PreNormBlock(norm, attention: Module, ffn: Module,
+                 residual_dtype=None) -> nn.Sequential:
+    """x + attention(norm(x)); x + ffn(norm(x)). ``norm`` is called once
+    for each of the two norms; ``attention`` and ``ffn`` are the modules
+    themselves — a new kind of block is new arguments, not a new case
+    here. The parameter tree is {"0": {"0": norm, "1": attention},
+    "1": {"0": norm, "1": ffn}} whatever they are."""
+    return (nn.Sequential()
+            .add(_Residual(norm(), attention, residual_dtype))
+            .add(_Residual(norm(), ffn, residual_dtype)))
 
 
 def TransformerBlock(d_model: int, num_heads: int, ffn_mult: int = 4,
@@ -50,9 +69,7 @@ def TransformerBlock(d_model: int, num_heads: int, ffn_mult: int = 4,
            .add(nn.Linear(ffn_mult * d_model, d_model)))
     if dropout > 0:
         ffn.add(nn.Dropout(dropout))
-    return (nn.Sequential()
-            .add(_Residual(d_model, mha))
-            .add(_Residual(d_model, ffn)))
+    return PreNormBlock(lambda: nn.LayerNorm(d_model), mha, ffn)
 
 
 class _TokenAndPosition(Module):
@@ -61,10 +78,11 @@ class _TokenAndPosition(Module):
     position enters through the attention rotation instead)."""
 
     def __init__(self, vocab: int, d_model: int, max_len: int,
-                 with_pos: bool = True):
+                 with_pos: bool = True, out_dtype=None):
         super().__init__()
         self.vocab, self.d_model, self.max_len = vocab, d_model, max_len
         self.with_pos = with_pos
+        self.out_dtype = out_dtype      # None: the policy's activations
 
     def init(self, rng):
         k1, k2 = jax.random.split(rng)
@@ -77,14 +95,16 @@ class _TokenAndPosition(Module):
         return p
 
     def apply(self, params, state, x, *, training=False, rng=None):
-        # x: (batch, seq) 1-based token ids (LookupTable convention)
+        # x: (batch, seq) 1-based token ids (LookupTable convention); an
+        # id outside 1..vocab reads the nearest row, it does not raise
+        # (tests/test_evabyte.py pins it)
         idx = x.astype(jnp.int32) - 1
         s = x.shape[1]
         y = jnp.take(params["tok"], jnp.clip(idx, 0, self.vocab - 1),
                      axis=0)
         if self.with_pos:
             y = y + params["pos"][:s]
-        return y.astype(activation_dtype()), state
+        return y.astype(self.out_dtype or activation_dtype()), state
 
 
 def TransformerLM(vocab_size: int, d_model: int = 128, num_heads: int = 4,
@@ -132,3 +152,67 @@ def TransformerLM(vocab_size: int, d_model: int = 128, num_heads: int = 4,
                      "vocab": vocab_size, "pos_encoding": pos_encoding,
                      "num_kv_heads": num_kv_heads}
     return model
+
+
+def EvaByteLM(vocab_size: int = 320, d_model: int = 4096,
+              num_heads: int = 32, num_layers: int = 32,
+              ffn_dim: int = 11008, window: int = 2048, chunk: int = 16,
+              num_pred_heads: int = 8, rope_theta: float = 100000.0,
+              rms_eps: float = 1e-5,
+              remat: str | None = "per_block") -> nn.Sequential:
+    """EvaByte (huggingface.co/EvaByte/EvaByte): a byte-level decoder of
+    pre-norm blocks x + EVA(RMSNorm(x)); x + SwiGLU(RMSNorm(x)) —
+    ``nn.EvaAttention``, RMS norms with a unit offset, no bias anywhere,
+    RoPE and no position table — on a float32 residual stream, ending in
+    ``num_pred_heads`` x ``vocab_size`` float32 logits a position (pair
+    with ``nn.MultiBytePredictionCriterion``). bytes (B, S) 1-based, S a
+    multiple of ``window``. docs/eva_attention.md has the equations.
+
+    ``remat`` is the training recipe of the model AS BUILT: each child
+    is recomputed in the backward pass (``Sequential.set_remat``), under
+    ``Optimizer`` and under a bare ``jax.grad`` of ``apply`` alike.
+
+    The same ``embed`` / ``block_i`` / ``final_norm`` / ``lm_head``
+    children as ``TransformerLM``, but no ``lm_meta``: there is no
+    decode path for EvaAttention yet (``decode_meta``)."""
+    def norm():
+        return nn.RMSNorm(d_model, eps=rms_eps, unit_offset=True)
+
+    model = (nn.Sequential()
+             .add(_TokenAndPosition(vocab_size, d_model, 0, with_pos=False,
+                                    out_dtype=jnp.float32)
+                  .set_name("embed")))
+    for i in range(num_layers):
+        model.add(PreNormBlock(
+            norm,
+            nn.EvaAttention(d_model, num_heads, window, chunk, rope_theta),
+            nn.GatedFFN(d_model, ffn_dim, act="silu"),
+            residual_dtype=jnp.float32).set_name(f"block_{i}"))
+    model.add(norm().set_name("final_norm"))
+    # logits of standard deviation 0.28 on a unit-RMS input: what Xavier
+    # gives TransformerLM's 50272-row head at OPT-1.3B's width. On these
+    # few columns Xavier gives 1.1, and every gradient of a random-init
+    # model, and so what rounding the weights to bf16 moves the loss by,
+    # grows with it (PERF.md section 6, PR 27)
+    def small_normal(rng, shape, dtype):
+        return jax.random.normal(rng, shape, dtype) * 0.28 * d_model ** -0.5
+
+    model.add(nn.Linear(d_model, num_pred_heads * vocab_size,
+                        with_bias=False, init_method=small_normal,
+                        output_dtype=jnp.float32).set_name("lm_head"))
+    model.no_decode_path = "EvaAttention: ROADMAP B7"
+    return model.set_remat(remat)
+
+
+def decode_meta(model) -> dict:
+    """The decode-path metadata ``TransformerLM`` attaches (generate.py,
+    serving.py). A model without it is refused by name, never guessed
+    at."""
+    meta = getattr(model, "lm_meta", None)
+    if meta is None:
+        why = getattr(model, "no_decode_path", None)
+        raise ValueError(
+            f"no decode path for {why}" if why else
+            "model has no lm_meta — build it with TransformerLM(...) to "
+            "generate")
+    return meta

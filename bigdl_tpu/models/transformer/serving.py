@@ -43,6 +43,7 @@ import numpy as np
 from bigdl_tpu.models.transformer.generate import (
     GenerationConfig, _embed, _ffn, _linear, _ln, _logits, _model_parts,
     _proj, _sample, _split_heads)
+from bigdl_tpu.models.transformer.model import decode_meta
 from bigdl_tpu.observability import compile_watch as _compile_watch
 from bigdl_tpu.observability import trace
 from bigdl_tpu.observability.registry import default_registry
@@ -236,7 +237,7 @@ def generate_ragged(model, prompts, config: GenerationConfig | None = None,
         batch[i, :len(p)] = np.asarray(p, np.int32)
         batch[i, len(p):] = 1                    # in-vocab padding id
     params = model.params if params is None else params
-    meta = model.lm_meta
+    meta = decode_meta(model)
     if pmax + config.max_new_tokens > meta["max_len"]:
         raise ValueError(f"longest prompt {pmax} + new "
                          f"{config.max_new_tokens} exceeds max_len "
@@ -437,7 +438,7 @@ def paged_prefill(model, cache: PagedKVCache, table, prompts, *,
     both straight into :func:`paged_decode`; pool arrays inside
     ``cache`` are rebound."""
     params = model.params if params is None else params
-    meta = model.lm_meta
+    meta = decode_meta(model)
     if lengths is None:
         lengths = np.asarray([len(p) for p in prompts], np.int32)
         pmax = int(lengths.max())
@@ -574,7 +575,7 @@ def paged_suffix_prefill(model, cache: PagedKVCache, table, suffixes, *,
     — and BITWISE the same tokens full prefill would have produced,
     on the dense and kernel paths alike (test-pinned)."""
     params = model.params if params is None else params
-    meta = model.lm_meta
+    meta = decode_meta(model)
     start = np.asarray(start, np.int32)
     lengths = np.asarray(lengths, np.int32)
     batch = np.asarray(suffixes, np.int32) \
@@ -709,7 +710,7 @@ def paged_decode(model, cache: PagedKVCache, table, lengths, last_tokens,
     old arrays are donated garbage)."""
     config = config or GenerationConfig(max_new_tokens=n_new)
     params = model.params if params is None else params
-    meta = model.lm_meta
+    meta = decode_meta(model)
     table = np.asarray(table, np.int32)
     lengths = np.asarray(lengths, np.int32)
     capacity = table.shape[1] * cache.page_size
@@ -862,7 +863,7 @@ def _compile_decode_step(model, cache: PagedKVCache, table, lengths,
     ``paged_decode_step[<kernel>]`` — the routing that lets its
     cost/memory analysis prove what the step materializes."""
     params = model.params if params is None else params
-    meta = model.lm_meta
+    meta = decode_meta(model)
     kernel = _resolve_paged_kernel(
         paged_kernel, lambda: _pool_kernel_supported(cache))
     policy_key = (str(activation_dtype()), str(compute_dtype()))
@@ -1234,7 +1235,7 @@ def speculative_generate(model, draft_model, prompts, *,
         raise ValueError(f"gamma must be >= 1, got {gamma}")
     if temperature < 0:
         raise ValueError(f"temperature must be >= 0, got {temperature}")
-    t_meta, d_meta = model.lm_meta, draft_model.lm_meta
+    t_meta, d_meta = decode_meta(model), draft_model.lm_meta
     lengths = np.asarray([len(p) for p in prompts], np.int32)
     pmax = int(lengths.max())
     if pmax + max_new_tokens + gamma > min(t_meta["max_len"],
@@ -1448,7 +1449,7 @@ class ContinuousBatcher:
                  watch=None, health_name: str = "serving_batcher",
                  on_complete=None, on_prefill=None, paged_kernel=None,
                  aot_cache=None, weight_version=None):
-        meta = model.lm_meta
+        meta = decode_meta(model)
         self.model = model
         # which published weight set this batcher serves (deploy plane;
         # None = unversioned). Exported KVSnapshots carry it and
@@ -1686,7 +1687,7 @@ class ContinuousBatcher:
                 f"cannot swap weights with {len(self.queue)} queued and "
                 f"{sum(s is not None for s in self.slots)} in-flight "
                 "requests — drain the replica first")
-        new, old = model.lm_meta, self.model.lm_meta
+        new, old = decode_meta(model), self.model.lm_meta
         keys = ("num_layers", "num_heads", "num_kv_heads", "max_len")
         if any(new.get(k) != old.get(k) for k in keys):
             raise ValueError(

@@ -3,7 +3,8 @@
 from bigdl_tpu.nn.module import Module, Container, Criterion, Identity, Echo
 from bigdl_tpu.nn.containers import (Sequential, Concat, ConcatTable,
                                      ParallelTable, MapTable, Bottle, Remat)
-from bigdl_tpu.nn.linear import (Linear, Bilinear, LookupTable, Cosine,
+from bigdl_tpu.nn.linear import (Linear, GatedFFN, Bilinear, LookupTable,
+                                 Cosine,
                                  Euclidean, Add, CAdd, CMul, Mul, MM, MV)
 from bigdl_tpu.nn.activations import (
     ReLU, ReLU6, PReLU, RReLU, LeakyReLU, ELU, Tanh, TanhShrink, Sigmoid,
@@ -20,7 +21,7 @@ from bigdl_tpu.nn.normalization import (
     BatchNormalization, SpatialBatchNormalization, SpatialCrossMapLRN,
     ReLUCrossMapLRN, Normalize, SpatialDivisiveNormalization,
     SpatialSubtractiveNormalization, SpatialContrastiveNormalization,
-    LayerNorm)
+    LayerNorm, RMSNorm)
 from bigdl_tpu.nn.dropout import Dropout, L1Penalty
 from bigdl_tpu.nn.structural import (
     Reshape, InferReshape, View, Transpose, Squeeze, Unsqueeze, Select,
@@ -34,7 +35,7 @@ from bigdl_tpu.nn.table_ops import (CAddTable, CSubTable, CMulTable,
                                     MaskedSelect)
 from bigdl_tpu.nn.recurrent import (Cell, RnnCell, RNN, LSTM, GRU, Recurrent,
                                     BiRecurrent, TimeDistributed)
-from bigdl_tpu.nn.attention import MultiHeadAttention
+from bigdl_tpu.nn.attention import MultiHeadAttention, EvaAttention
 from bigdl_tpu.nn.criterion import (
     ClassNLLCriterion, MSECriterion, BCECriterion, CrossEntropyCriterion,
     ClassSimplexCriterion, AbsCriterion, CosineEmbeddingCriterion,
@@ -43,6 +44,7 @@ from bigdl_tpu.nn.criterion import (
     MultiCriterion, MultiLabelMarginCriterion, MultiLabelSoftMarginCriterion,
     MultiMarginCriterion, SmoothL1Criterion, SmoothL1CriterionWithWeights,
     SoftMarginCriterion, SoftmaxWithCriterion, ParallelCriterion,
-    TimeDistributedCriterion, CriterionTable, MaskedCriterion)
+    TimeDistributedCriterion, CriterionTable, MaskedCriterion,
+    MultiBytePredictionCriterion)
 from bigdl_tpu.nn.detection import Nms, nms
 from bigdl_tpu.nn import init  # noqa: F401

@@ -15,7 +15,8 @@ from bigdl_tpu.nn import init as init_mod
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.tensor import activation_dtype, compute_dtype, default_dtype
 
-__all__ = ["MultiHeadAttention", "apply_rope"]
+__all__ = ["MultiHeadAttention", "EvaAttention", "apply_rope",
+           "eva_chunk_summaries", "eva_attention_xla"]
 
 
 def apply_rope(x, positions, theta: float = 10000.0):
@@ -157,3 +158,122 @@ class MultiHeadAttention(Module):
         return (f"MultiHeadAttention({self.embed_dim}, "
                 f"heads={self.num_heads}, causal={self.causal}, "
                 f"sp={self.sequence_parallel})")
+
+
+_NEG = -1e9  # finite mask value, as parallel/sequence.py
+
+
+def eva_chunk_summaries(k, v, phi, mu, chunk: int):
+    """One summary key and value per chunk of ``chunk`` tokens: with
+    a_j = softmax over the chunk's j of (k_j . phi), k~ = sum_j a_j k_j +
+    mu and v~ = sum_j a_j v_j. ``k, v``: (B, S, H, D); ``phi, mu``:
+    (H, D); returns two (B, S / chunk, H, D) arrays of k's dtype. The
+    pooling scores, the softmax and the sums are float32."""
+    b, s, h, d = k.shape
+    f32 = jnp.float32
+    kc = k.astype(f32).reshape(b, s // chunk, chunk, h, d)
+    vc = v.astype(f32).reshape(b, s // chunk, chunk, h, d)
+    a = jax.nn.softmax(jnp.einsum("bnchd,hd->bnch", kc, phi.astype(f32)),
+                       axis=2)[..., None]
+    ks = jnp.sum(a * kc, axis=2) + mu.astype(f32)
+    vs = jnp.sum(a * vc, axis=2)
+    return ks.astype(k.dtype), vs.astype(v.dtype)
+
+
+def eva_attention_xla(q, k, v, ks, vs, *, window: int, chunk: int,
+                      scale: float | None = None):
+    """EVA attention in plain ``jax.numpy`` — the semantics the kernel
+    (ops/pallas/eva_attention.py) is tested against, and the path off the
+    TPU: query i attends its own window's keys j <= i exactly and every
+    EARLIER window's chunk summaries, under one softmax. Materialises
+    (W, W + S / chunk) scores a window: small sizes only."""
+    b, s, h, d = q.shape
+    nw, per = s // window, window // chunk
+    f32 = jnp.float32
+    scale = scale if scale is not None else d ** -0.5
+    qw, kw, vw = (x.astype(f32).reshape(b, nw, window, h, d)
+                  for x in (q, k, v))
+    local = jnp.einsum("bnqhd,bnkhd->bnhqk", qw, kw) * scale
+    pos = jnp.arange(window)
+    local = jnp.where(pos[None, :] > pos[:, None], _NEG, local)
+    remote = jnp.einsum("bnqhd,bchd->bnhqc", qw, ks.astype(f32)) * scale
+    # summary c belongs to window c // per; a window sees those before it
+    before = jnp.arange(nw * per)[None, :] // per < jnp.arange(nw)[:, None]
+    remote = jnp.where(before[None, :, None, None, :], remote, _NEG)
+    p = jax.nn.softmax(jnp.concatenate([local, remote], axis=-1), axis=-1)
+    o = (jnp.einsum("bnhqk,bnkhd->bnqhd", p[..., :window], vw)
+         + jnp.einsum("bnhqc,bchd->bnqhd", p[..., window:],
+                      vs.astype(f32)))
+    return o.reshape(b, s, h, d).astype(q.dtype)
+
+
+class EvaAttention(Module):
+    """EVA attention (Zheng et al., arXiv:2302.04542) as EvaByte uses it:
+    causal self-attention over (batch, seq, embed) that is exact inside a
+    window of ``window`` tokens and sees every earlier window through one
+    learned summary per ``chunk`` tokens (``eva_chunk_summaries``, made
+    once a call), both under one softmax; linear in seq. Bias-free
+    projections named as ``MultiHeadAttention`` names them, RoPE on q and
+    k (before the pooling, so summaries carry rotated keys), and per head
+    the pooling query ``phi`` and the summary-key offset ``mu``, each
+    (heads, head_dim). docs/eva_attention.md has the equations.
+
+    On the TPU the core is the Pallas kernel and shapes it does not take
+    are an error, never another path; elsewhere (the CPU tests) it is
+    ``eva_attention_xla``. seq must be a multiple of ``window``."""
+
+    def __init__(self, embed_dim: int, num_heads: int, window: int,
+                 chunk: int, rope_theta: float = 10000.0):
+        super().__init__()
+        assert embed_dim % num_heads == 0
+        if window % chunk:
+            raise ValueError(f"EvaAttention: window {window} is not a "
+                             f"multiple of chunk {chunk}")
+        self.embed_dim, self.num_heads = embed_dim, num_heads
+        self.head_dim = embed_dim // num_heads
+        assert self.head_dim % 2 == 0, "rope needs an even head_dim"
+        self.window, self.chunk = window, chunk
+        self.rope_theta = rope_theta
+
+    def init(self, rng):
+        ks = jax.random.split(rng, 6)
+        e, hd = self.embed_dim, (self.num_heads, self.head_dim)
+        p = {f"{name}_weight": init_mod.init_weight(
+                init_mod.Xavier, k, (e, e), fan_in=e, fan_out=e)
+             for name, k in zip(("q", "k", "v", "out"), ks)}
+        p.update({name: jax.random.normal(key, hd, default_dtype())
+                  * self.head_dim ** -0.5
+                  for name, key in zip(("phi", "mu"), ks[4:])})
+        return p
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        b, s, e = x.shape
+        if s % self.window:
+            raise ValueError(
+                f"EvaAttention: sequence length {s} is not a multiple of "
+                f"window {self.window}")
+        cd = compute_dtype()
+        h = x.astype(cd)
+        q, k, v = (jnp.matmul(h, params[f"{n}_weight"].astype(cd).T)
+                   .reshape(b, s, self.num_heads, self.head_dim)
+                   for n in "qkv")
+        pos = jnp.arange(s)
+        q = apply_rope(q, pos, self.rope_theta)
+        k = apply_rope(k, pos, self.rope_theta)
+        with jax.named_scope("eva_prep_kv"):
+            ks, vs = eva_chunk_summaries(k, v, params["phi"],
+                                         params["mu"], self.chunk)
+        with jax.named_scope("eva_attention"):
+            if jax.default_backend() == "tpu":
+                from bigdl_tpu.ops.pallas.eva_attention import eva_attention
+                core = eva_attention
+            else:
+                core = eva_attention_xla
+            o = core(q, k, v, ks, vs, window=self.window, chunk=self.chunk)
+        y = jnp.matmul(o.reshape(b, s, e),
+                       params["out_weight"].astype(cd).T)
+        return y.astype(activation_dtype()), state
+
+    def __repr__(self):
+        return (f"EvaAttention({self.embed_dim}, heads={self.num_heads}, "
+                f"window={self.window}, chunk={self.chunk})")

@@ -23,9 +23,32 @@ class Sequential(Container):
     ``<index>_<ClassName>`` — so a profiler trace of a compiled step
     names device operations by module (``jvp(model)/block_3/...``):
     the reference's per-module forward/backward time in the form a
-    compiled step can give it."""
+    compiled step can give it.
+
+    ``set_remat(policy)`` makes a recomputation policy of optim/remat.py
+    part of the model AS BUILT: ``apply`` then IS
+    ``remat_forward(self, policy)``, for whoever differentiates it — the
+    optimizers' step or a bare ``jax.grad``. An optimizer given a policy
+    of its own differentiates ``apply_plain`` under that one instead, so
+    nothing is recomputed twice."""
+
+    remat_policy: str | None = None
+
+    def set_remat(self, policy: str | None):
+        from bigdl_tpu.optim.remat import check_remat_policy
+        policy = check_remat_policy(policy)
+        self.remat_policy = None if policy == "none" else policy
+        return self
 
     def apply(self, params, state, x, *, training=False, rng=None):
+        if self.remat_policy is not None:
+            from bigdl_tpu.optim.remat import remat_forward
+            return remat_forward(self, self.remat_policy)(
+                params, state, x, training=training, rng=rng)
+        return self.apply_plain(params, state, x, training=training,
+                                rng=rng)
+
+    def apply_plain(self, params, state, x, *, training=False, rng=None):
         new_state = {}
         for i, m in enumerate(self.modules):
             # never get_name()'s default: it holds id(self)

@@ -28,6 +28,7 @@ __all__ = ["ClassNLLCriterion", "MSECriterion", "BCECriterion",
            "MarginCriterion", "MarginRankingCriterion", "MultiCriterion",
            "MultiLabelMarginCriterion", "MultiLabelSoftMarginCriterion",
            "MultiMarginCriterion", "SmoothL1Criterion",
+           "MultiBytePredictionCriterion",
            "SmoothL1CriterionWithWeights", "SoftMarginCriterion",
            "SoftmaxWithCriterion", "ParallelCriterion",
            "TimeDistributedCriterion", "CriterionTable", "MaskedCriterion"]
@@ -539,3 +540,35 @@ class MaskedCriterion(Criterion):
                 x, target)
         m = mask.astype(per_row.dtype)
         return jnp.sum(per_row * m), jnp.sum(m)
+
+
+class MultiBytePredictionCriterion(Criterion):
+    """Cross-entropy of ``num_heads`` prediction heads, head i (1-based)
+    at position t scored against the token i steps ahead (multi-byte
+    prediction, as EvaByte's ``num_pred_heads``; head 1 alone is
+    next-token cross-entropy).
+
+    ``x``: (B, S, num_heads * vocab) logits, head-major in the last axis;
+    ``target``: the (B, S) 1-based NEXT-token labels the input pipeline
+    yields (target[t] = token t + 1), shifted here: head i reads
+    target[t + i - 1] and has no target in the last i - 1 positions,
+    which are left out. Each head's loss is the mean over its own
+    positions and the heads weigh equally."""
+
+    def __init__(self, num_heads: int, vocab: int):
+        super().__init__()
+        self.num_heads, self.vocab = num_heads, vocab
+
+    def apply(self, x, target):
+        b, s, _ = x.shape
+        h = self.num_heads
+        logits = x.reshape(b, s, h, self.vocab).astype(
+            jnp.promote_types(x.dtype, jnp.float32))
+        ahead = jnp.arange(s)[:, None] + jnp.arange(h)[None, :]   # (S, H)
+        valid = ahead < s
+        t = (target.astype(jnp.int32) - 1)[:, jnp.minimum(ahead, s - 1)]
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, t[..., None], axis=-1)[..., 0]
+        per_head = jnp.sum(jnp.where(valid, lse - picked, 0.0),
+                           axis=(0, 1)) / (b * jnp.sum(valid, axis=0))
+        return jnp.mean(per_head)

@@ -27,8 +27,11 @@ def uniform_reset(rng, shape, stdv, dtype=None):
 
 
 def init_weight(method, rng, shape, fan_in, fan_out, dtype=None):
-    """Dispatch on init method (reference InitializationMethod.scala)."""
+    """Dispatch on init method (reference InitializationMethod.scala);
+    a callable ``method(rng, shape, dtype)`` is the initialiser itself."""
     dtype = dtype or default_dtype()
+    if callable(method):
+        return method(rng, shape, dtype)
     if method == Default:
         stdv = 1.0 / np.sqrt(fan_in)
         return uniform_reset(rng, shape, stdv, dtype)

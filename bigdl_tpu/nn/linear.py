@@ -14,7 +14,8 @@ from bigdl_tpu.nn import init as init_mod
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.tensor import activation_dtype, compute_dtype, default_dtype
 
-__all__ = ["Linear", "Bilinear", "LookupTable", "Cosine", "Euclidean",
+__all__ = ["Linear", "GatedFFN", "Bilinear", "LookupTable", "Cosine",
+           "Euclidean",
            "Add", "CAdd", "CMul", "Mul", "MM", "MV"]
 
 
@@ -24,12 +25,16 @@ class Linear(Module):
 
     def __init__(self, input_size: int, output_size: int,
                  with_bias: bool = True,
-                 init_method: str = init_mod.Default):
+                 init_method: str = init_mod.Default,
+                 output_dtype=None):
         super().__init__()
         self.input_size = input_size
         self.output_size = output_size
         self.with_bias = with_bias
         self.init_method = init_method
+        # e.g. float32 logits from bf16 operands: the matmul's own f32
+        # accumulator is the output, not a rounded copy of it widened
+        self.output_dtype = output_dtype
 
     def init(self, rng):
         kw, kb = jax.random.split(rng)
@@ -46,6 +51,12 @@ class Linear(Module):
 
     def apply(self, params, state, x, *, training=False, rng=None):
         w = params["weight"].astype(compute_dtype())
+        if self.output_dtype is not None:
+            y = jnp.matmul(x.astype(compute_dtype()), w.T,
+                           preferred_element_type=self.output_dtype)
+            if self.with_bias:
+                y = y + params["bias"].astype(self.output_dtype)
+            return y, state
         y = jnp.matmul(x.astype(compute_dtype()), w.T)
         if self.with_bias:
             y = y + params["bias"].astype(compute_dtype())
@@ -53,6 +64,39 @@ class Linear(Module):
 
     def __repr__(self):
         return f"Linear({self.input_size} -> {self.output_size})"
+
+
+class GatedFFN(Module):
+    """y = W_down( act(W_gate x) * (W_up x) ), three bias-free matrices
+    (Shazeer, arXiv:2002.05202; ``act="silu"`` is SwiGLU). ``act`` is a
+    callable or the name of one in ``jax.nn``."""
+
+    def __init__(self, d_model: int, d_ff: int, act="silu"):
+        super().__init__()
+        self.d_model, self.d_ff = d_model, d_ff
+        self.act = getattr(jax.nn, act) if isinstance(act, str) else act
+
+    def init(self, rng):
+        shapes = {"gate_weight": (self.d_ff, self.d_model),
+                  "up_weight": (self.d_ff, self.d_model),
+                  "down_weight": (self.d_model, self.d_ff)}
+        return {name: init_mod.init_weight(
+                    init_mod.Default, k, shape, fan_in=shape[1],
+                    fan_out=shape[0])
+                for (name, shape), k in zip(
+                    shapes.items(), jax.random.split(rng, len(shapes)))}
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        cd = compute_dtype()
+        h = x.astype(cd)
+        gate = jnp.matmul(h, params["gate_weight"].astype(cd).T)
+        up = jnp.matmul(h, params["up_weight"].astype(cd).T)
+        y = jnp.matmul(self.act(gate) * up,
+                       params["down_weight"].astype(cd).T)
+        return y.astype(activation_dtype()), state
+
+    def __repr__(self):
+        return f"GatedFFN({self.d_model} -> {self.d_ff} -> {self.d_model})"
 
 
 class Bilinear(Module):

@@ -20,10 +20,11 @@ import numpy as np
 from bigdl_tpu.nn.containers import Sequential as _Sequential
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.ops import pow_neg_beta as _pow_neg_beta
-from bigdl_tpu.tensor import default_dtype
+from bigdl_tpu.tensor import activation_dtype, default_dtype
 
 __all__ = ["BatchNormalization", "SpatialBatchNormalization",
            "SpatialCrossMapLRN", "ReLUCrossMapLRN", "Normalize", "LayerNorm",
+           "RMSNorm",
            "SpatialDivisiveNormalization", "SpatialSubtractiveNormalization",
            "SpatialContrastiveNormalization"]
 
@@ -401,3 +402,40 @@ class LayerNorm(Module):
             y = y * params["weight"].astype(f32) \
                 + params["bias"].astype(f32)
         return y.astype(x.dtype), state
+
+
+class RMSNorm(Module):
+    """y = x / sqrt(mean(x^2) + eps) * w over the trailing feature axis,
+    no mean subtracted and no bias (Zhang & Sennrich, arXiv:1910.07467).
+
+    ``unit_offset=True`` stores w - 1 (initialised to zero) and scales by
+    1 + g, so weight decay pulls the scale towards one. The mean of
+    squares and the division are float32 whatever x is; the result is
+    rounded ONCE to the policy's activation dtype — a float32 residual
+    stream comes out as activations — and scaled there, or stays float32
+    throughout under ``fp32=True``."""
+
+    def __init__(self, n_output: int, eps: float = 1e-5,
+                 unit_offset: bool = False, fp32: bool = False):
+        super().__init__()
+        self.n_output, self.eps = n_output, eps
+        self.unit_offset, self.fp32 = unit_offset, fp32
+
+    def init(self, rng):
+        fill = jnp.zeros if self.unit_offset else jnp.ones
+        return {"weight": fill((self.n_output,), default_dtype())}
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        f32 = jnp.promote_types(x.dtype, jnp.float32)
+        xs = x.astype(f32)
+        inv = jax.lax.rsqrt(jnp.mean(jnp.square(xs), axis=-1,
+                                     keepdims=True) + self.eps)
+        dt = f32 if self.fp32 else activation_dtype()
+        w = params["weight"].astype(dt)
+        if self.unit_offset:
+            w = 1.0 + w
+        return (xs * inv).astype(dt) * w, state
+
+    def __repr__(self):
+        return (f"RMSNorm({self.n_output}, eps={self.eps}, "
+                f"unit_offset={self.unit_offset})")

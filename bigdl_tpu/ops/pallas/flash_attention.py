@@ -385,14 +385,13 @@ def _walk_to_diagonal(qi, bq, bk, step):
         + [(qi * bq, bq, True)]))
 
 
-def _fwd_looped_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       m_scr, l_scr, acc_scr, *, scale, bq, bk, block):
-    """One q block of causal self-attention against the head's K/V in
-    VMEM (``_walk_to_diagonal``). The bq x bq block on the diagonal is
-    cut into ``block``-sided squares: each group of ``block`` q rows
-    takes the K rows up to its own square, which alone is masked."""
-    _softmax_init(m_scr, l_scr, acc_scr)
-    q, s_scale = _fold_scale(q_ref[0], scale)
+def _walk_forward(q, s_scale, k_ref, v_ref, qi, m_scr, l_scr, acc_scr, *,
+                  bq, bk, block):
+    """The online-softmax steps of q block ``qi`` (bq rows, counted from
+    row 0 of ``k_ref``) of causal self-attention against K/V in VMEM
+    (``_walk_to_diagonal``). The bq x bq block on the diagonal is cut
+    into ``block``-sided squares: each group of ``block`` q rows takes
+    the K rows up to its own square, which alone is masked."""
 
     def rows(at, n):
         at = pl.multiple_of(at, block)
@@ -410,7 +409,17 @@ def _fwd_looped_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                               (0, lo)))
         _softmax_step(q, split, m_scr, l_scr, acc_scr, s_scale)
 
-    _walk_to_diagonal(pl.program_id(1), bq, bk, step)
+    _walk_to_diagonal(qi, bq, bk, step)
+
+
+def _fwd_looped_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                       m_scr, l_scr, acc_scr, *, scale, bq, bk, block):
+    """One q block of causal self-attention against the head's K/V in
+    VMEM (``_walk_forward``)."""
+    _softmax_init(m_scr, l_scr, acc_scr)
+    q, s_scale = _fold_scale(q_ref[0], scale)
+    _walk_forward(q, s_scale, k_ref, v_ref, pl.program_id(1), m_scr, l_scr,
+                  acc_scr, bq=bq, bk=bk, block=block)
     _softmax_finish(o_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
@@ -492,42 +501,37 @@ def _fwd(q, k, v, scale, causal, interpret):
 # accumulators fit VMEM; else dq in one kernel, dk/dv fused in another.
 # --------------------------------------------------------------------------
 
-def _dqdkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
-                   *, scale, nq, bq, bk, block):
-    """One q block of causal self-attention against the head's K/V in
-    VMEM (``_walk_to_diagonal``), all of the backward in one pass. Tiles
-    are computed TRANSPOSED, k rows by q columns: the q block's row
-    statistics broadcast along sublanes from lane-dense (1, bq) rows, the
-    q block and its dO are the stationary operand of four of the five
-    matmuls however many K rows a step streams past them, dv and dk are
-    plain matmuls and only dq contracts the tile's first dim. The block
-    on the diagonal goes a ``block`` of K rows at a time, each against
-    the q rows from its own square on. dk and dv accumulate over the q
-    blocks of a head in ``dk_scr`` / ``dv_scr``."""
-    qi = pl.program_id(1)
+def _backward_piece(q, do, s_scale, k_ref, v_ref, lse_ref, delta_ref,
+                    dq_scr, dk_scr, dv_scr, rows, lo, masked):
+    """Rows ``rows`` of K/V against q rows lo.. of the block, all of the
+    backward: the tile is computed TRANSPOSED, k rows by q columns, so
+    the q block's row statistics broadcast along sublanes from
+    lane-dense (1, bq) rows, the q block and its dO are the stationary
+    operand of four of the five matmuls however many K rows stream past
+    them, dv and dk are plain matmuls and only dq contracts the tile's
+    first dim."""
+    k, v = k_ref[0, rows, :], v_ref[0, rows, :]
+    st = _scores(k, q[lo:], s_scale, (1, 0) if masked else None)
+    pt = jnp.exp(st - lse_ref[0, :, lo:])                   # (n, bq - lo)
+    dv_scr[rows, :] = dv_scr[rows, :] + _dot(
+        pt.astype(do.dtype), do[lo:], _NN)
+    dst = (pt * (_dot(v, do[lo:], _NT) - delta_ref[0, :, lo:])
+           ).astype(q.dtype)
+    dk_scr[rows, :] = dk_scr[rows, :] + _dot(dst, q[lo:], _NN)
+    dq_scr[lo:, :] = dq_scr[lo:, :] + _dot(dst, k, _TN)
 
-    @pl.when(qi == 0)
-    def _new_head():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    dq_scr[:] = jnp.zeros_like(dq_scr)
-    q, s_scale = _fold_scale(q_ref[0], scale)
-    do = do_ref[0]
+def _walk_backward(q, do, s_scale, k_ref, v_ref, lse_ref, delta_ref,
+                   dq_scr, dk_scr, dv_scr, qi, *, bq, bk, block):
+    """The backward of q block ``qi`` (counted from row 0 of ``k_ref``)
+    of causal self-attention against K/V in VMEM (``_walk_to_diagonal``,
+    ``_backward_piece``). The block on the diagonal goes a ``block`` of
+    K rows at a time, each against the q rows from its own square on."""
 
     def piece(at, n, lo, masked):
-        """K rows [at, at + n) against q rows lo.. of the block."""
-        rows = pl.ds(pl.multiple_of(at, block), n)
-        k, v = k_ref[0, rows, :], v_ref[0, rows, :]
-        st = _scores(k, q[lo:], s_scale, (1, 0) if masked else None)
-        pt = jnp.exp(st - lse_ref[0, :, lo:])               # (n, bq - lo)
-        dv_scr[rows, :] = dv_scr[rows, :] + _dot(
-            pt.astype(do.dtype), do[lo:], _NN)
-        dst = (pt * (_dot(v, do[lo:], _NT) - delta_ref[0, :, lo:])
-               ).astype(q.dtype)
-        dk_scr[rows, :] = dk_scr[rows, :] + _dot(dst, q[lo:], _NN)
-        dq_scr[lo:, :] = dq_scr[lo:, :] + _dot(dst, k, _TN)
+        _backward_piece(q, do, s_scale, k_ref, v_ref, lse_ref, delta_ref,
+                        dq_scr, dk_scr, dv_scr,
+                        pl.ds(pl.multiple_of(at, block), n), lo, masked)
 
     def step(pieces):
         for at, n, masked in pieces:
@@ -538,6 +542,26 @@ def _dqdkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 piece(at + lo, block, lo, True)
 
     _walk_to_diagonal(qi, bq, bk, step)
+
+
+def _dqdkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                   *, scale, nq, bq, bk, block):
+    """One q block of causal self-attention against the head's K/V in
+    VMEM, all of the backward in one pass (``_walk_backward``). dk and
+    dv accumulate over the q blocks of a head in ``dk_scr`` /
+    ``dv_scr``."""
+    qi = pl.program_id(1)
+
+    @pl.when(qi == 0)
+    def _new_head():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    dq_scr[:] = jnp.zeros_like(dq_scr)
+    q, s_scale = _fold_scale(q_ref[0], scale)
+    _walk_backward(q, do_ref[0], s_scale, k_ref, v_ref, lse_ref, delta_ref,
+                   dq_scr, dk_scr, dv_scr, qi, bq=bq, bk=bk, block=block)
     dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
     # dk saw q * scale when the scale folded; else it takes it here, once

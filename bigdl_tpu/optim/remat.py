@@ -78,9 +78,17 @@ def remat_forward(model, policy):
     ``"none"`` returns ``model.apply`` untouched — the plain step is
     EXACTLY the pre-remat construction (golden fixtures unaffected).
     ``"per_block"`` checkpoints each top-level child of a ``Sequential``
-    with the child-index rng fold mirrored from ``Sequential.apply`` so
-    dropout draws land identically; non-Sequential models degrade to a
+    with the child-index rng fold and name scope mirrored from
+    ``Sequential.apply`` so dropout draws land identically and a trace
+    names the same modules; non-Sequential models degrade to a
     whole-forward checkpoint (logged).
+
+    A model may carry a policy of its own (``Sequential.set_remat``: the
+    recipe it was built with). Its ``apply`` is then this function's
+    result for that policy, which is what ``"none"`` returns here: the
+    model's own. Any other ``policy`` wins over it: this function wraps
+    the model's PLAIN forward (``apply_plain``), so the model's policy is
+    not applied a second time inside the caller's.
     """
     import jax
 
@@ -90,6 +98,7 @@ def remat_forward(model, policy):
     policy = check_remat_policy(policy)
     if policy == "none":
         return model.apply
+    plain = getattr(model, "apply_plain", model.apply)
 
     if policy == "per_block":
         if not isinstance(model, Sequential):
@@ -100,7 +109,7 @@ def remat_forward(model, policy):
 
             def whole(params, state, x, *, training=False, rng=None):
                 def inner(p, s, xx, r):
-                    return model.apply(p, s, xx, training=training, rng=r)
+                    return plain(p, s, xx, training=training, rng=r)
                 return jax.checkpoint(inner)(params, state, x, rng)
 
             return whole
@@ -114,8 +123,9 @@ def remat_forward(model, policy):
                 def block(p, s, xx, r, _m=m):
                     return _m.apply(p, s, xx, training=training, rng=r)
 
-                x, s = jax.checkpoint(block)(params[str(i)], state[str(i)],
-                                             x, _fold(rng, i))
+                with jax.named_scope(m._name or f"{i}_{type(m).__name__}"):
+                    x, s = jax.checkpoint(block)(
+                        params[str(i)], state[str(i)], x, _fold(rng, i))
                 new_state[str(i)] = s
             return x, new_state
 
@@ -125,7 +135,7 @@ def remat_forward(model, policy):
 
     def whole_forward(params, state, x, *, training=False, rng=None):
         def inner(p, s, xx, r):
-            return model.apply(p, s, xx, training=training, rng=r)
+            return plain(p, s, xx, training=training, rng=r)
 
         return jax.checkpoint(inner, policy=chk_policy)(params, state, x,
                                                         rng)

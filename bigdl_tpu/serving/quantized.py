@@ -219,8 +219,9 @@ def _impls_cached():
 def _statics(model, qcache, *, paged_kernel):
     from bigdl_tpu.models.transformer.serving import (
         _pool_kernel_supported, _resolve_paged_kernel)
+    from bigdl_tpu.models.transformer.model import decode_meta
     from bigdl_tpu.tensor import activation_dtype, compute_dtype
-    meta = model.lm_meta
+    meta = decode_meta(model)
     kernel = _resolve_paged_kernel(
         paged_kernel, lambda: _pool_kernel_supported(qcache))
     return dict(

@@ -151,6 +151,28 @@ class TestFlashCompilesForTheV5e:
         jax.jit(fn).lower(q, kv, kv).compile()
 
 
+class TestEvaCompilesForTheV5e:
+    """Mosaic's verdict on the EVA kernels at the benchmark cell's shape
+    (evabyte-6.5b.train.long: 32 heads of 16384 x 128, window 2048, chunk
+    16), forward and backward in one program: VMEM for a window's K/V and
+    dk/dv, the head's summaries and their gradients, the static slices of
+    the summaries."""
+
+    @pytest.mark.parametrize("seq", [16384, 8192], ids=["s16384", "s8192"])
+    def test_window_and_summaries_fwd_bwd(self, one_chip, seq):
+        from bigdl_tpu.ops.pallas.eva_attention import eva_attention
+        fn = _scalar_grads(
+            lambda q, k, v, ks, vs: eva_attention(q, k, v, ks, vs,
+                                                  window=2048, chunk=16), 5)
+        x = jax.ShapeDtypeStruct((1, seq, 32, 128), BF16, sharding=one_chip)
+        s = jax.ShapeDtypeStruct((1, seq // 16, 32, 128), BF16,
+                                 sharding=one_chip)
+        text = jax.jit(fn).lower(x, x, x, s, s).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        assert "eva_attention_fwd" in text
+        assert "eva_attention_dqdkdv" in text
+
+
 class TestPredicatesMatchTheLowering:
     """Whatever a ``*_supported`` predicate accepts on a TPU lowers; what
     the lowering refuses reads as unsupported."""
