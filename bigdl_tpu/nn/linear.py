@@ -6,6 +6,8 @@ Reference parity: Linear (nn/Linear.scala, 218 LoC), Bilinear, LookupTable
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -66,10 +68,68 @@ class Linear(Module):
         return f"Linear({self.input_size} -> {self.output_size})"
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _gated_ffn(act, h, w_gate, w_up, w_down):
+    return _gated_ffn_fwd(act, h, w_gate, w_up, w_down)[0]
+
+
+def _gated_ffn_fwd(act, h, w_gate, w_up, w_down):
+    gate = jnp.matmul(h, w_gate.T)
+    up = jnp.matmul(h, w_up.T)
+    y = jnp.matmul(act(gate) * up, w_down.T)
+    return y, (h, gate, up, w_gate, w_up, w_down)
+
+
+def _gated_ffn_bwd(act, res, dy):
+    from bigdl_tpu.observability import trace
+    held = jax.lax.optimization_barrier
+    h, gate, up, w_gate, w_up, w_down = res
+    d_ff, d_model = w_gate.shape
+    tokens = gate.size // d_ff
+    # python runs this when the backward is traced for a compile, once a
+    # layer and never in a step
+    trace.instant("gated_ffn_backward", cat="nn", tokens=tokens,
+                  d_model=d_model, d_ff=d_ff,
+                  materialised_bytes=tokens * (4 * d_ff + 2 * d_model)
+                  * gate.dtype.itemsize)
+    # every token-side operand of the five matmuls is held as a tensor.
+    # Left to itself XLA fuses each one's producer (act and act' for a,
+    # d_gate and d_up; the cast of the residual stream's gradient for
+    # dy; the norm's scale for h) into the operand of every matmul that
+    # reads it, and evaluates it again as that matmul streams it. d_a
+    # too: the elementwise pass then rides the recomputed gate matmul
+    # and not the d_a matmul, which the v5e runs 3 ms a layer sooner
+    # (PERF.md section 6, PR 28)
+    dy, h = held((dy, h))
+    d_a = held(jnp.matmul(dy, w_down))
+    s, act_vjp = jax.vjp(act, gate)
+    a, d_gate, d_up = held((s * up, act_vjp(d_a * up)[0], d_a * s))
+
+    def over_tokens(x, y):
+        return jnp.matmul(x.reshape(tokens, -1).T, y.reshape(tokens, -1))
+
+    d_h = jnp.matmul(d_gate, w_gate) + jnp.matmul(d_up, w_up)
+    return (d_h, over_tokens(d_gate, h), over_tokens(d_up, h),
+            over_tokens(dy, a))
+
+
+_gated_ffn.defvjp(_gated_ffn_fwd, _gated_ffn_bwd)
+
+
 class GatedFFN(Module):
     """y = W_down( act(W_gate x) * (W_up x) ), three bias-free matrices
     (Shazeer, arXiv:2002.05202; ``act="silu"`` is SwiGLU). ``act`` is a
-    callable or the name of one in ``jax.nn``."""
+    callable or the name of one in ``jax.nn``.
+
+    The module defines its own backward (``jax.custom_vjp``): the five
+    matmuls autodiff writes, with ``act(gate) * up`` and the gradients
+    of ``gate`` and ``up`` computed in one elementwise pass and every
+    matmul operand kept as a tensor, because XLA otherwise evaluates
+    the activation and its derivative again inside every matmul that
+    reads them (PERF.md section 6, PR 28). So it is differentiable in
+    reverse mode only:
+    ``jax.jvp`` / ``jax.jacfwd`` (and ``jax.hessian``) through it raise;
+    nothing here differentiates it twice."""
 
     def __init__(self, d_model: int, d_ff: int, act="silu"):
         super().__init__()
@@ -88,11 +148,10 @@ class GatedFFN(Module):
 
     def apply(self, params, state, x, *, training=False, rng=None):
         cd = compute_dtype()
-        h = x.astype(cd)
-        gate = jnp.matmul(h, params["gate_weight"].astype(cd).T)
-        up = jnp.matmul(h, params["up_weight"].astype(cd).T)
-        y = jnp.matmul(self.act(gate) * up,
-                       params["down_weight"].astype(cd).T)
+        y = _gated_ffn(self.act, x.astype(cd),
+                       params["gate_weight"].astype(cd),
+                       params["up_weight"].astype(cd),
+                       params["down_weight"].astype(cd))
         return y.astype(activation_dtype()), state
 
     def __repr__(self):

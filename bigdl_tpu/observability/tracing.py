@@ -46,7 +46,7 @@ import time
 
 __all__ = ["Tracer", "get_tracer", "enable", "disable", "enabled",
            "span", "instant", "host_sync", "export", "to_dict", "clear",
-           "ProgramScopes"]
+           "ProgramScopes", "matmuls_fed_by"]
 
 _annotation_cls = None
 
@@ -97,6 +97,63 @@ _HLO_NO_OP = frozenset({"parameter", "get-tuple-element", "tuple",
                         "constant", "bitcast"})
 
 
+def _hlo_rows(hlo_text: str):
+    """``(module name, {computation: [(instruction, opcode, op_name or
+    None, [called computations], [operand instructions])]})`` of a
+    compiled program's text."""
+    program, current = "", ""
+    rows: dict[str, list] = {}
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            if line.startswith("HloModule "):
+                program = line.split()[1].rstrip(",")
+            else:
+                m = _HLO_COMPUTATION.match(line)
+                if m:
+                    current = m.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            op_name = _HLO_OP_NAME.search(line)
+            operands = line[m.end():].partition(")")[0].split(",")
+            rows.setdefault(current, []).append(
+                (m.group(1), m.group(2), op_name and op_name.group(1),
+                 _HLO_CALLED.findall(line),
+                 [o.split()[-1].lstrip("%") for o in operands if o.strip()]))
+    return program, rows
+
+
+def matmuls_fed_by(hlo_text: str, opcode: str) -> dict:
+    """``{instruction: op_name}`` of the operations of a compiled
+    program (``compiled.as_text()``) in which a ``convolution`` reads,
+    through producers fused into it, the result of an ``opcode``
+    instruction. Such a producer is evaluated as the matmul streams that
+    operand, once for every pass over it (PERF.md section 6, PR 28). An
+    ``opcode`` in the matmul's epilogue, after the ``convolution``, is
+    evaluated once and is not counted."""
+    _, rows = _hlo_rows(hlo_text)
+    called = {c for body in rows.values() for row in body for c in row[3]}
+
+    def holds(computation):
+        return any(op == opcode or any(map(holds, callees))
+                   for _, op, _, callees, _ in rows.get(computation, ()))
+
+    def fed(computation):
+        tainted = set()
+        for name, op, _, callees, operands in rows.get(computation, ()):
+            reads = not tainted.isdisjoint(operands)
+            if (op == "convolution" and reads) or any(map(fed, callees)):
+                return True
+            if reads or op == opcode or any(map(holds, callees)):
+                tainted.add(name)
+        return False
+
+    return {name: op_name or ""
+            for computation, body in rows.items() if computation not in called
+            for name, _, op_name, callees, _ in body
+            if any(map(fed, callees))}
+
+
 def _program_scopes(hlo_text: str) -> dict:
     """``{"program": <module name>, "scopes": {op_name: [instruction
     names]}, "inside": {scope: [instruction names]}}`` of a compiled
@@ -114,27 +171,11 @@ def _program_scopes(hlo_text: str) -> dict:
     instructions in the computations it calls, other than its own — XLA
     fuses a weight's optimizer update into that weight's gradient
     matmul, and the fusion reads as backward."""
-    program, current = "", ""
-    rows: dict[str, list] = {}          # computation -> its instructions
-    for line in hlo_text.splitlines():
-        if not line.startswith(" "):
-            if line.startswith("HloModule "):
-                program = line.split()[1].rstrip(",")
-            else:
-                m = _HLO_COMPUTATION.match(line)
-                if m:
-                    current = m.group(1)
-            continue
-        m = _HLO_INSTRUCTION.match(line)
-        if m:
-            op_name = _HLO_OP_NAME.search(line)
-            rows.setdefault(current, []).append(
-                (m.group(1), m.group(2), op_name and op_name.group(1),
-                 _HLO_CALLED.findall(line)))
+    program, rows = _hlo_rows(hlo_text)
     called = {c for body in rows.values() for row in body for c in row[3]}
 
     def scopes_in(computation, seen):
-        for _, _, op_name, callees in rows.get(computation, ()):
+        for _, _, op_name, callees, _ in rows.get(computation, ()):
             if op_name and "/" in op_name:
                 seen.add(op_name.rpartition("/")[0])
             for c in callees:
@@ -146,7 +187,7 @@ def _program_scopes(hlo_text: str) -> dict:
     for computation, body in rows.items():
         if computation in called:
             continue
-        for name, opcode, op_name, callees in body:
+        for name, opcode, op_name, callees, _ in body:
             if not op_name or opcode in _HLO_NO_OP:
                 continue
             scopes.setdefault(op_name, []).append(name)

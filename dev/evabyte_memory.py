@@ -32,6 +32,7 @@ def main():
     ap.add_argument("--seq", type=int, default=16384)
     ap.add_argument("--batch", type=int, default=1)
     ap.add_argument("--only", default="step,check,reference")
+    ap.add_argument("--text", help="write the compiled step's text here")
     args = ap.parse_args()
 
     from jax.experimental import topologies
@@ -41,6 +42,7 @@ def main():
     from benchmarks.builders import evabyte as builder
     from benchmarks.reference import evabyte as reference
     from bigdl_tpu import optim
+    from bigdl_tpu.observability.tracing import matmuls_fed_by
     from bigdl_tpu.optim.accumulation import make_train_step
 
     jax.config.update("jax_enable_compilation_cache", False)
@@ -70,7 +72,8 @@ def main():
 
     def report(name, lowered):
         t = time.time()
-        m = lowered.compile().memory_analysis()
+        compiled = lowered.compile()
+        m = compiled.memory_analysis()
         held = m.argument_size_in_bytes + m.output_size_in_bytes \
             - m.alias_size_in_bytes
         print(f"{name}: arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
@@ -80,6 +83,7 @@ def main():
               f"aliased + temporaries "
               f"{(held + m.temp_size_in_bytes) / 1e9:.2f}"
               f" GB (compiled in {time.time() - t:.0f} s)", flush=True)
+        return compiled
 
     only = args.only.split(",")
     if "step" in only:
@@ -90,8 +94,18 @@ def main():
                                update_fn=method.update)
         key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip)
         epoch = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
-        report("step", jax.jit(step, donate_argnums=(0, 1, 2)).lower(
-            params, state, opt_state, key, ids, ids, epoch))
+        text = report("step", jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            params, state, opt_state, key, ids, ids, epoch)).as_text()
+        if args.text:
+            with open(args.text, "w") as f:
+                f.write(text)
+        # a producer fused into a matmul's operand is evaluated again on
+        # every pass over it (PERF.md section 6, PR 28)
+        fed = matmuls_fed_by(text, "exponential")
+        backward = sum("transpose(" in scope for scope in fed.values())
+        print(f"step: {len(fed)} matmul fusions read an exponential "
+              f"through a fused producer, {backward} of them in the "
+              f"backward pass", flush=True)
     one = jax.ShapeDtypeStruct((1, args.seq), jnp.int32, sharding=chip)
     if "check" in only:
         def sys_loss(p, x, y):

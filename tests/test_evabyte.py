@@ -350,6 +350,95 @@ def test_gated_ffn_is_three_bias_free_matrices():
     assert float(jnp.max(jnp.abs(relu.apply(p, {}, x)[0] - want))) > 1e-3
 
 
+def _ffn_case(act, compute, remat):
+    """``(loss of the module, loss of the plain formula, params, x)``:
+    one ``GatedFFN`` in a ``Sequential``, recomputed as ``set_remat``
+    does it where asked, against ``(act(h Wg^T) * (h Wu^T)) Wd^T`` in the
+    same compute dtype, both weighted by one random cotangent."""
+    cd = jnp.dtype(compute)
+    ffn = nn.GatedFFN(16, 48, act=act)
+    model = nn.Sequential().add(ffn).set_remat(
+        "per_block" if remat else None)
+    params = {"0": ffn.init(jax.random.PRNGKey(0))}
+    x, cot = (jax.random.normal(jax.random.PRNGKey(k), (2, 24, 16))
+              for k in (1, 2))
+
+    def weighted(y):
+        return jnp.sum(y.astype(jnp.float32) * cot)
+
+    def system(p, x):
+        return weighted(model.apply(p, {"0": {}}, x, training=True)[0])
+
+    def formula(p, x, f=ffn.act):
+        h = x.astype(cd)
+        w = {k: v.astype(cd) for k, v in p["0"].items()}
+        return weighted((f(h @ w["gate_weight"].T) * (h @ w["up_weight"].T))
+                        @ w["down_weight"].T)
+
+    return system, formula, params, x
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["grad", "checkpoint"])
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+@pytest.mark.parametrize("act", [
+    "silu", "relu",
+    pytest.param(lambda g: g * jax.nn.sigmoid(1.702 * g), id="callable")])
+def test_gated_ffn_backward_is_autodiff_of_the_plain_formula(
+        act, compute, remat):
+    """``GatedFFN`` defines its own backward: the gradients of h and of
+    the three weights are autodiff's of the plain formula.
+
+    float32: the same five matmuls and three products, so only the
+    order of a matmul's summation differs; 1e-6 is 8 ulp (1.2e-7) of
+    the leaf's largest element, where a missing or wrong term moves it
+    by its own size. bf16: ``act(gate) * up``, ``dgate`` and ``dup`` are
+    bf16 tensors in autodiff's program too, each rounded once, so the
+    bound is one bf16 ulp (2**-8) of the leaf's largest element; this
+    backend reads 0."""
+    system, formula, params, x = _ffn_case(act, compute, remat)
+    cd = jnp.dtype(compute)
+    with policy_scope(DTypePolicy(param_dtype=jnp.float32, compute_dtype=cd,
+                                  activation_dtype=cd)):
+        value, got = jax.value_and_grad(system, argnums=(0, 1))(params, x)
+    want_value, want = jax.value_and_grad(formula, argnums=(0, 1))(params, x)
+    other = jax.grad(formula, argnums=(0, 1))(
+        params, x, jax.nn.silu if act == "relu" else jax.nn.relu)
+    np.testing.assert_allclose(value, want_value, rtol=1e-5)
+    tol = 1e-6 if compute == "float32" else 2.0 ** -8
+    leaves = jax.tree_util.tree_leaves_with_path(got)
+    assert len(leaves) == 4
+    for (path, g), w, o in zip(leaves, jax.tree.leaves(want),
+                               jax.tree.leaves(other)):
+        name = jax.tree_util.keystr(path)
+        assert g.dtype == jnp.float32 and g.shape == w.shape, name
+        top = float(jnp.max(jnp.abs(w)))
+        assert float(jnp.max(jnp.abs(g - w))) <= tol * top, name
+        # the comparison can fail: another activation's gradients
+        assert float(jnp.max(jnp.abs(o - w))) > 0.05 * top, name
+
+
+def test_gated_ffn_states_its_backward_once_a_trace_and_is_reverse_only():
+    """The instant that counts the layers on the path (PERF.md section
+    3), and the docstring's contract: reverse mode only."""
+    from bigdl_tpu.observability import trace
+    system, _, params, x = _ffn_case("silu", "float32", True)
+    seen = []
+    trace._TRACER._taps.append(seen.append)
+    try:
+        jax.eval_shape(system, params, x)       # the forward says nothing
+        assert not [e for e in seen if e["name"] == "gated_ffn_backward"]
+        jax.eval_shape(jax.grad(system), params, x)
+    finally:
+        trace._TRACER._taps.remove(seen.append)
+    stated = [e for e in seen if e["name"] == "gated_ffn_backward"]
+    assert len(stated) == 1 and stated[0]["cat"] == "nn"
+    assert stated[0]["args"] == {
+        "tokens": 48, "d_model": 16, "d_ff": 48,
+        "materialised_bytes": 48 * (4 * 48 + 2 * 16) * 4}
+    with pytest.raises(TypeError, match="custom_vjp"):
+        jax.jvp(lambda x: system(params, x), (x,), (x,))
+
+
 def test_eva_attention_names_its_parameters_as_mha_does():
     att = nn.EvaAttention(32, 4, window=32, chunk=4, rope_theta=1e5)
     p = att.init(jax.random.PRNGKey(0))

@@ -173,6 +173,51 @@ class TestEvaCompilesForTheV5e:
         assert "eva_attention_dqdkdv" in text
 
 
+class TestGatedFFNBackwardCompilesForTheV5e:
+    """XLA:TPU's verdict on ``nn.GatedFFN``'s own backward at the
+    benchmark cell's widths (evabyte-6.5b.train.long: 4096 / 11008 over
+    16384 tokens, bf16 compute, the block recomputed): no matmul of the
+    backward pass reads SiLU's exponential through a producer fused into
+    its operand, which it would evaluate again on every pass over it
+    (PERF.md section 6, PR 28; autodiff's backward has five a layer)."""
+
+    def test_no_backward_matmul_recomputes_the_activation(self, one_chip,
+                                                          on_tpu):
+        from bigdl_tpu import nn
+        from bigdl_tpu.models.transformer.model import PreNormBlock
+        from bigdl_tpu.observability.tracing import matmuls_fed_by
+        from bigdl_tpu.tensor import DTypePolicy, policy_scope
+        d, seq = 4096, 16384
+        block = PreNormBlock(
+            lambda: nn.RMSNorm(d, unit_offset=True),
+            nn.EvaAttention(d, 32, 2048, 16, 1e5), nn.GatedFFN(d, 11008),
+            residual_dtype=jnp.float32).set_name("block_0")
+        model = nn.Sequential().add(block).set_remat("per_block")
+
+        def loss(p, x):
+            with jax.named_scope("model"):
+                y, _ = model.apply(p, model.init_state(), x, training=True)
+            return jnp.sum(y)
+
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+        x = jax.ShapeDtypeStruct((1, seq, d), jnp.float32, sharding=one_chip)
+        with policy_scope(DTypePolicy(param_dtype=jnp.float32,
+                                      compute_dtype=BF16,
+                                      activation_dtype=BF16)):
+            text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+                params, x).compile().as_text()
+        # the custom backward keeps the block's scope, which is what
+        # step.backward_ms and step.update_fused_ms read it by
+        assert ("transpose(jvp(model))/block_0/jvp(model)/block_0/"
+                "checkpoint/1__Residual/dot_general") in text
+        fed = matmuls_fed_by(text, "exponential")
+        assert not [name for name, scope in fed.items()
+                    if "transpose(" in scope], fed
+
+
 class TestPredicatesMatchTheLowering:
     """Whatever a ``*_supported`` predicate accepts on a TPU lowers; what
     the lowering refuses reads as unsupported."""
