@@ -29,14 +29,11 @@ improvement.
 from __future__ import annotations
 
 import logging
-import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu.observability import trace
-from bigdl_tpu.optim.optimizer import Optimizer
+from bigdl_tpu.optim.optimizer import Optimizer, _TrainRun
 from bigdl_tpu.parallel.engine import (get_mesh, data_sharding, replicated)
 
 logger = logging.getLogger("bigdl_tpu.optim")
@@ -288,7 +285,7 @@ class DistriOptimizer(Optimizer):
         if logger.isEnabledFor(logging.DEBUG):
             logger.debug(self.metrics.summary())
 
-    def _optimize_impl(self):
+    def _prepare_run(self):
         model, criterion, optim = self.model, self.criterion, \
             self.optim_method
         mesh = self.mesh or get_mesh()
@@ -300,12 +297,6 @@ class DistriOptimizer(Optimizer):
             # under GSPMD (values summed over the data axis — see
             # SGD.group_small_leaves); force the per-leaf form
             optim.group_small_leaves = False
-        model.materialize()
-        model.training()
-        params, mstate = model.params, model.state
-        driver_state = {"epoch": int(self.state.get("epoch", 1)),
-                        "neval": int(self.state.get("neval", 1)),
-                        "is_epoch_end": False, "loss": float("inf")}
         if self.expert_parallel and \
                 self.expert_parallel not in mesh.axis_names:
             raise ValueError(
@@ -319,8 +310,8 @@ class DistriOptimizer(Optimizer):
                 "wire codec: the per-shard compressed step cannot nest "
                 "the MoE dispatch's own shard_map — use "
                 "wire_codec=None")
-        opt_state, rng, count_this_epoch, batches_to_skip = \
-            self._resume(optim, params)
+        params, mstate, opt_state, rng, count_this_epoch, \
+            batches_to_skip = self._initial_state()
         pp = self._init_pipeline(mesh)
         su = None if pp is not None \
             else self._init_sharded_update(mesh, params)
@@ -415,18 +406,7 @@ class DistriOptimizer(Optimizer):
                                      shardings=opt_shard,
                                      what="optimizer state")
 
-        use_mask = self._pad_stage is not None
-        masked = None
-        if use_mask:
-            from bigdl_tpu.nn.criterion import MaskedCriterion
-            masked = MaskedCriterion(criterion)
-
-        # memory-for-throughput knobs applied at step construction:
-        # named remat policy around the forward, microbatched gradient
-        # accumulation around fwd/bwd (optim/remat.py,
-        # optim/accumulation.py); "none" + k=1 is EXACTLY the plain step
-        from bigdl_tpu.optim.remat import remat_forward
-        fwd = remat_forward(model, self.remat_policy)
+        masked = self._masked_criterion()
 
         if pp is not None:
             # combined forward/backward schedule in ONE compiled step:
@@ -445,6 +425,9 @@ class DistriOptimizer(Optimizer):
             # compressed reduce-scatter + error feedback firing ONCE on
             # the accumulated grads), sharded update on f32 masters,
             # compressed param all-gather
+            from bigdl_tpu.optim.remat import remat_forward
+            fwd = remat_forward(model, self.remat_policy)
+
             def local_vag(p, mstate_in, data, labels, key):
                 if self.input_transform is not None:
                     data = self.input_transform(data)
@@ -473,21 +456,14 @@ class DistriOptimizer(Optimizer):
             # with grad accumulation the induced reduction fires once
             # per ACCUMULATED step (k x fewer collective bytes per
             # example)
-            from bigdl_tpu.optim.accumulation import make_train_step
-            train_step = make_train_step(
-                fwd=fwd, criterion=criterion, masked=masked,
-                input_transform=self.input_transform,
-                grad_clip=self.grad_clip,
-                update_fn=(su.apply_update if su is not None
-                           else optim.update),
-                num_microbatches=self.grad_accumulation,
-                aux_loss=self._aux_loss_fn())
+            train_step = self._global_view_step(
+                masked, su.apply_update if su is not None else optim.update)
 
         # label_shard is None under sequence_parallel (rank-derived at
         # placement, _shard_batch); jit then inherits the arg sharding
         in_shardings = (param_shard, repl, opt_shard, repl, batch_shard,
                         label_shard, None)
-        if use_mask:
+        if masked is not None:
             in_shardings += (None,)   # n_valid: replicated scalar
         jit_step = jax.jit(
             train_step,
@@ -504,13 +480,6 @@ class DistriOptimizer(Optimizer):
             _UnderMesh(jit_step, mesh), name="distri_train_step",
             cache=self._aot_cache() or False, mesh=mesh,
             donate_argnums=(0, 1, 2), extra=self._step_key_extra())
-        self.step_compiler = step_pipeline
-
-        def eval_apply(params, mstate, data):
-            if self.input_transform is not None:
-                data = self.input_transform(data)
-            out, _ = model.apply(params, mstate, data, training=False)
-            return out
 
         # sharded update / pipeline: evaluation/checkpoint see the
         # gathered params tree, so eval shardings are replicated
@@ -524,11 +493,11 @@ class DistriOptimizer(Optimizer):
             # with the host-gathered params _validate provides, and
             # Optimizer._validate merges results across hosts.
             from bigdl_tpu.optim.validator import local_sharded_eval
-            eval_fn = local_sharded_eval(eval_apply)
+            eval_fn = local_sharded_eval(self._eval_apply)
         else:
             from bigdl_tpu.optim.validator import _padded_eval
             jit_eval = _UnderMesh(
-                jax.jit(eval_apply,
+                jax.jit(self._eval_apply,
                         in_shardings=(eval_param_shard, repl, batch_shard),
                         out_shardings=batch_shard), mesh)
             # params stay in their training placement (param_shard may be
@@ -570,151 +539,25 @@ class DistriOptimizer(Optimizer):
             from bigdl_tpu.dataset.sample import MiniBatch
             return MiniBatch(data, labels, valid=batch.valid)
 
-        epoch_start_host_rng = self._host_rng_snapshot()
-        epoch_size = self.dataset.size()
-        batches_this_epoch = batches_to_skip
-        pipeline = self._open_train_pipeline(
-            place, skip=batches_to_skip, consumed=count_this_epoch,
-            records_scale=jax.process_count())
-        window, lockstep = self._dispatch_window()
-        pending: list[dict] = []
-        wallclock_start = time.perf_counter()
+        # sharded update / pipeline: validation, a checkpoint and the
+        # exit see the gathered f32 masters, and a checkpoint the
+        # bucketed optimizer state re-shaped to the params-shaped
+        # (ZeRO-1-compatible) layout
+        owner = su if su is not None else pp
 
-        try:
-            while True:
-                # the profiler hook first, so that a set_profiler trace
-                # holds the whole of its first iteration's span
-                self._profile_hook(driver_state["neval"])
-                with trace.span("train iteration",
-                                step=driver_state["neval"]):
-                    if self.end_when is not None and \
-                            self.end_when(driver_state):
-                        break
-                    driver_state["is_epoch_end"] = False
-                    self._step_scopes.annotate()
-                    t0 = time.perf_counter()
-                    with trace.span("input wait"):
-                        # queue pop at depth >= 1: the batch was assembled,
-                        # checked, and mesh-placed on the worker thread
-                        # ("input produce")
-                        batch = next(pipeline)
-                    t1 = time.perf_counter()
-                    data_time = t1 - t0
-                    data, labels = batch.data, batch.labels
-                    if batch.valid is not None:
-                        # padded batch: count the REAL rows (single
-                        # controller — _init_pad_stage refuses multi-host)
-                        global_n = int(batch.valid)
-                    else:
-                        global_n = int(data.shape[0])
-                    with trace.span("step lookup"):
-                        rng, step_rng = jax.random.split(rng)
-                        epoch_arr = jnp.asarray(driver_state["epoch"],
-                                                jnp.int32)
-                        step_args = (step_rng, data, labels, epoch_arr)
-                        if use_mask:
-                            step_args += (jnp.asarray(global_n, jnp.int32),)
-                        # lower/compile (or AOT-cache load) on first sight
-                        # of a shape; compile counts, executable FLOPs and
-                        # peak HBM land in the registry either way
-                        # (observability/compile_watch.py)
-                        compiled, compiled_this_iter = self._lookup_step(
-                            step_pipeline, (data.shape, labels.shape),
-                            (params, mstate, opt_state) + step_args)
-                        if compiled_this_iter and len(step_pipeline) == 1:
-                            self._account_collectives(compiled, n_shards)
-                    with trace.span("device step"):
-                        # dispatch only — loss stays on device; the packed
-                        # readback happens at drain time (docs/PERFORMANCE.md).
-                        # Honest phase metrics: the reference's get-weights/
-                        # compute/aggregate phases fuse inside the jitted
-                        # step, so what's measurable is input wait vs device
-                        # step (see metrics.py)
-                        params, mstate, opt_state, loss = compiled(
-                            params, mstate, opt_state, *step_args)
-                    t2 = time.perf_counter()
-                    self._telemetry_step()
-                    n = global_n  # records consumed across all hosts
-                    count_this_epoch += n
-                    batches_this_epoch += 1
-                    pending.append({"epoch": driver_state["epoch"],
-                                    "count": count_this_epoch,
-                                    "epoch_size": epoch_size,
-                                    "neval": driver_state["neval"],
-                                    "wallclock": time.perf_counter()
-                                    - wallclock_start,
-                                    "loss": loss, "n": n,
-                                    "step_time": t2 - t0,
-                                    "data_time": data_time,
-                                    "device_time": t2 - t1,
-                                    "compiled": compiled_this_iter})
-                    if len(pending) >= window:
-                        self._drain_pending(pending, driver_state,
-                                            lockstep or "window full")
-                    driver_state["neval"] += 1
-                    if count_this_epoch >= epoch_size:
-                        self._drain_pending(pending, driver_state, "epoch end")
-                        self._emit_input_wait_fraction(driver_state["neval"])
-                        # epoch-end checkpoint barrier: pending async saves
-                        # commit before the next epoch dispatches
-                        self._ckpt_barrier()
-                        driver_state["epoch"] += 1
-                        driver_state["is_epoch_end"] = True
-                        count_this_epoch = 0
-                        batches_this_epoch = 0
-                        # join the worker BEFORE shuffle() mutates the order
-                        # it iterates (thread-safety contract,
-                        # dataset/prefetch.py), then restart on the fresh
-                        # epoch's iterator
-                        pipeline.close()
-                        self.dataset.shuffle()
-                        epoch_start_host_rng = self._host_rng_snapshot()
-                        pipeline = self._open_train_pipeline(
-                            place, records_scale=jax.process_count())
-                        # MoE dispatch telemetry -> registry, once per
-                        # epoch (one batched readback, never per-step)
-                        self._publish_expert_telemetry(mstate)
-                    fire_val, fire_ckpt = self._fires(driver_state)
-                    ptree, opt_export = params, opt_state
-                    if fire_val or fire_ckpt:
-                        # validation/checkpoint read host-visible state: flush
-                        # the window first, then publish params (host-side
-                        # tree walk is overhead on deep models). Sharded
-                        # update: gather the f32 masters and re-shape the
-                        # bucketed optimizer state back to the params-shaped
-                        # (ZeRO-1-compatible) checkpoint layout
-                        self._drain_pending(pending, driver_state,
-                                            "validation/checkpoint trigger")
-                        with trace.span("model sync"):
-                            if su is not None:
-                                ptree = su.gather_params(params)
-                                if fire_ckpt:
-                                    opt_export = su.export_opt_state(
-                                        opt_state)
-                            elif pp is not None:
-                                ptree = pp.gather_params(params)
-                                if fire_ckpt:
-                                    opt_export = pp.export_opt_state(
-                                        opt_state)
-                            model.sync(ptree, mstate)
-                    self._validate(eval_fn, ptree, mstate, driver_state,
-                                   fire=fire_val)
-                    self._checkpoint(driver_state, opt_export, rng,
-                                     count_this_epoch, batches_this_epoch,
-                                     epoch_start_host_rng, fire=fire_ckpt)
-        finally:
-            pipeline.close()
+        def export(params, opt_state, with_opt):
+            if owner is None:
+                return params, opt_state
+            return (owner.gather_params(params),
+                    owner.export_opt_state(opt_state) if with_opt
+                    else opt_state)
 
-        self._drain_pending(pending, driver_state, "training end")
-        # exit barrier: every handed-off checkpoint is committed (and any
-        # background save error raised) before optimize() returns
-        self._ckpt_shutdown(raise_errors=True)
-        self._stop_profiler()
-        self._publish_expert_telemetry(mstate)
-        if su is not None:
-            params = su.gather_params(params)
-        elif pp is not None:
-            params = pp.gather_params(params)
-        model.sync(params, mstate)
-        model.evaluate()
-        return model
+        return _TrainRun(
+            params, mstate, opt_state, rng, count_this_epoch,
+            batches_to_skip, step_compiler=step_pipeline, place=place,
+            eval_fn=eval_fn,
+            records_scale=jax.process_count(),
+            on_first_compile=lambda compiled: self._account_collectives(
+                compiled, n_shards),
+            publish_telemetry=self._publish_expert_telemetry,
+            export=export)

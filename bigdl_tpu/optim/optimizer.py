@@ -13,8 +13,10 @@ donated-argument jitted so weights update in place in HBM.
 """
 from __future__ import annotations
 
+import dataclasses
 import logging
 import time
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +88,34 @@ def _require_process_sharded(dataset, what: str):
             "processes (every process must pass its own process_index, "
             "not the default) — duplicated shards would be "
             "double-counted and the rest never evaluated")
+
+
+@dataclasses.dataclass(frozen=True)
+class _TrainRun:
+    """What a subclass's ``_prepare_run`` hands the one training loop
+    (``Optimizer._optimize_impl``): everything in which training one
+    program on one device and training over a mesh differ. The three
+    hooks default to what the local optimizer needs: nothing."""
+    # the placed training state and the resume point
+    params: Any
+    mstate: Any
+    opt_state: Any
+    rng: Any
+    count_this_epoch: int
+    batches_to_skip: int
+    step_compiler: Any                 # tuning/aot_cache.StepCompiler
+    place: Callable                    # host batch -> device batch
+    eval_fn: Callable                  # (params, mstate, data) -> out
+    records_scale: int = 1             # processes feeding one batch
+    # the first executable of the run, once (collective accounting)
+    on_first_compile: Callable = lambda compiled: None
+    # at every epoch end and at exit (MoE dispatch telemetry)
+    publish_telemetry: Callable = lambda mstate: None
+    # the params-shaped trees validation, a checkpoint and the exit
+    # read: (params, opt_state, with_opt) -> (params tree, opt state);
+    # opt state is re-shaped only where ``with_opt``
+    export: Callable = lambda params, opt_state, with_opt: (params,
+                                                            opt_state)
 
 
 class Optimizer:
@@ -201,7 +231,7 @@ class Optimizer:
         # set_aot_cache() overrides either way
         self._aot_cache_cfg = "env"
         # the run's lower -> compile -> cache pipeline (set by
-        # _optimize_impl); its executables() are the compiled train
+        # _prepare_run); its executables() are the compiled train
         # steps, e.g. to read the optimized program text
         self.step_compiler = None
         # overlapped input pipeline (dataset/prefetch.py): batches are
@@ -651,9 +681,6 @@ class Optimizer:
             # already shut it down, raising on background save errors)
             self._ckpt_shutdown(raise_errors=False)
             self._telemetry_stop()
-
-    def _optimize_impl(self):
-        raise NotImplementedError
 
     # -- shared helpers --
     def _header(self, epoch, count, total, neval, wallclock):
@@ -1179,95 +1206,70 @@ class Optimizer:
                                    name="train", dataset=self.dataset,
                                    shard=self.dataset.process_shard_index())
 
+    # -- the training loop: one, for both optimizers --
+    def _prepare_run(self) -> _TrainRun:
+        """A subclass's whole share of a run: check the configuration,
+        place the training state, build the step."""
+        raise NotImplementedError
 
-class LocalOptimizer(Optimizer):
-    """Single-host training loop (reference optim/LocalOptimizer.scala)."""
-
-    def _optimize_impl(self):
-        model, criterion, optim = self.model, self.criterion, \
-            self.optim_method
-        if self.pipeline_stages > 1:
-            raise ValueError(
-                "pipeline_stages needs a device mesh to shard stages "
-                "over — construct the optimizer with mesh= (or a "
-                "sharded dataset) so the distributed path runs, with a "
-                "'pipe' axis of that size")
-        if self.shard_weight_update or self.wire_codec is not None:
-            logger.info(
-                "sharded update / wire codec configured, but the local "
-                "optimizer is one program with no collectives — inert "
-                "(DistriOptimizer runs the sharded path)")
+    def _initial_state(self):
+        """The module's training state and the resume point: ``(params,
+        mstate, opt_state, rng, count_this_epoch, batches_to_skip)``
+        (reference: epoch/neval live in the state Table,
+        DistriOptimizer.scala:80-81; full opt_state/rng/data-position
+        restore when the state came from a checkpoint)."""
+        model = self.model
         model.materialize()
         model.training()
-        params, mstate = model.params, model.state
-        # resume support (reference: epoch/neval live in the state Table,
-        # DistriOptimizer.scala:80-81; full opt_state/rng/data-position
-        # restore when the state came from a checkpoint)
-        driver_state = {"epoch": int(self.state.get("epoch", 1)),
-                        "neval": int(self.state.get("neval", 1)),
-                        "is_epoch_end": False, "loss": float("inf")}
-        opt_state, rng, count_this_epoch, batches_to_skip = \
-            self._resume(optim, params)
+        return (model.params, model.state) \
+            + self._resume(self.optim_method, model.params)
 
-        use_mask = self._pad_stage is not None
-        masked = None
-        if use_mask:
-            from bigdl_tpu.nn.criterion import MaskedCriterion
-            masked = MaskedCriterion(criterion)
+    def _masked_criterion(self):
+        """The criterion under the in-step validity mask of padded
+        partial batches; None where batches are not padded."""
+        if self._pad_stage is None:
+            return None
+        from bigdl_tpu.nn.criterion import MaskedCriterion
+        return MaskedCriterion(self.criterion)
 
-        # the step program is assembled from the memory knobs: the
-        # (possibly remat-wrapped) forward and the microbatched
-        # gradient-accumulation scan (optim/remat.py,
-        # optim/accumulation.py); policy "none" + k=1 is EXACTLY the
-        # plain step
+    def _global_view_step(self, masked, update_fn):
+        """The step over the whole batch, assembled from the memory
+        knobs: the (possibly remat-wrapped) forward and the microbatched
+        gradient-accumulation scan (optim/remat.py,
+        optim/accumulation.py); policy "none" + k=1 is EXACTLY the plain
+        step."""
         from bigdl_tpu.optim.accumulation import make_train_step
         from bigdl_tpu.optim.remat import remat_forward
-        train_step = make_train_step(
-            fwd=remat_forward(model, self.remat_policy),
-            criterion=criterion, masked=masked,
+        return make_train_step(
+            fwd=remat_forward(self.model, self.remat_policy),
+            criterion=self.criterion, masked=masked,
             input_transform=self.input_transform,
-            grad_clip=self.grad_clip, update_fn=optim.update,
+            grad_clip=self.grad_clip, update_fn=update_fn,
             num_microbatches=self.grad_accumulation,
             aux_loss=self._aux_loss_fn())
 
-        # explicit lower -> compile -> cache step construction
-        # (tuning/aot_cache.py): executables are built per batch
-        # signature OUTSIDE the hot loop's dispatch path, optionally
-        # loaded from the persistent AOT cache (set_aot_cache /
-        # $BIGDL_TPU_AOT_CACHE_DIR) so a restarting worker skips XLA;
-        # per-call signature counting keeps compile_watch's
-        # calls/compiles/storm accounting identical to the old
-        # implicit-jit path
-        from bigdl_tpu.tuning.aot_cache import StepCompiler
-        step_pipeline = StepCompiler(
-            jax.jit(train_step, donate_argnums=(0, 1, 2)),
-            name="local_train_step", cache=self._aot_cache() or False,
-            donate_argnums=(0, 1, 2), extra=self._step_key_extra(),
-            count_calls=True)
-        self.step_compiler = step_pipeline
+    def _eval_apply(self, params, mstate, data):
+        if self.input_transform is not None:
+            data = self.input_transform(data)
+        out, _ = self.model.apply(params, mstate, data, training=False)
+        return out
 
-        def eval_apply(params, mstate, data):
-            if self.input_transform is not None:
-                data = self.input_transform(data)
-            out, _ = model.apply(params, mstate, data, training=False)
-            return out
-
-        jit_eval = jax.jit(eval_apply)
-
-        def place(b):
-            # runs on the prefetch worker (depth >= 1): host->device
-            # transfer overlaps the in-flight device steps
-            if isinstance(b.data, jax.Array):
-                return b   # a user pipeline already placed it
-            from bigdl_tpu.dataset.sample import MiniBatch
-            return MiniBatch(jnp.asarray(b.data), jnp.asarray(b.labels),
-                             valid=b.valid)
-
+    def _optimize_impl(self):
+        run = self._prepare_run()
+        model = self.model
+        params, mstate, opt_state, rng = \
+            run.params, run.mstate, run.opt_state, run.rng
+        step_pipeline = self.step_compiler = run.step_compiler
+        driver_state = {"epoch": int(self.state.get("epoch", 1)),
+                        "neval": int(self.state.get("neval", 1)),
+                        "is_epoch_end": False, "loss": float("inf")}
+        count_this_epoch = run.count_this_epoch
+        batches_this_epoch = run.batches_to_skip
         epoch_start_host_rng = self._host_rng_snapshot()
         epoch_size = self.dataset.size()
-        batches_this_epoch = batches_to_skip
-        pipeline = self._open_train_pipeline(place, skip=batches_to_skip,
-                                             consumed=count_this_epoch)
+        pipeline = self._open_train_pipeline(
+            run.place, skip=run.batches_to_skip, consumed=count_this_epoch,
+            records_scale=run.records_scale)
         window, lockstep = self._dispatch_window()
         pending: list[dict] = []
         wallclock_start = time.perf_counter()
@@ -1286,34 +1288,48 @@ class LocalOptimizer(Optimizer):
                     self._step_scopes.annotate()
                     t0 = time.perf_counter()
                     with trace.span("input wait"):
-                        # at depth >= 1 this is a queue pop — assembly and
-                        # placement happened on the worker ("input produce")
+                        # queue pop at depth >= 1: the batch was assembled,
+                        # checked, and placed on the worker thread
+                        # ("input produce")
                         batch = next(pipeline)
                     t1 = time.perf_counter()
                     data_time = t1 - t0
                     data, labels = batch.data, batch.labels
+                    # records consumed across all hosts; of a padded
+                    # batch the REAL rows (single controller —
+                    # _init_pad_stage refuses multi-host)
                     n = int(batch.valid if batch.valid is not None
                             else data.shape[0])
                     with trace.span("step lookup"):
                         rng, step_rng = jax.random.split(rng)
-                        step_args = (params, mstate, opt_state, step_rng,
-                                     data, labels,
-                                     jnp.asarray(driver_state["epoch"],
-                                                 jnp.int32))
-                        if use_mask:
+                        epoch_arr = jnp.asarray(driver_state["epoch"],
+                                                jnp.int32)
+                        step_args = (step_rng, data, labels, epoch_arr)
+                        if self._pad_stage is not None:   # n_valid
                             step_args += (jnp.asarray(n, jnp.int32),)
-                        # quick dispatch key: only the batch varies
-                        # between iterations (params/opt state keep their
-                        # avals through donation) — two leaves to hash,
-                        # full signature only on a miss inside the
-                        # pipeline
-                        quick = compile_watch.signature_of((data, labels))
-                        compiled, _ = self._lookup_step(
-                            step_pipeline, quick, step_args)
+                        # lower/compile (or AOT-cache load) on first sight
+                        # of a batch signature; compile counts, executable
+                        # FLOPs and peak HBM land in the registry either
+                        # way (observability/compile_watch.py). Only the
+                        # batch varies between iterations (params/opt
+                        # state keep their avals through donation): two
+                        # leaves to hash, the full signature only on a
+                        # miss inside the pipeline
+                        compiled, compiled_this_iter = self._lookup_step(
+                            step_pipeline,
+                            compile_watch.signature_of((data, labels)),
+                            (params, mstate, opt_state) + step_args)
+                        if compiled_this_iter and len(step_pipeline) == 1:
+                            run.on_first_compile(compiled)
                     with trace.span("device step"):
                         # dispatch only — loss stays on device; the packed
-                        # readback happens at drain time (docs/PERFORMANCE.md)
-                        params, mstate, opt_state, loss = compiled(*step_args)
+                        # readback happens at drain time (docs/PERFORMANCE.md).
+                        # Honest phase metrics: the reference's get-weights/
+                        # compute/aggregate phases fuse inside the jitted
+                        # step, so what's measurable is input wait vs device
+                        # step (see metrics.py)
+                        params, mstate, opt_state, loss = compiled(
+                            params, mstate, opt_state, *step_args)
                     t2 = time.perf_counter()
                     self._telemetry_step()
                     count_this_epoch += n
@@ -1327,7 +1343,8 @@ class LocalOptimizer(Optimizer):
                                     "loss": loss, "n": n,
                                     "step_time": t2 - t0,
                                     "data_time": data_time,
-                                    "device_time": t2 - t1})
+                                    "device_time": t2 - t1,
+                                    "compiled": compiled_this_iter})
                     if len(pending) >= window:
                         self._drain_pending(pending, driver_state,
                                             lockstep or "window full")
@@ -1351,19 +1368,27 @@ class LocalOptimizer(Optimizer):
                         pipeline.close()
                         self.dataset.shuffle()
                         epoch_start_host_rng = self._host_rng_snapshot()
-                        pipeline = self._open_train_pipeline(place)
+                        pipeline = self._open_train_pipeline(
+                            run.place, records_scale=run.records_scale)
+                        # once per epoch: one batched readback, never
+                        # per-step
+                        run.publish_telemetry(mstate)
                     fire_val, fire_ckpt = self._fires(driver_state)
+                    ptree, opt_export = params, opt_state
                     if fire_val or fire_ckpt:
                         # validation/checkpoint read host-visible state: flush
                         # the window first, then publish params (syncing the
-                        # module tree every iteration is pure host overhead)
+                        # module tree every iteration is pure host overhead:
+                        # a tree walk on deep models)
                         self._drain_pending(pending, driver_state,
                                             "validation/checkpoint trigger")
                         with trace.span("model sync"):
-                            model.sync(params, mstate)
-                    self._validate(jit_eval, params, mstate, driver_state,
+                            ptree, opt_export = run.export(
+                                params, opt_state, fire_ckpt)
+                            model.sync(ptree, mstate)
+                    self._validate(run.eval_fn, ptree, mstate, driver_state,
                                    fire=fire_val)
-                    self._checkpoint(driver_state, opt_state, rng,
+                    self._checkpoint(driver_state, opt_export, rng,
                                      count_this_epoch, batches_this_epoch,
                                      epoch_start_host_rng, fire=fire_ckpt)
         finally:
@@ -1374,6 +1399,60 @@ class LocalOptimizer(Optimizer):
         # background save error raised) before optimize() returns
         self._ckpt_shutdown(raise_errors=True)
         self._stop_profiler()
-        model.sync(params, mstate)
+        run.publish_telemetry(mstate)
+        ptree, _ = run.export(params, opt_state, False)
+        model.sync(ptree, mstate)
         model.evaluate()
         return model
+
+
+class LocalOptimizer(Optimizer):
+    """Single-host training (reference optim/LocalOptimizer.scala): one
+    program on the default device, no mesh and no collectives."""
+
+    def _prepare_run(self):
+        if self.pipeline_stages > 1:
+            raise ValueError(
+                "pipeline_stages needs a device mesh to shard stages "
+                "over — construct the optimizer with mesh= (or a "
+                "sharded dataset) so the distributed path runs, with a "
+                "'pipe' axis of that size")
+        if self.shard_weight_update or self.wire_codec is not None:
+            logger.info(
+                "sharded update / wire codec configured, but the local "
+                "optimizer is one program with no collectives — inert "
+                "(DistriOptimizer runs the sharded path)")
+        params, mstate, opt_state, rng, count_this_epoch, \
+            batches_to_skip = self._initial_state()
+        masked = self._masked_criterion()
+        train_step = self._global_view_step(masked,
+                                            self.optim_method.update)
+
+        # explicit lower -> compile -> cache step construction
+        # (tuning/aot_cache.py): executables are built per batch
+        # signature OUTSIDE the hot loop's dispatch path, optionally
+        # loaded from the persistent AOT cache (set_aot_cache /
+        # $BIGDL_TPU_AOT_CACHE_DIR) so a restarting worker skips XLA;
+        # per-call signature counting keeps compile_watch's
+        # calls/compiles/storm accounting identical to the old
+        # implicit-jit path
+        from bigdl_tpu.tuning.aot_cache import StepCompiler
+        step_pipeline = StepCompiler(
+            jax.jit(train_step, donate_argnums=(0, 1, 2)),
+            name="local_train_step", cache=self._aot_cache() or False,
+            donate_argnums=(0, 1, 2), extra=self._step_key_extra(),
+            count_calls=True)
+
+        def place(b):
+            # runs on the prefetch worker (depth >= 1): host->device
+            # transfer overlaps the in-flight device steps
+            if isinstance(b.data, jax.Array):
+                return b   # a user pipeline already placed it
+            from bigdl_tpu.dataset.sample import MiniBatch
+            return MiniBatch(jnp.asarray(b.data), jnp.asarray(b.labels),
+                             valid=b.valid)
+
+        return _TrainRun(
+            params, mstate, opt_state, rng, count_this_epoch,
+            batches_to_skip, step_compiler=step_pipeline, place=place,
+            eval_fn=jax.jit(self._eval_apply))

@@ -19,6 +19,7 @@ only sanctioned readback path (the L-BFGS reads in optim_method.py are
 not exercised here).
 """
 import math
+import threading
 
 import numpy as np
 import pytest
@@ -63,10 +64,11 @@ def count_device_get(monkeypatch):
 
 
 def _run(end_when, *, max_in_flight=None, mesh=None, ckpt_dir=None,
-         summary=None):
+         summary=None, configure=None):
     """One deterministic training run (host RNG + init key pinned, so two
     runs differing only in the dispatch window see identical data order
-    and identical initial params)."""
+    and identical initial params). ``configure(o)`` has the last word on
+    the optimizer before it runs."""
     RandomGenerator.set_seed(11)
     ds = array(_samples()) >> SampleToBatch(BATCH)
     model = _mlp()
@@ -86,6 +88,8 @@ def _run(end_when, *, max_in_flight=None, mesh=None, ckpt_dir=None,
         o.overwrite_checkpoint()
     if summary is not None:
         o.set_train_summary(summary)
+    if configure is not None:
+        configure(o)
     trained = o.optimize()
     return trained, o
 
@@ -232,6 +236,108 @@ class TestDeferredEmission:
         dsteps = [e for e in events if e["name"] == "device step"]
         assert len(dsteps) == 4
         assert all("host_sync" not in e.get("args", {}) for e in dsteps)
+
+
+class TestOneLoop:
+    """Both optimizers train through the ONE loop of the base
+    ``Optimizer`` (ISSUE 29): a subclass prepares the run and writes no
+    iteration of its own. Six steps of the same job over 4-batch epochs,
+    validation and a checkpoint after steps 2 and 5, the epoch's turn in
+    step 4."""
+
+    KINDS = {"steady": 3, "epoch turn": 4, "validation and checkpoint": 5}
+
+    @pytest.fixture(scope="class")
+    def both(self, tmp_path_factory):
+        """Per optimizer: the leaf spans of the loop's thread by
+        iteration, in order, and every entry handed to ``_emit_step``."""
+        from bigdl_tpu.observability import trace
+        from bigdl_tpu.parallel import Engine
+        tmp = tmp_path_factory.mktemp("one_loop")
+        loop_thread = threading.get_ident()
+        runs = {}
+        Engine.reset()
+        try:
+            for name, mesh in (("local", None),
+                               ("distri", Engine.init(axes={"data": 8}))):
+                spans, iterations, entries = [], {}, []
+
+                def tap(ev):
+                    if ev["ph"] != "X" or ev["tid"] != loop_thread:
+                        return
+                    if ev["name"] != "train iteration":
+                        spans.append(ev)
+                        return
+                    # a span ends after the spans inside it: a leaf is
+                    # one that holds none of those that ended before it
+                    iterations[ev["args"]["step"]] = [
+                        e["name"] for i, e in enumerate(spans)
+                        if not any(e["ts"] <= c["ts"] and c["ts"] + c["dur"]
+                                   <= e["ts"] + e["dur"]
+                                   for c in spans[:i])]
+                    spans.clear()
+
+                def configure(o):
+                    val = array(_samples(64, seed=4)) >> SampleToBatch(BATCH)
+                    o.set_validation(optim.several_iteration(3), val,
+                                     [optim.Top1Accuracy()])
+                    o.set_checkpoint(str(tmp / name),
+                                     optim.several_iteration(3))
+                    emit = o._emit_step
+                    o._emit_step = lambda e, loss: (
+                        entries.append(dict(e)), emit(e, loss))
+
+                trace.get_tracer().add_tap(tap)
+                try:
+                    _run(optim.max_iteration(6), mesh=mesh,
+                         configure=configure)
+                finally:
+                    trace.get_tracer().remove_tap(tap)
+                runs[name] = (iterations, entries)
+        finally:
+            Engine.reset()
+        return runs
+
+    @pytest.mark.parametrize("kind", sorted(KINDS))
+    def test_iteration_has_the_same_leaf_spans_in_both(self, both, kind):
+        step = self.KINDS[kind]
+        local, distri = both["local"][0], both["distri"][0]
+        assert sorted(local) == sorted(distri) == [1, 2, 3, 4, 5, 6, 7]
+        assert local[step] == distri[step]
+        lookup_and_step = ["input wait", "step lookup", "device step"]
+        assert local[step][:3] == lookup_and_step
+        rest = local[step][3:]
+        if kind == "steady":
+            assert rest == []
+        elif kind == "epoch turn":
+            assert rest == ["loss drain", "emit steps"]
+        else:
+            assert rest[:3] == ["loss drain", "emit steps", "model sync"]
+            assert "checkpoint handoff" in rest[3:]
+        # and every other iteration, the compiling first and the seventh
+        # that only ends the run among them
+        assert local == distri
+
+    def test_emit_step_is_handed_the_same_entry_by_both(self, both):
+        local, distri = both["local"][1], both["distri"][1]
+        assert [e["neval"] for e in local] == [1, 2, 3, 4, 5, 6]
+        assert [e["neval"] for e in distri] == [1, 2, 3, 4, 5, 6]
+        assert {frozenset(e) for e in local} \
+            == {frozenset(e) for e in distri}
+        # the first step compiled; "compiled" is what keeps a compile's
+        # wall time out of DistriOptimizer's link-bandwidth estimate
+        assert [e["compiled"] for e in local] \
+            == [e["compiled"] for e in distri] == [True] + [False] * 5
+
+    @pytest.mark.parametrize("name", ["LocalOptimizer", "DistriOptimizer"])
+    def test_subclass_writes_no_loop_of_its_own(self, name):
+        import inspect
+        from bigdl_tpu.optim import distri_optimizer
+        cls = getattr(optim, name, None) or getattr(distri_optimizer, name)
+        assert "_optimize_impl" not in vars(cls)
+        assert "_prepare_run" in vars(cls)
+        assert cls._optimize_impl is optim.Optimizer._optimize_impl
+        assert "while True" not in inspect.getsource(cls)
 
 
 class TestBuilderAPI:

@@ -61,17 +61,26 @@ def _host_annotations(trace_dir):
     return out
 
 
-@pytest.fixture(scope="module")
-def profiled_run(tmp_path_factory):
+@pytest.fixture(scope="module", params=["local", "distri"])
+def profiled_run(request, tmp_path_factory):
     """Four steps of a toy model through ``Optimizer`` inside a profiler
-    session; validation and a checkpoint fire after step 4."""
+    session; validation and a checkpoint fire after step 4. Once on one
+    device and once over a mesh: the readers of ``benchmarks/`` hold
+    both optimizers to the one span contract of the one loop."""
+    from bigdl_tpu.parallel import Engine
     tmp = tmp_path_factory.mktemp("program_spans")
     train = array(_samples()) >> SampleToBatch(BATCH)
     val = array(_samples(64, seed=4)) >> SampleToBatch(BATCH)
     model = nn.Sequential(nn.Linear(2, 16), nn.Tanh(),
                           nn.Linear(16, 2), nn.LogSoftMax())
+    Engine.reset()
+    request.addfinalizer(Engine.reset)
+    mesh = (Engine.init(axes={"data": 8}) if request.param == "distri"
+            else None)
     o = optim.Optimizer(model=model, dataset=train,
-                        criterion=nn.ClassNLLCriterion())
+                        criterion=nn.ClassNLLCriterion(), mesh=mesh)
+    assert type(o).__name__ == {"local": "LocalOptimizer",
+                                "distri": "DistriOptimizer"}[request.param]
     o.set_optim_method(optim.SGD(learning_rate=0.5))
     o.set_end_when(optim.max_iteration(4))
     o.set_validation(optim.every_epoch(), val, [optim.Top1Accuracy()])
