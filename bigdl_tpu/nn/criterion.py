@@ -109,10 +109,15 @@ class CrossEntropyCriterion(Criterion):
     TPU note: computed as ``logsumexp(x) - x[target]`` rather than
     composing ``log_softmax`` + NLL: the composition materializes the
     (N, V) log-prob tensor in f32 as a saved residual, while the lse
-    form's backward is ``softmax(x) - onehot`` fused into the one
-    cotangent buffer that must exist anyway — at LM vocab sizes this is
-    the difference between several extra (B, S, V) buffers and none
-    (docs/PERF.md transformer section)."""
+    form's backward is ``softmax(x) - onehot``, which XLA evaluates
+    inside the consumers of the one cotangent. The label's logit is a
+    masked row sum beside the sum of exponentials, not a gather: every
+    reduction reads the logits where and as they arrived (bf16 from an
+    LM head) and widens them on the way, so no float32 array of the
+    logits' size is written in either direction. A gather wants its
+    operand in memory, rows by classes: at LM vocabulary sizes that was
+    a float32 copy of all the logits to pick one number a row
+    (PERF.md section 6, PR 30)."""
 
     def __init__(self, weights=None, size_average: bool = True,
                  label_smoothing: float = 0.0):
@@ -125,11 +130,19 @@ class CrossEntropyCriterion(Criterion):
         self.label_smoothing = label_smoothing
 
     def apply(self, x, target):
+        from bigdl_tpu.observability import trace
         t = target.astype(jnp.int32).reshape(-1) - 1
         logits = x.reshape(-1, x.shape[-1]).astype(
             jnp.promote_types(x.dtype, jnp.float32))
+        # python runs this where the criterion is traced for a compile,
+        # never in a step
+        trace.instant("cross_entropy", cat="nn", rows=logits.shape[0],
+                      classes=logits.shape[1], logits_dtype=x.dtype.name,
+                      materialised_bytes=0)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, t[:, None], axis=1)[:, 0]
+        column = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        picked = jnp.sum(jnp.where(column == t[:, None], logits, 0.0),
+                         axis=-1)
         per = lse - picked
         eps = self.label_smoothing
         if eps > 0.0 and self.weights is not None:
@@ -138,7 +151,8 @@ class CrossEntropyCriterion(Criterion):
             # own weight (-(logp * w).sum / K); mean divides by sum w[t]
             w = self.weights.astype(logits.dtype)
             w_t = jnp.take(w, t)
-            smooth = (lse * jnp.sum(w) - logits @ w) / logits.shape[-1]
+            smooth = (lse * jnp.sum(w) - jnp.sum(logits * w, axis=-1)) \
+                / logits.shape[-1]
             total = jnp.sum((1.0 - eps) * w_t * per + eps * smooth)
             return total / jnp.sum(w_t) if self.size_average else total
         if eps > 0.0:

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 import torch
 import torch.nn.functional as F
 
@@ -277,6 +278,95 @@ def test_label_smoothing_matches_torch():
                 weight=None if weights is None else torch.tensor(w),
                 label_smoothing=eps)
             np.testing.assert_allclose(got, float(want), rtol=1e-5)
-    import pytest as _pytest
-    with _pytest.raises(ValueError, match="label_smoothing"):
+    with pytest.raises(ValueError, match="label_smoothing"):
         nn.CrossEntropyCriterion(label_smoothing=1.0)
+
+
+class _ParentCrossEntropy(nn.CrossEntropyCriterion):
+    """The formula ``CrossEntropyCriterion`` computed before it stopped
+    widening the logits to gather from them (PERF.md section 6, PR 30):
+    the float32 cast of the whole input, ``logsumexp`` of it and a
+    gather of the label's logit; the yardstick of the test below."""
+
+    def apply(self, x, target):
+        t = target.astype(jnp.int32).reshape(-1) - 1
+        logits = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        per = lse - jnp.take_along_axis(logits, t[:, None], axis=1)[:, 0]
+        eps, w = self.label_smoothing, self.weights
+        w_t = jnp.ones_like(per) if w is None else jnp.take(w, t)
+        if eps > 0.0 and w is not None:
+            smooth = (lse * jnp.sum(w) - logits @ w) / logits.shape[-1]
+            total = jnp.sum((1.0 - eps) * w_t * per + eps * smooth)
+        else:
+            if eps > 0.0:
+                per = (1.0 - eps) * per + eps * (
+                    lse - jnp.mean(logits, axis=-1))
+            total = jnp.sum(w_t * per)
+        return total / jnp.sum(w_t) if self.size_average else total
+
+
+_CE_CLASSES = 11
+_CE_OPTIONS = {
+    "plain": {},
+    "weights": {"weights": np.linspace(0.5, 2.0, _CE_CLASSES,
+                                       dtype=np.float32)},
+    "label_smoothing": {"label_smoothing": 0.1},
+    "both": {"weights": np.linspace(2.0, 0.5, _CE_CLASSES,
+                                    dtype=np.float32),
+             "label_smoothing": 0.3},
+    "sum": {"size_average": False},
+}
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["bare", "masked"])
+@pytest.mark.parametrize("options", sorted(_CE_OPTIONS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_cross_entropy_is_the_parent_formula(dtype, options, masked):
+    """One pass over the logits as they arrive gives the loss and the
+    gradient of the parent's cast-then-gather formula.
+
+    float32: 1e-6 of the gradient's largest element (the weighted
+    smoothing term sums in another order). bfloat16: the cotangent is
+    computed in float32 and rounded once to the input's dtype, as
+    autodiff's transposed cast rounds the parent's, so each element is
+    within one bf16 ulp (2**-7 of itself at most)."""
+    rng = np.random.default_rng(30)
+    x = jnp.asarray(3.0 * rng.standard_normal((4, 6, _CE_CLASSES)),
+                    dtype)
+    t = rng.integers(1, _CE_CLASSES + 1, size=(4, 6))
+    t[0, :2] = 1, _CE_CLASSES            # the first class and the last
+    t = jnp.asarray(t)
+    system = nn.CrossEntropyCriterion(**_CE_OPTIONS[options])
+    parent = _ParentCrossEntropy(**_CE_OPTIONS[options])
+    mask = jnp.asarray([True, True, False, True])
+    if masked:
+        def loss(c, x, t=t):
+            return nn.MaskedCriterion(c).apply(x, t, mask)
+    else:
+        def loss(c, x, t=t):
+            return c.apply(x, t)
+
+    value, got = jax.value_and_grad(loss, argnums=1)(system, x)
+    want_value, want = jax.value_and_grad(loss, argnums=1)(parent, x)
+    assert value.dtype == jnp.float32
+    np.testing.assert_allclose(value, want_value, rtol=1e-6)
+    assert got.dtype == x.dtype and got.shape == x.shape
+    want32 = np.asarray(want, np.float32)
+
+    def agrees(g):
+        off = np.abs(np.asarray(g, np.float32) - want32)
+        if dtype == "float32":
+            return np.max(off) <= 1e-6 * np.max(np.abs(want32))
+        return np.all(off <= 2.0 ** -7 * np.abs(want32))
+
+    assert agrees(got)
+    if masked:
+        assert not np.any(got[2]) and np.any(got[3])
+    # the comparison can fail: another label moves the gradient
+    assert not agrees(jax.grad(loss, argnums=1)(
+        parent, x, jnp.roll(t, 1, axis=1)))
+    # the gradient traces under jit and under vmap
+    grad = jax.grad(lambda x: loss(system, x))
+    assert agrees(jax.jit(grad)(x))
+    assert agrees(jax.vmap(grad)(jnp.stack([x + 1, x]))[1])

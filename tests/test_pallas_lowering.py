@@ -9,6 +9,9 @@ only runs when ``chip_smoke.py`` compiles the same kernels on a TPU. The
 geometries are exactly the smoke's.
 """
 import itertools
+import math
+import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -216,6 +219,104 @@ class TestGatedFFNBackwardCompilesForTheV5e:
         fed = matmuls_fed_by(text, "exponential")
         assert not [name for name, scope in fed.items()
                     if "transpose(" in scope], fed
+
+
+class TestHeadAndLossCompileForTheV5e:
+    """XLA:TPU's verdict on the head and the loss at the benchmark
+    cells' widths (opt-1.3b: 4 x 2048 tokens, 2048 wide, 50272 classes,
+    bf16 logits): ``final_norm`` + ``lm_head`` +
+    ``CrossEntropyCriterion`` + AdamW as ``make_train_step`` assembles
+    them. The criterion reads the logits where the matmul left them: no
+    float32 array of their size is written, nothing copies them and
+    nothing gathers from them (PERF.md section 6, PR 30; the parent's
+    cast-then-gather formula cost a 1.65 GB float32 copy, 3.7 ms a
+    step on the chip)."""
+
+    TOKENS, D, V = (4, 2048), 2048, 50272
+
+    @staticmethod
+    def _parent_formula(x, target):
+        t = target.astype(jnp.int32).reshape(-1) - 1
+        logits = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, t[:, None], axis=1)[:, 0]
+        return jnp.mean(lse - picked)
+
+    def _step(self, one_chip, criterion):
+        """The compiled step. The hidden state arrives as a parameter,
+        so that the head's input-gradient matmul is in the program."""
+        from bigdl_tpu import nn, optim
+        from bigdl_tpu.nn import init as init_mod
+        from bigdl_tpu.optim.accumulation import make_train_step
+        from bigdl_tpu.tensor import DTypePolicy, policy_scope
+        model = nn.Sequential()
+        model.add(nn.LayerNorm(self.D).set_name("final_norm"))
+        model.add(nn.Linear(self.D, self.V, init_method=init_mod.Xavier)
+                  .set_name("lm_head"))
+        method = optim.AdamW(learning_rate=1e-4, beta1=0.9, beta2=0.95,
+                             weight_decay=0.1)
+
+        def fwd(p, mstate, data, training, rng):
+            return model.apply(p["model"], mstate, p["hidden"].astype(BF16),
+                               training=training, rng=rng)
+
+        def on_chip(tree):
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), tree)
+
+        params = on_chip({
+            "model": jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+            "hidden": jax.ShapeDtypeStruct((*self.TOKENS, self.D),
+                                           jnp.float32)})
+        opt_state = on_chip(jax.eval_shape(method.init_state, params))
+        key, epoch, ids = on_chip((
+            jax.ShapeDtypeStruct((2,), jnp.uint32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct(self.TOKENS, jnp.int32)))
+        step = make_train_step(fwd=fwd, criterion=criterion,
+                               update_fn=method.update)
+        with policy_scope(DTypePolicy(param_dtype=jnp.float32,
+                                      compute_dtype=BF16,
+                                      activation_dtype=BF16)):
+            return jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+                params, model.init_state(), opt_state, key, ids, ids,
+                epoch).compile()
+
+    def _written(self, text):
+        """``[(opcode, dtype)]`` of the logits-sized arrays that the
+        entry computation's instructions write."""
+        from bigdl_tpu.observability.tracing import _HLO_NO_OP
+        size = math.prod(self.TOKENS) * self.V
+        out = []
+        for line in text[text.index("\nENTRY "):].splitlines():
+            m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(",
+                         line)
+            if not m or m.group(2) in _HLO_NO_OP:
+                continue
+            out += [(m.group(2), dtype)
+                    for dtype, dims in re.findall(r"([a-z]\w*)\[([\d,]+)\]",
+                                                  m.group(1))
+                    if math.prod(map(int, dims.split(","))) == size]
+        return out
+
+    def test_nothing_widens_copies_or_gathers_the_logits(self, one_chip):
+        from bigdl_tpu import nn
+        step = self._step(one_chip, nn.CrossEntropyCriterion())
+        text = step.as_text()
+        # the logits themselves, out of the head's matmul, and no other
+        # array of their size in any dtype
+        assert self._written(text) == [("fusion", "bf16")]
+        assert not [line for line in text.splitlines()
+                    if " gather(" in line and f",{self.V}]" in line]
+        assert "/lm_head/dot_general" in text and "jvp(criterion)/" in text
+        parent = self._step(one_chip, types.SimpleNamespace(
+            apply=self._parent_formula, size_average=True))
+        # the comparison can fail: the parent's formula
+        written = self._written(parent.as_text())
+        assert ("copy", "f32") in written and ("fusion", "bf16") in written
+        freed = (parent.memory_analysis().temp_size_in_bytes
+                 - step.memory_analysis().temp_size_in_bytes)
+        assert freed >= 1.3e9, freed
 
 
 class TestPredicatesMatchTheLowering:

@@ -162,7 +162,8 @@ def test_disabled_span_is_the_bare_annotation():
 # scopes inside the step program
 # ---------------------------------------------------------------------------
 
-def _lm_step_lowered(num_microbatches):
+def _lm_step(num_microbatches):
+    """``(jitted step, its arguments)`` of a toy LM."""
     from bigdl_tpu.models import TransformerLM
     from bigdl_tpu.optim.accumulation import make_train_step
     from bigdl_tpu.optim.remat import remat_forward
@@ -176,9 +177,14 @@ def _lm_step_lowered(num_microbatches):
         num_microbatches=num_microbatches)
     params = model.init(jax.random.PRNGKey(0))
     tokens = jnp.ones((4, 16), jnp.int32)
-    return jax.jit(step).lower(
+    return jax.jit(step), (
         params, model.init_state(), method.init_state(params),
         jax.random.PRNGKey(1), tokens, tokens, jnp.asarray(1, jnp.int32))
+
+
+def _lm_step_lowered(num_microbatches):
+    step, args = _lm_step(num_microbatches)
+    return step.lower(*args)
 
 
 @pytest.mark.parametrize("k", [1, 2])
@@ -197,6 +203,26 @@ def test_scopes_do_not_change_the_step_program(k, monkeypatch):
                   "transpose(jvp(model))/block_1/",
                   "transpose(jvp(model))/embed/"):
         assert scope in named, scope
+
+
+def test_cross_entropy_states_itself_where_it_is_traced_never_in_a_step():
+    """The ``bigdl:nn:cross_entropy`` instant (PERF.md section 3): once
+    where the step is traced, with what the criterion read, and not
+    again when the compiled step runs."""
+    step, args = _lm_step(1)
+    seen = []
+    trace.get_tracer().add_tap(seen.append)
+    try:
+        step(*args)
+        stated = [e for e in seen if e["name"] == "cross_entropy"]
+        step(*args)
+    finally:
+        trace.get_tracer().remove_tap(seen.append)
+    assert len(stated) == 1 and stated[0]["cat"] == "nn"
+    assert stated[0]["args"] == {"rows": 64, "classes": 64,
+                                 "logits_dtype": "float32",
+                                 "materialised_bytes": 0}
+    assert [e for e in seen if e["name"] == "cross_entropy"] == stated
 
 
 def test_sequential_scope_names_hold_no_id():
