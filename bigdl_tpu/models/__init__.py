@@ -11,7 +11,8 @@ from bigdl_tpu.models.vgg.model import VggForCifar10, Vgg_16, Vgg_19
 from bigdl_tpu.models.resnet.model import (ResNet, ShortcutType, DatasetType,
                                      model_init)
 from bigdl_tpu.models.rnn.model import SimpleRNN, BatchedSimpleRNN
-from bigdl_tpu.models.transformer.model import (EvaByteLM, TransformerBlock,
+from bigdl_tpu.models.transformer.model import (EvaByteLM, KeyeLM,
+                                                TransformerBlock,
                                                 TransformerLM)
 
 __all__ = [
@@ -21,5 +22,5 @@ __all__ = [
     "VggForCifar10", "Vgg_16", "Vgg_19",
     "ResNet", "ShortcutType", "DatasetType", "model_init",
     "SimpleRNN", "BatchedSimpleRNN",
-    "TransformerLM", "TransformerBlock", "EvaByteLM",
+    "TransformerLM", "TransformerBlock", "EvaByteLM", "KeyeLM",
 ]

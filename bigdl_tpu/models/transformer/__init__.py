@@ -1,9 +1,11 @@
 from bigdl_tpu.models.transformer.generate import (GenerationConfig,
                                                     beam_search, generate)
-from bigdl_tpu.models.transformer.model import (EvaByteLM, PreNormBlock,
+from bigdl_tpu.models.transformer.model import (EvaByteLM, KeyeLM,
+                                                PreNormBlock,
                                                 TransformerBlock,
                                                 TransformerLM)
 
 __all__ = ["TransformerBlock", "TransformerLM", "PreNormBlock", "EvaByteLM",
+           "KeyeLM",
            "GenerationConfig",
            "generate", "beam_search"]

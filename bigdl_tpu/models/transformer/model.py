@@ -19,7 +19,7 @@ from bigdl_tpu.nn.module import Container, Module
 from bigdl_tpu.tensor import activation_dtype, default_dtype
 
 __all__ = ["TransformerLM", "TransformerBlock", "PreNormBlock", "EvaByteLM",
-           "decode_meta"]
+           "KeyeLM", "decode_meta"]
 
 
 class _Residual(Container):
@@ -189,18 +189,78 @@ def EvaByteLM(vocab_size: int = 320, d_model: int = 4096,
             nn.GatedFFN(d_model, ffn_dim, act="silu"),
             residual_dtype=jnp.float32).set_name(f"block_{i}"))
     model.add(norm().set_name("final_norm"))
-    # logits of standard deviation 0.28 on a unit-RMS input: what Xavier
-    # gives TransformerLM's 50272-row head at OPT-1.3B's width. On these
-    # few columns Xavier gives 1.1, and every gradient of a random-init
-    # model, and so what rounding the weights to bf16 moves the loss by,
-    # grows with it (PERF.md section 6, PR 27)
-    def small_normal(rng, shape, dtype):
-        return jax.random.normal(rng, shape, dtype) * 0.28 * d_model ** -0.5
-
+    # on these few columns Xavier gives logits of deviation 1.1
     model.add(nn.Linear(d_model, num_pred_heads * vocab_size,
-                        with_bias=False, init_method=small_normal,
+                        with_bias=False, init_method=_small_head(d_model),
                         output_dtype=jnp.float32).set_name("lm_head"))
     model.no_decode_path = "EvaAttention: ROADMAP B7"
+    return model.set_remat(remat)
+
+
+def _small_head(d_model: int):
+    """A head initialiser: logits of standard deviation 0.28 on a
+    unit-RMS input — what Xavier gives TransformerLM's 50272-row head at
+    OPT-1.3B's width. On fewer columns Xavier gives more, and every
+    gradient of a random-init model, and so what rounding the weights to
+    bf16 moves the loss by, grows with it (PERF.md section 6, PR 27)."""
+    def small_normal(rng, shape, dtype):
+        return jax.random.normal(rng, shape, dtype) * 0.28 * d_model ** -0.5
+    return small_normal
+
+
+def KeyeLM(vocab_size: int = 151936, d_model: int = 2048,
+           num_heads: int = 32, num_kv_heads: int = 4, head_dim: int = 128,
+           num_layers: int = 48, expert_dim: int = 768,
+           experts_total: int = 128, experts_per_token: int = 8,
+           experts_held: int | None = None, experts_offset: int = 0,
+           index_heads: int = 16, index_dim: int = 64, topk: int = 2048,
+           rope_theta: float = 1e7, rms_eps: float = 1e-6,
+           remat: str | None = "per_block") -> nn.Sequential:
+    """The language model of Keye-VL-2.0-30B-A3B
+    (huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B): a decoder of
+    pre-norm blocks x + SparseAttn(RMSNorm(x)); x + MoE(RMSNorm(x)) —
+    ``nn.SparseSelectAttention`` (GQA with per-head q/k RMS norms and
+    RoPE, a learned top-``topk`` selection with its indexer and the
+    indexer's loss) and ``parallel.expert.ExpertShare`` (a float32
+    softmax router over ``experts_total``, ``experts_per_token`` a token
+    renormalised, SwiGLU experts of which ``experts_held`` from
+    ``experts_offset`` live here, dropless) — on a float32 residual
+    stream, no bias anywhere, no position table, an untied head of
+    float32 logits over ``vocab_size`` rows (a chip's slice of the
+    vocabulary is a smaller ``vocab_size``). tokens (B, S) 1-based; text
+    positions only (M-RoPE's rows equal, so plain RoPE); no vision
+    tower. The norms hand float32 to the blocks: the router and the
+    indexer read it, the large matmuls round it to the compute dtype.
+    docs/sparse_attention.md and docs/expert_share.md have the
+    equations.
+
+    ``remat`` as ``EvaByteLM``'s; the same ``embed`` / ``block_i`` /
+    ``final_norm`` / ``lm_head`` children and no ``lm_meta``: there is
+    no decode path for the selection yet (``decode_meta``)."""
+    from bigdl_tpu.parallel.expert import ExpertShare
+
+    def norm():
+        return nn.RMSNorm(d_model, eps=rms_eps, fp32=True)
+
+    model = (nn.Sequential()
+             .add(_TokenAndPosition(vocab_size, d_model, 0, with_pos=False,
+                                    out_dtype=jnp.float32)
+                  .set_name("embed")))
+    for i in range(num_layers):
+        model.add(PreNormBlock(
+            norm,
+            nn.SparseSelectAttention(d_model, num_heads, num_kv_heads,
+                                     head_dim, index_heads, index_dim, topk,
+                                     rope_theta, rms_eps),
+            ExpertShare(d_model, expert_dim, experts_total,
+                        experts_per_token, experts_held=experts_held,
+                        experts_offset=experts_offset),
+            residual_dtype=jnp.float32).set_name(f"block_{i}"))
+    model.add(nn.RMSNorm(d_model, eps=rms_eps).set_name("final_norm"))
+    model.add(nn.Linear(d_model, vocab_size, with_bias=False,
+                        init_method=_small_head(d_model),
+                        output_dtype=jnp.float32).set_name("lm_head"))
+    model.no_decode_path = "SparseSelectAttention: ROADMAP B11"
     return model.set_remat(remat)
 
 

@@ -35,7 +35,8 @@ from bigdl_tpu.nn.table_ops import (CAddTable, CSubTable, CMulTable,
                                     MaskedSelect)
 from bigdl_tpu.nn.recurrent import (Cell, RnnCell, RNN, LSTM, GRU, Recurrent,
                                     BiRecurrent, TimeDistributed)
-from bigdl_tpu.nn.attention import MultiHeadAttention, EvaAttention
+from bigdl_tpu.nn.attention import (MultiHeadAttention, EvaAttention,
+                                    SparseSelectAttention)
 from bigdl_tpu.nn.criterion import (
     ClassNLLCriterion, MSECriterion, BCECriterion, CrossEntropyCriterion,
     ClassSimplexCriterion, AbsCriterion, CosineEmbeddingCriterion,

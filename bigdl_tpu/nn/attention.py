@@ -15,8 +15,9 @@ from bigdl_tpu.nn import init as init_mod
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.tensor import activation_dtype, compute_dtype, default_dtype
 
-__all__ = ["MultiHeadAttention", "EvaAttention", "apply_rope",
-           "eva_chunk_summaries", "eva_attention_xla"]
+__all__ = ["MultiHeadAttention", "EvaAttention", "SparseSelectAttention",
+           "apply_rope", "eva_chunk_summaries", "eva_attention_xla",
+           "index_scores_xla", "select_topk_xla", "sparse_select_xla"]
 
 
 def apply_rope(x, positions, theta: float = 10000.0):
@@ -277,3 +278,237 @@ class EvaAttention(Module):
     def __repr__(self):
         return (f"EvaAttention({self.embed_dim}, heads={self.num_heads}, "
                 f"window={self.window}, chunk={self.chunk})")
+
+
+def index_scores_xla(qi, ki, wi):
+    """The indexer's scores in plain ``jax.numpy``: I[b, t, s] = sum_j
+    wi[b, t, j] ReLU(qi[b, t, j] . ki[b, s]); ``qi`` (B, S, J, DI), ``ki``
+    (B, S, DI), ``wi`` (B, S, J) -> (B, S, S) float32. Materialises
+    (B, S, J, S): small sizes only."""
+    f32 = jnp.float32
+    r = jnp.einsum("btjd,bsd->btjs", qi.astype(f32), ki.astype(f32),
+                   precision="highest")
+    return jnp.einsum("btj,btjs->bts", wi.astype(f32), jax.nn.relu(r),
+                      precision="highest")
+
+
+def select_topk_xla(scores, topk: int):
+    """The selection in plain ``jax.numpy``: ``scores`` (B, S, S) ->
+    (masked scores, row logsumexp): the scores with -inf wherever key s
+    is NOT among query t's ``topk`` largest over s <= t (every s <= t
+    while t < topk; among equal scores the lower s wins), and the
+    logsumexp of what is left of each row (B, S)."""
+    s = scores.shape[-1]
+    pos = jnp.arange(s)
+    valid = pos[None, :] <= pos[:, None]
+    masked = jnp.where(valid, scores, -jnp.inf)
+    kth = jax.lax.top_k(masked, min(topk, s))[0][..., -1:]
+    above = masked > kth
+    equal = (masked == kth) & valid
+    room = topk - jnp.sum(above, axis=-1, keepdims=True)
+    keep = (above | (equal & (jnp.cumsum(equal, axis=-1) <= room))) & valid
+    kept = jnp.where(keep, scores, -jnp.inf)
+    return kept, jax.scipy.special.logsumexp(kept, axis=-1)
+
+
+@jax.custom_vjp
+def _with_gradient_of(y, aux):
+    """``y``, with ``aux``'s gradient at weight one riding ``y``'s
+    backward pass whatever cotangent ``y`` gets (the public precedent is
+    Megatron-LM's MoEAuxLossAutoScaler)."""
+    return y
+
+
+_with_gradient_of.defvjp(
+    lambda y, aux: (y, aux),
+    lambda aux, g: (g, jnp.ones_like(aux)))
+
+
+def sparse_select_xla(q, k, v, qi, ki, wi, *, topk: int,
+                      scale: float | None = None):
+    """Learned sparse attention in plain ``jax.numpy`` — the semantics
+    the kernels (ops/pallas/sparse_attention.py) are tested against, and
+    the path off the TPU. ``q`` (B, S, H, D), ``k, v`` (B, S, G, D) with
+    query head j reading key/value head j // (H / G); ``qi, ki, wi`` the
+    indexer's queries, key and head weights (``index_scores_xla``).
+    Returns (o, L_I, masked index scores): o (B, S, H, D) attends the
+    selected keys alone; L_I is the mean over (b, t) of KL(p_t ||
+    softmax over S_t of I[t]), p_t the probabilities summed over heads,
+    L1-normalised and detached, and its gradient (weight one, to qi, ki
+    and wi only) rides o's backward pass. Materialises (B, H, S, S):
+    small sizes only."""
+    b, s, h, d = q.shape
+    g = k.shape[2]
+    f32 = jnp.float32
+    scale = scale if scale is not None else d ** -0.5
+    scores = index_scores_xla(qi, ki, wi)
+    keep = select_topk_xla(jax.lax.stop_gradient(scores), topk)[0] > -jnp.inf
+    kept = jnp.where(keep, scores, -jnp.inf)
+    lse_i = jax.scipy.special.logsumexp(kept, axis=-1)
+    qg = q.astype(f32).reshape(b, s, g, h // g, d)
+    sc = jnp.einsum("btgpd,bsgd->bgpts", qg, k.astype(f32)) * scale
+    p = jax.nn.softmax(jnp.where(keep[:, None, None], sc, -jnp.inf), -1)
+    o = jnp.einsum("bgpts,bsgd->btgpd", p, v.astype(f32))
+    target = jax.lax.stop_gradient(jnp.sum(p, axis=(1, 2)) / h)
+    logq = jnp.where(keep, scores - lse_i[..., None], 0.0)
+    logp = jnp.log(jnp.where(target > 0, target, 1.0))
+    l_i = jnp.mean(jnp.sum(target * (logp - logq), axis=-1))
+    o = _with_gradient_of(o.reshape(b, s, h, d).astype(q.dtype), l_i)
+    return o, l_i, kept
+
+
+class SparseSelectAttention(Module):
+    """Learned sparse attention (DeepSeek-V3.2-Exp's DSA; the block of
+    Keye-VL-2.0-30B-A3B): grouped-query causal self-attention over
+    (batch, seq, embed) in which query t attends only the ``topk`` keys
+    s <= t that a small INDEXER scores highest (every s <= t while
+    t < topk), and the indexer is trained to imitate the attention it
+    steers. docs/sparse_attention.md has the equations.
+
+    Main path: bias-free q (``num_heads`` x ``head_dim``), k, v
+    (``num_kv_heads`` x ``head_dim``: K/V are never repeated across a
+    group) and out projections, an RMS norm per head on q and k
+    (``q_norm`` / ``k_norm``, a weight of ``head_dim`` each), RoPE.
+    Indexer, on the input DETACHED: ``index_heads`` queries of
+    ``index_dim``, ONE key of ``index_dim`` under a LayerNorm, RoPE on
+    both, a weight a head scaled by index_heads^-1/2 index_dim^-1/2;
+    I[t, s] = sum_j w[t, j] ReLU(qI[t, j] . kI[s]), float32; the
+    selection is exact (lower s wins among equals) and passes no
+    gradient. The indexer's loss L_I = mean_t KL(p_t || softmax over the
+    selection of I[t]) (p_t: the heads' probabilities summed,
+    normalised, detached) is NOT added to anything the caller sees: its
+    gradient, at weight one, is injected into this layer's backward
+    pass and reaches the indexer's five leaves alone, while the
+    caller's loss reaches every other leaf — so ``jax.grad`` of
+    ``criterion(model.apply(...))`` IS the training gradient (DSA's
+    sparse stage), with nothing for an optimizer to opt into. Averaged
+    over micro-batches like any gradient.
+
+    On the TPU the core is the Pallas kernels and shapes they do not
+    take are an error, never another path; elsewhere (the CPU tests) it
+    is ``sparse_select_xla``."""
+
+    def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int, index_heads: int, index_dim: int, topk: int,
+                 rope_theta: float = 10000.0, eps: float = 1e-6):
+        super().__init__()
+        if num_heads % num_kv_heads:
+            raise ValueError(f"num_heads={num_heads} must be a multiple "
+                             f"of num_kv_heads={num_kv_heads}")
+        assert head_dim % 2 == 0 and index_dim % 2 == 0, "rope: even dims"
+        self.embed_dim, self.num_heads = embed_dim, num_heads
+        self.num_kv_heads, self.head_dim = num_kv_heads, head_dim
+        self.index_heads, self.index_dim = index_heads, index_dim
+        self.topk, self.rope_theta, self.eps = topk, rope_theta, eps
+
+    def init(self, rng):
+        e, d = self.embed_dim, self.head_dim
+        shapes = {"q_weight": (self.num_heads * d, e),
+                  "k_weight": (self.num_kv_heads * d, e),
+                  "v_weight": (self.num_kv_heads * d, e),
+                  "out_weight": (e, self.num_heads * d),
+                  "iq_weight": (self.index_heads * self.index_dim, e),
+                  "ik_weight": (self.index_dim, e),
+                  "iw_weight": (self.index_heads, e)}
+        p = {name: init_mod.init_weight(init_mod.Xavier, key, shape,
+                                        fan_in=shape[1], fan_out=shape[0])
+             for (name, shape), key in zip(
+                 shapes.items(), jax.random.split(rng, len(shapes)))}
+        p["q_norm"] = jnp.ones((d,), default_dtype())
+        p["k_norm"] = jnp.ones((d,), default_dtype())
+        p["ik_norm_weight"] = jnp.ones((self.index_dim,), default_dtype())
+        p["ik_norm_bias"] = jnp.zeros((self.index_dim,), default_dtype())
+        return p
+
+    def _head_norm(self, x, w, scale=1.0):
+        """RMS norm over the head dim in float32, rounded once; ``scale``
+        rides the weight (q takes the softmax's head_dim^-1/2 here, so
+        no score tile is ever multiplied by it)."""
+        xs = x.astype(jnp.float32)
+        inv = jax.lax.rsqrt(jnp.mean(jnp.square(xs), -1, keepdims=True)
+                            + self.eps)
+        return (xs * inv * (w.astype(jnp.float32) * scale)).astype(x.dtype)
+
+    def indexer(self, params, h):
+        """(qI (B, S, J, DI), kI (B, S, DI), w (B, S, J)), float32, from
+        the layer's normed input ``h`` in the compute dtype; no gradient
+        reaches ``h``."""
+        f32 = jnp.float32
+        b, s, _ = h.shape
+        h = jax.lax.stop_gradient(h)
+
+        def proj(name):
+            return jnp.matmul(h, params[name].astype(h.dtype).T,
+                              preferred_element_type=f32)
+
+        pos = jnp.arange(s)
+        qi = apply_rope(proj("iq_weight").reshape(
+            b, s, self.index_heads, self.index_dim), pos, self.rope_theta)
+        ki = proj("ik_weight")
+        mu = jnp.mean(ki, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(ki - mu), axis=-1, keepdims=True)
+        ki = ((ki - mu) * jax.lax.rsqrt(var + self.eps)
+              * params["ik_norm_weight"].astype(f32)
+              + params["ik_norm_bias"].astype(f32))
+        ki = apply_rope(ki[:, :, None, :], pos, self.rope_theta)[:, :, 0]
+        wi = proj("iw_weight") * (self.index_heads ** -0.5
+                                  * self.index_dim ** -0.5)
+        return qi, ki, wi
+
+    def core_inputs(self, params, x):
+        """(q, k, v, qI, kI, w) of ``sparse_select_xla`` from the layer's
+        normed input: q carries the softmax's head_dim^-1/2 (so the core
+        runs at scale 1)."""
+        b, s, _ = x.shape
+        cd = compute_dtype()
+        h = x.astype(cd)
+
+        def proj(name, heads):
+            return jnp.matmul(h, params[f"{name}_weight"].astype(cd).T) \
+                .reshape(b, s, heads, self.head_dim)
+
+        pos = jnp.arange(s)
+        q = apply_rope(self._head_norm(proj("q", self.num_heads),
+                                       params["q_norm"],
+                                       self.head_dim ** -0.5),
+                       pos, self.rope_theta)
+        k = apply_rope(self._head_norm(proj("k", self.num_kv_heads),
+                                       params["k_norm"]),
+                       pos, self.rope_theta)
+        with jax.named_scope("indexer"):
+            qi, ki, wi = self.indexer(params, h)
+        return q, k, proj("v", self.num_kv_heads), qi, ki, wi
+
+    def indexer_loss(self, params, x):
+        """L_I of this layer on its normed input ``x``, by the jnp path
+        (small sizes): the training path forms only its gradient."""
+        return sparse_select_xla(*self.core_inputs(params, x),
+                                 topk=self.topk, scale=1.0)[1]
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        from bigdl_tpu.observability import trace
+        b, s, _ = x.shape
+        inputs = self.core_inputs(params, x)
+        kept = min(self.topk, s)
+        selected = kept * (kept + 1) // 2 + (s - kept) * kept
+        # python runs this when the layer is traced for a compile, never
+        # in a step
+        trace.instant("sparse_select", cat="nn", seq=s, topk=self.topk,
+                      selected_pairs=b * selected,
+                      causal_pairs=b * s * (s + 1) // 2,
+                      materialised_bytes=b * s * s * 4)
+        if jax.default_backend() == "tpu":
+            from bigdl_tpu.ops.pallas.sparse_attention import (
+                sparse_select_attention)
+            o = sparse_select_attention(*inputs, topk=self.topk, scale=1.0)
+        else:
+            o = sparse_select_xla(*inputs, topk=self.topk, scale=1.0)[0]
+        y = jnp.matmul(o.reshape(b, s, self.num_heads * self.head_dim),
+                       params["out_weight"].astype(compute_dtype()).T)
+        return y.astype(activation_dtype()), state
+
+    def __repr__(self):
+        return (f"SparseSelectAttention({self.embed_dim}, "
+                f"heads={self.num_heads}/{self.num_kv_heads}x"
+                f"{self.head_dim}, indexer={self.index_heads}x"
+                f"{self.index_dim}, topk={self.topk})")

@@ -22,6 +22,13 @@ training objective and publishes the drop/overflow/imbalance counters to
 the metric registry at epoch boundaries (one batched ``jax.device_get``
 per epoch — never a per-step sync; see docs/PERFORMANCE.md).
 
+One chip's SHARE of a layer with more experts than a chip holds
+(PR 31): :class:`ExpertShare` is told which experts it holds, routes
+over all of them, renormalises over the k chosen and computes its own
+experts' part by dropless grouped matrix products — no capacity, no
+all_to_all (on one chip it runs without its exchange). It shares the
+router (``route_top_k``) with :class:`MoE`; docs/expert_share.md.
+
 Combine-weight semantics after capacity drops: the k gate probabilities
 renormalize over the KEPT ranks only. A dropped second choice used to
 leave the first choice's weight at p1/(p1+p2) — every affected token's
@@ -31,6 +38,8 @@ tests/test_expert_parallel.py).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
@@ -38,7 +47,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from bigdl_tpu.parallel.collective import shard_map
 from bigdl_tpu.parallel.engine import get_mesh
 
-__all__ = ["moe_apply", "MoE", "moe_aux_total", "moe_state_stats",
+__all__ = ["moe_apply", "MoE", "ExpertShare", "route_top_k",
+           "grouped_matmul", "moe_aux_total", "moe_state_stats",
            "publish_moe_metrics"]
 
 #: module-state keys the MoE layer maintains (floats — they survive the
@@ -46,6 +56,24 @@ __all__ = ["moe_apply", "MoE", "moe_aux_total", "moe_state_stats",
 MOE_STATE_KEYS = ("moe_aux", "moe_dropped_rank_frac",
                   "moe_dropped_token_frac", "moe_overflow_tokens",
                   "moe_load_imbalance")
+
+
+#: module-state keys ``ExpertShare`` maintains (floats, as above)
+SHARE_STATE_KEYS = ("moe_held_load_max", "moe_held_load_mean",
+                    "moe_local_assignment_share", "moe_tokens_without_local")
+
+
+def route_top_k(x, gate_w, k: int, precision=None):
+    """The router both layers share: float32 logits ``x @ gate_w``
+    (``gate_w``: (d, E)), a float32 softmax over all E experts, the k
+    largest probabilities of each token (lower expert number first among
+    equals). Returns (probs (T, E), top_p (T, k), top (T, k))."""
+    f32 = jnp.float32
+    logits = jnp.matmul(x.astype(f32), gate_w.astype(f32),
+                        precision=precision)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top = jax.lax.top_k(probs, k)
+    return probs, top_p, top
 
 
 def moe_apply(expert_apply, stacked_expert_params, x, gate_w, *,
@@ -100,9 +128,7 @@ def moe_apply(expert_apply, stacked_expert_params, x, gate_w, *,
     def body(expert_params, xb, gw):
         # xb: (t_local, d) — this shard's tokens
         f32 = jnp.float32
-        logits = (xb.astype(f32) @ gw.astype(f32))            # (T, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top = jax.lax.top_k(probs, k)                  # (T, k)
+        probs, top_p, top = route_top_k(xb, gw, k)            # (T, k)
 
         # rank-ordered capacity assignment: rank r's queue positions
         # start where ranks < r left each expert's occupancy
@@ -287,6 +313,303 @@ class MoE(_Module):
                 f"cf={self.capacity_factor}, axis={self.axis!r})")
 
 
+def grouped_matmul(x, w, group_sizes, *, interpret: bool = False):
+    """Rows of ``x`` (M, k), sorted into consecutive groups of
+    ``group_sizes`` (E + 1,) rows, each group times its own matrix:
+    group e < E by ``w[e]`` ((E, n, k): out by in, as every matrix
+    here), and the LAST group — rows no matrix here is for — gives
+    zeros. (M, n) in x's dtype, float32 accumulation; differentiable in
+    x and w. Work follows the rows that have a matrix, not M: on the TPU
+    ``jax.experimental.pallas.ops.tpu.megablox.gmm`` (its grid is the
+    row tiles in use, 512 rows each: smaller tiles were timed and lose,
+    PERF.md section 6, PR 31, where ``lax.ragged_dot`` is timed beside
+    it), elsewhere ``lax.ragged_dot``."""
+    m, k = x.shape
+    n = w.shape[1]
+    if jax.default_backend() == "tpu" or interpret:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        tm = next((t for t in (512, 256, 128, 64, 32, 16, 8)
+                   if m % t == 0), m)
+
+        def tile(size, most):
+            return next((t for t in range(most, 127, -128)
+                         if size % t == 0), size)
+
+        return gmm(x, w, group_sizes, x.dtype,
+                   (tm, tile(k, 1024), tile(n, 1024)), None, None, True,
+                   interpret)
+    return jax.lax.ragged_dot(x, w.swapaxes(1, 2), group_sizes[:-1],
+                              preferred_element_type=x.dtype)
+
+
+def _sum_by_token(rows, at, weights=None):
+    """(T, d) float32: token t's sum over its k assignments of
+    ``rows[at[t, j]]`` (times ``weights[t, j]``), where ``at`` (T, k) is
+    ``len(rows)`` for an assignment that has no row there: it adds zero.
+    One gather of T rows an assignment, so nothing of T k rows is ever
+    made."""
+    total = 0.0
+    for j in range(at.shape[1]):
+        part = jnp.take(rows, at[:, j], axis=0, mode="fill",
+                        fill_value=0).astype(jnp.float32)
+        total = total + (part if weights is None
+                         else weights[:, j, None] * part)
+    return total
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_by_expert(x, idx, at, k):
+    """Token rows (T, d) -> the assignment rows (R, d) that ``idx`` (R,)
+    names (assignment a is token ``a // k``). ``at`` (T, k) says where
+    among the R each assignment went, R for "not among them": the
+    backward is gathers by it summed over a token's k, never a
+    scatter."""
+    return jnp.take(x, idx // k, axis=0)
+
+
+_rows_by_expert.defvjp(
+    lambda x, idx, at, k: (_rows_by_expert(x, idx, at, k), at),
+    lambda k, at, g: (_sum_by_token(g, at).astype(g.dtype), None, None))
+
+
+@jax.custom_vjp
+def _combine(out, cw, idx, at):
+    """y[t] = sum over t's k assignments of ``cw[t, j] out[at[t, j]]``
+    ((T, d) float32; an assignment that is not among ``out``'s R rows
+    adds zero). The backward works on the R rows alone:
+    ``d out[r] = cw[idx[r]] dy[idx[r] // k]`` and ``d cw`` from the R
+    dot products ``dy[idx[r] // k] . out[r]``."""
+    return _sum_by_token(out, at, cw)
+
+
+def _combine_bwd(res, dy):
+    out, cw, idx, at = res
+    dy_r = jnp.take(dy, idx // cw.shape[1], axis=0)
+    cw_r = jnp.take(cw.reshape(-1), idx)
+    d_cw_r = jnp.sum(dy_r * out, axis=-1)
+    return ((cw_r[:, None] * dy_r).astype(out.dtype),
+            jnp.take(d_cw_r, at, mode="fill", fill_value=0).astype(cw.dtype),
+            None, None)
+
+
+_combine.defvjp(lambda out, cw, idx, at: (_combine(out, cw, idx, at),
+                                          (out, cw, idx, at)), _combine_bwd)
+
+
+def _chunk_rows(assignments: int, held: int, total: int) -> int:
+    """Sorted assignment rows worked on at a time: FOUR times what lands
+    here when the router is balanced (``assignments held / total``), as
+    the nearest whole division of ``assignments``. Four, because an
+    untrained router sends every token to the same k experts (PERF.md
+    section 6, PR 31): the share that lands here is then j / k with j of
+    them held, and j > 4 of 8 has 7 chances in 10000 at 16 of 128."""
+    most = max(1, total // (4 * held))
+    return assignments // max(n for n in range(1, most + 1)
+                              if assignments % n == 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _in_chunks(chunk, rows, weights, tokens, cw, where, live):
+    """``sum over lo = 0, rows, 2 rows, ... of chunk(weights, tokens, cw,
+    where, lo=lo, rows=rows)``: the chunk at 0 always, a later one only
+    when ``live > lo`` (a ``lax.cond`` each). It keeps its arguments and
+    nothing else: the backward pass recomputes each chunk that ran and
+    adds its gradients into ONE set of accumulators handed through the
+    ``cond``s, where autodiff of the ``cond``s would return a
+    parameter-sized set of zeros from every chunk that did not run."""
+    total = where[0].shape[0]
+    y = chunk(weights, tokens, cw, where, lo=0, rows=rows)
+    for lo in range(rows, total, rows):
+        y = jax.lax.cond(
+            live > lo,
+            lambda y, lo=lo: y + chunk(weights, tokens, cw, where, lo=lo,
+                                       rows=rows),
+            lambda y: y, y)
+    return y
+
+
+def _in_chunks_bwd(chunk, rows, res, dy):
+    weights, tokens, cw, where, live = res
+
+    def grads(lo):
+        return jax.vjp(lambda *a: chunk(*a, where, lo=lo, rows=rows),
+                       weights, tokens, cw)[1](dy)
+
+    acc = grads(0)
+    for lo in range(rows, where[0].shape[0], rows):
+        acc = jax.lax.cond(
+            live > lo,
+            lambda acc, lo=lo: jax.tree.map(jnp.add, acc, grads(lo)),
+            lambda acc: acc, acc)
+    return (*acc, None, None)
+
+
+_in_chunks.defvjp(
+    lambda chunk, rows, *args: (_in_chunks(chunk, rows, *args), args),
+    _in_chunks_bwd)
+
+
+class ExpertShare(_Module):
+    """One chip's share of a routed mixture-of-experts layer: it is TOLD
+    which experts it holds — ``experts_held`` of ``experts_total``, from
+    number ``experts_offset`` — routes every token over all
+    ``experts_total`` (float32 logits and softmax, the ``top_k`` largest,
+    divided by their sum over all ``top_k``, held here or not), and
+    computes the part of the result its own experts give:
+
+        y[t] = sum over e in top_k(t) AND held here of
+               c[t, e] W_down,e( silu(W_gate,e x[t]) * (W_up,e x[t]) ).
+
+    A token none of whose experts live here gets zero. DROPLESS: there
+    is no capacity. The T k assignments are sorted by expert, those for
+    experts elsewhere last, and the sorted rows are worked on a CHUNK at
+    a time (``_chunk_rows``: four times the balanced share), each through
+    grouped matrix products over ragged groups (``grouped_matmul``),
+    forward and backward. The first chunk always runs; a later one runs
+    only when the assignments that land here reach it (``_in_chunks``: a
+    ``lax.cond`` each, every chunk recomputed in the backward pass), so
+    one expert may take every token and the step is sized for the
+    routing it meets, not for the worst. A chunk's rows for experts
+    elsewhere ride in its last held expert's group with weight zero: a
+    chunk's work is its rows, whatever the routing, so the layer's time
+    moves only when another chunk is reached. With ``experts_held ==
+    experts_total`` it is the whole layer in one chunk. On one chip it
+    runs without an exchange and nothing here stands in for the absent
+    chips: summed over the shares of a layer, the results are the uncut
+    layer's (tests/test_keye.py).
+
+    It shares ``route_top_k`` with ``MoE``. The combine is its own:
+    ``MoE`` reads each rank's output back from a capacity slot and
+    renormalises over the ranks that were KEPT; here nothing is dropped,
+    the weights are normalised before anything is placed, and a token's
+    k rows come back by one gather. No load-balancing term: ``moe_aux``
+    is not in its state, and ``moe_aux_total`` skips it.
+
+    Run-time routing telemetry rides the module STATE as ``MoE``'s does
+    (read out with the losses, never a sync of its own):
+    ``moe_held_load_max`` / ``moe_held_load_mean`` (assignments of the
+    busiest held expert, and of the mean one),
+    ``moe_local_assignment_share`` (assignments landing here over all
+    T k) and ``moe_tokens_without_local`` (share of tokens that get
+    zero)."""
+
+    def __init__(self, d_model: int, d_ff: int, experts_total: int,
+                 top_k: int, *, experts_held: int | None = None,
+                 experts_offset: int = 0):
+        super().__init__()
+        held = experts_total if experts_held is None else experts_held
+        if not 0 <= experts_offset <= experts_total - held:
+            raise ValueError(
+                f"experts {experts_offset}..{experts_offset + held - 1} "
+                f"are not among {experts_total}")
+        if not 1 <= top_k <= experts_total:
+            raise ValueError(f"top_k={top_k} of {experts_total} experts")
+        self.d_model, self.d_ff = int(d_model), int(d_ff)
+        self.experts_total, self.experts_held = int(experts_total), held
+        self.experts_offset, self.top_k = int(experts_offset), int(top_k)
+
+    def init(self, rng):
+        from bigdl_tpu.nn import init as init_mod
+        e, d, f = self.experts_held, self.d_model, self.d_ff
+        kr, *ks = jax.random.split(rng, 4)
+        shapes = {"gate_weight": (e, f, d), "up_weight": (e, f, d),
+                  "down_weight": (e, d, f)}
+        p = {name: init_mod.init_weight(init_mod.Default, key, shape,
+                                        fan_in=shape[2], fan_out=shape[1])
+             for (name, shape), key in zip(shapes.items(), ks)}
+        p["router_weight"] = init_mod.init_weight(
+            init_mod.Xavier, kr, (self.experts_total, d), fan_in=d,
+            fan_out=self.experts_total)
+        return p
+
+    def init_state(self):
+        return {key: jnp.zeros((), jnp.float32) for key in SHARE_STATE_KEYS}
+
+    def route(self, params, tokens):
+        """(numbers of each token's ``top_k`` experts (T, k), their
+        weights normalised over the k)."""
+        _, top_p, top = route_top_k(tokens, params["router_weight"].T,
+                                    self.top_k, precision="highest")
+        return top, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+
+    def _chunk(self, weights, tokens, cw, where, *, lo, rows):
+        """What sorted assignment rows ``lo .. lo + rows - 1`` add to the
+        result: (T, d) float32. ``weights``: the experts' three stacks in
+        the compute dtype; ``cw`` (T, k): the combine weights, zero for
+        an expert elsewhere; ``where``: the sorted order of the T k
+        assignments, its inverse, and (held + 1,) the sorted row at
+        which each held expert's group starts and the last one ends."""
+        order, inverse, starts = where
+        held = self.experts_held
+        idx = jax.lax.slice_in_dim(order, lo, lo + rows)
+        at = jnp.where((inverse >= lo) & (inverse < lo + rows),
+                       inverse - lo, rows).reshape(cw.shape)
+        edges = jnp.clip(starts, lo, lo + rows)
+        # the rows past the last held expert's are for experts elsewhere:
+        # they ride in its group (weight zero), so the products' work is
+        # this chunk's rows whatever the routing
+        sizes = jnp.diff(edges.at[held].set(lo + rows), append=lo + rows)
+        x = _rows_by_expert(tokens, idx, at, self.top_k)
+        gate, up, down = (functools.partial(grouped_matmul, w=w,
+                                            group_sizes=sizes)
+                          for w in weights)
+        return _combine(down(jax.nn.silu(gate(x)) * up(x)), cw, idx, at)
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        from bigdl_tpu.observability import trace
+        from bigdl_tpu.tensor import activation_dtype, compute_dtype
+        d, k, held = x.shape[-1], self.top_k, self.experts_held
+        if d != self.d_model:
+            raise ValueError(f"ExpertShare built for d_model="
+                             f"{self.d_model}, got feature dim {d}")
+        tokens = x.reshape(-1, d)
+        t = tokens.shape[0]
+        rows = _chunk_rows(t * k, held, self.experts_total)
+        # python runs this when the layer is traced for a compile, never
+        # in a step
+        trace.instant("moe_share", cat="nn", experts_total=self.experts_total,
+                      experts_held=held, top_k=k, tokens=t,
+                      expected_local_assignments=t * k * held
+                      / self.experts_total)
+        with jax.named_scope("moe_router"):
+            top, c = self.route(params, tokens)
+            local = top - self.experts_offset
+            here = (local >= 0) & (local < held)
+            # assignments by expert held, those for elsewhere last
+            group = jnp.where(here, local, held).reshape(-1)
+            order = jnp.argsort(group, stable=True).astype(jnp.int32)
+            inverse = jnp.argsort(order).astype(jnp.int32)
+            counts = jnp.sum(group[:, None] == jnp.arange(held), axis=0,
+                             dtype=jnp.int32)
+            starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                      jnp.cumsum(counts)])
+        with jax.named_scope("moe_experts"):
+            cd = compute_dtype()
+            # the experts' matrices in the compute dtype, made ONCE
+            y = _in_chunks(
+                self._chunk, rows,
+                tuple(params[name].astype(cd) for name in
+                      ("gate_weight", "up_weight", "down_weight")),
+                tokens.astype(cd), jnp.where(here, c, 0.0),
+                (order, inverse, starts), starts[held])
+        f32 = jnp.float32
+        loads = counts.astype(f32)
+        stats = {
+            "moe_held_load_max": jnp.max(loads),
+            "moe_held_load_mean": jnp.mean(loads),
+            "moe_local_assignment_share": jnp.sum(loads) / (t * k),
+            "moe_tokens_without_local":
+                1.0 - jnp.mean(jnp.any(here, axis=-1), dtype=f32),
+        }
+        return (y.reshape(x.shape).astype(activation_dtype()),
+                jax.tree.map(jax.lax.stop_gradient, stats))
+
+    def __repr__(self):
+        return (f"ExpertShare(d{self.d_model}x{self.d_ff}, experts "
+                f"{self.experts_offset}..+{self.experts_held} of "
+                f"{self.experts_total}, k={self.top_k})")
+
+
 def moe_aux_total(mstate):
     """Sum of every MoE layer's load-balancing aux loss in a module
     state tree (traced — this is the term ``set_expert_parallel`` folds
@@ -308,17 +631,19 @@ def moe_aux_total(mstate):
 
 
 def moe_state_stats(mstate) -> dict:
-    """Walk a module-state tree for MoE layer states and return
-    ``{path: {stat: device array}}`` — one ``jax.device_get`` away from
-    host values (the caller batches the readback)."""
+    """Walk a module-state tree for MoE layer states (``MoE``'s and
+    ``ExpertShare``'s alike) and return ``{path: {stat: device array}}``
+    — one ``jax.device_get`` away from host values (the caller batches
+    the readback)."""
     found = {}
 
     def walk(tree, path):
         if isinstance(tree, dict):
-            if "moe_aux" in tree:
+            keys = [key for key in MOE_STATE_KEYS + SHARE_STATE_KEYS
+                    if key in tree]
+            if keys:
                 found["/".join(path) or "moe"] = {
-                    key: tree[key] for key in MOE_STATE_KEYS
-                    if key in tree}
+                    key: tree[key] for key in keys}
                 return
             for key, sub in tree.items():
                 walk(sub, path + [str(key)])

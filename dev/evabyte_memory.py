@@ -1,9 +1,12 @@
 #!/usr/bin/env python3
-"""Compile-only memory analysis of the EvaByte cell for a described v5e
-(benchmarks/README.md, rehearsal ladder step 2; no chip, no chip time):
+"""Compile-only memory analysis of a one-chip training cell for a
+described v5e (benchmarks/README.md, rehearsal ladder step 2; no chip,
+no chip time) — the EvaByte cell, or with ``--cell`` another whose
+reference has ``_layer_vjp`` / ``_head_vjp`` (keye-vl-2.0-30b-a3b.train.
+seq16384):
 
-    JAX_PLATFORMS=cpu python3 dev/evabyte_memory.py [--layers 4] \
-        [--seq 16384] [--batch 1]
+    JAX_PLATFORMS=cpu python3 dev/evabyte_memory.py [--cell NAME] \
+        [--layers N] [--seq 16384] [--batch 1]
 
 Three programs at the configuration's widths: the training step
 ``make_train_step`` builds (AdamW, donated state), the check's bare
@@ -28,7 +31,8 @@ import jax.numpy as jnp  # noqa: E402
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--layers", type=int, default=4)
+    ap.add_argument("--cell", default="evabyte-6.5b.train.long")
+    ap.add_argument("--layers", type=int, help="default: the file's")
     ap.add_argument("--seq", type=int, default=16384)
     ap.add_argument("--batch", type=int, default=1)
     ap.add_argument("--only", default="step,check,reference")
@@ -39,8 +43,6 @@ def main():
     from jax.sharding import SingleDeviceSharding
 
     from benchmarks import manifest, model_setup
-    from benchmarks.builders import evabyte as builder
-    from benchmarks.reference import evabyte as reference
     from bigdl_tpu import optim
     from bigdl_tpu.observability.tracing import matmuls_fed_by
     from bigdl_tpu.optim.accumulation import make_train_step
@@ -51,9 +53,13 @@ def main():
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
 
-    cfg = dict(manifest.data_file("configs", "evabyte-6.5b"),
-               num_hidden_layers=args.layers)
-    traffic = manifest.data_file("traffic", "pretrain-bytes-seq16384")
+    cell = manifest.data_file("workloads", args.cell)
+    cfg = manifest.data_file("configs", cell["config"])
+    if args.layers:
+        cfg = dict(cfg, num_hidden_layers=args.layers)
+    traffic = manifest.data_file("traffic", cell["traffic"])
+    builder = manifest.plugin("builders", cfg["builder"])
+    reference = manifest.plugin("reference", cfg["reference"])
     model_setup.set_dtype_policy(cfg["policy"])
     model = builder.build(cfg)
     crit = builder.criterion()
@@ -65,8 +71,8 @@ def main():
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     state = model.init_state()
     n = sum(x.size for x in jax.tree.leaves(params))
-    print(f"{args.layers} layers, {n / 1e6:.1f}M parameters, "
-          f"{args.batch} x {args.seq} bytes")
+    print(f"{cfg['num_hidden_layers']} layers, {n / 1e6:.1f}M parameters, "
+          f"{args.batch} x {args.seq} tokens")
     ids = jax.ShapeDtypeStruct((args.batch, args.seq), jnp.int32,
                                sharding=chip)
 

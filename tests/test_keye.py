@@ -1,0 +1,642 @@
+"""Keye-VL-2.0-30B-A3B's language model on the training path (ISSUE 31):
+``nn.SparseSelectAttention`` (learned top-k sparse attention, its
+indexer and the indexer's loss) and its Pallas kernels,
+``parallel.expert.ExpertShare`` (one chip's share of a routed
+mixture-of-experts layer, dropless) and ``KeyeLM``.
+
+The model tests compare the program with the plain float32 reference
+(benchmarks/reference/keye.py) on the logits, the loss, the selection
+and EVERY gradient leaf — the indexer's under L_I — at 48 tokens with
+top-12 keys and 4 of 8 experts held, with every norm weight and bias
+perturbed so that it matters.
+"""
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmarks.builders import keye as builder
+from benchmarks.reference import keye as reference
+from bigdl_tpu.nn import attention as attention_mod
+from bigdl_tpu.parallel import expert as expert_mod
+from bigdl_tpu.parallel.expert import ExpertShare
+from bigdl_tpu.tensor import DTypePolicy, policy_scope
+
+CFG = dict(vocab_size=50, hidden_size=32, num_attention_heads=4,
+           num_key_value_heads=2, head_dim=8, num_hidden_layers=2,
+           moe_intermediate_size=16, published={"num_experts": 8},
+           num_experts_per_tok=2, num_experts=4, num_local_experts=4,
+           experts_offset=2, rope_theta=1e4, rms_norm_eps=1e-6,
+           sa_config=dict(indexer_num_heads=2, indexer_head_dim=8,
+                          topk=12))
+HEADS = CFG["num_attention_heads"]
+SEQ = 48
+TOL = 2e-5          # float32 on both sides, another order of summation
+LEAVES = ("ln1_g", "q_w", "k_w", "v_w", "o_w", "qn_g", "kn_g", "iq_w",
+          "ik_w", "ik_ln_g", "ik_ln_b", "iw_w", "ln2_g", "router_w",
+          "gate_w", "up_w", "down_w")
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _float32_policy():
+    """float32 on both sides, at full matmul precision, whatever policy
+    an earlier file of this worker left set."""
+    f32 = jnp.dtype("float32")
+    with policy_scope(DTypePolicy(param_dtype=f32, compute_dtype=f32,
+                                  activation_dtype=f32)), \
+            jax.default_matmul_precision("highest"):
+        yield
+
+
+def _batch(seq=SEQ, rows=2, seed=0):
+    toks = np.random.default_rng(seed).integers(
+        1, CFG["vocab_size"] + 1, size=(rows, seq + 1))
+    return jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:])
+
+
+def _perturbed(params, seed=1):
+    """Every leaf moved off its initial value: a norm weight of one and
+    a bias of zero hide a missing norm."""
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return tree.unflatten([a + 0.1 * jax.random.normal(k, a.shape, a.dtype)
+                           for a, k in zip(leaves, keys)])
+
+
+@pytest.fixture(scope="module")
+def system():
+    model = builder.build(CFG)
+    params = _perturbed(model.init(jax.random.PRNGKey(0)))
+    return model, params, model.init_state()
+
+
+@pytest.fixture(scope="module")
+def both(system):
+    """(system loss, system gradients as the reference names them,
+    reference loss, reference gradients) on one batch."""
+    model, params, state = system
+    x, t = _batch()
+    crit = builder.criterion()
+    loss, grads = jax.value_and_grad(lambda p: crit.apply(
+        model.apply(p, state, x, training=True)[0], t))(params)
+    w = builder.reference_weights(params, CFG)
+    ref_loss, ref_grads = reference.loss_and_grads(w, x - 1, t - 1, HEADS)
+    return (float(loss), builder.reference_weights(grads, CFG), ref_loss,
+            ref_grads)
+
+
+def _rel(a, b):
+    return float(jnp.linalg.norm(jnp.asarray(a) - jnp.asarray(b))
+                 / jnp.linalg.norm(jnp.asarray(b)))
+
+
+def test_logits_match_the_reference(system):
+    model, params, state = system
+    x, _ = _batch()
+    w = builder.reference_weights(params, CFG)
+    got = model.apply(params, state, x, training=True)[0]
+    want = jnp.stack([reference.logits(w, x[i] - 1, HEADS)
+                      for i in range(x.shape[0])])
+    assert got.shape == (2, SEQ, CFG["vocab_size"])
+    assert float(jnp.abs(got - want).max()) < TOL
+
+
+def test_loss_matches_the_reference_and_carries_no_indexer_term(both):
+    loss, _, ref_loss, _ = both
+    assert abs(loss - ref_loss) < TOL * abs(ref_loss)
+
+
+@pytest.mark.parametrize("layer", range(CFG["num_hidden_layers"]))
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_every_layer_leafs_gradient_matches_the_reference(both, layer, leaf):
+    """The indexer's five leaves get L_I's gradient, every other the
+    loss's: one ``jax.grad`` of the program against the reference's
+    chain rule by hand."""
+    _, grads, _, ref_grads = both
+    got, want = grads["layers"][layer][leaf], ref_grads["layers"][layer][leaf]
+    assert float(jnp.linalg.norm(jnp.asarray(want))) > 1e-4
+    assert _rel(got, want) < TOL
+
+
+@pytest.mark.parametrize("leaf", ["tok", "lnf_g", "head_w"])
+def test_embedding_and_head_gradients_match_the_reference(both, leaf):
+    _, grads, _, ref_grads = both
+    assert _rel(grads[leaf], ref_grads[leaf]) < TOL
+
+
+def test_the_indexer_gets_no_gradient_from_the_loss_and_nothing_else_from_l_i(
+        system, monkeypatch):
+    """With L_I's injection taken out, the indexer's leaves get exactly
+    zero and every other leaf what it got before."""
+    model, params, state = system
+    x, t = _batch()
+    crit = builder.criterion()
+
+    def grads():
+        return builder.reference_weights(jax.grad(lambda p: crit.apply(
+            model.apply(p, state, x, training=True)[0], t))(params), CFG)
+
+    with_l_i = grads()
+    monkeypatch.setattr(attention_mod, "_with_gradient_of",
+                        lambda y, aux: y)
+    without = grads()
+    for layer, plain in zip(with_l_i["layers"], without["layers"]):
+        for leaf in LEAVES:
+            if leaf in reference.INDEXER_LEAVES:
+                assert float(jnp.abs(plain[leaf]).max()) == 0.0
+                assert float(jnp.abs(layer[leaf]).max()) > 0.0
+            else:
+                assert _rel(plain[leaf], layer[leaf]) < 1e-6
+
+
+def _attention_inputs(seq, seed=0, batch=2, h=4, g=2, d=8, j=2, di=8):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return (jax.random.normal(ks[0], (batch, seq, h, d)),
+            jax.random.normal(ks[1], (batch, seq, g, d)),
+            jax.random.normal(ks[2], (batch, seq, g, d)),
+            jax.random.normal(ks[3], (batch, seq, j, di)),
+            jax.random.normal(ks[4], (batch, seq, di)),
+            jax.random.normal(ks[5], (batch, seq, j)) * 0.3)
+
+
+def test_the_selection_is_the_references(system):
+    """The indices kept — not just what attention makes of them."""
+    model, params, state = system
+    x, _ = _batch()
+    w = builder.reference_weights(params, CFG)
+    block = model.modules[1]
+    emb = model.modules[0].apply(params["0"], state["0"], x)[0]
+    u = block.modules[0].modules[0].apply(params["1"]["0"]["0"], {}, emb)[0]
+    att = block.modules[0].modules[1]
+    qi, ki, wi = att.indexer(params["1"]["0"]["1"], u)
+    kept, _ = attention_mod.select_topk_xla(
+        attention_mod.index_scores_xla(qi, ki, wi), att.topk)
+    for i in range(x.shape[0]):
+        want = reference.selection(w["layers"][0], u[i], w.spec)
+        assert bool(jnp.array_equal(kept[i] > -jnp.inf, want))
+        counts = np.asarray(want.sum(-1))
+        assert list(counts) == [min(t + 1, att.topk) for t in range(SEQ)]
+
+
+def test_equal_scores_keep_the_lower_index():
+    scores = jnp.zeros((1, 6, 6)).at[0, 5, 4].set(1.0)
+    kept, lse = attention_mod.select_topk_xla(scores, 3)
+    assert np.asarray(kept[0] > -jnp.inf).tolist() == [
+        [1, 0, 0, 0, 0, 0], [1, 1, 0, 0, 0, 0], [1, 1, 1, 0, 0, 0],
+        [1, 1, 1, 0, 0, 0], [1, 1, 1, 0, 0, 0], [1, 1, 0, 0, 1, 0]]
+    assert float(lse[0, 0]) == 0.0
+    want = reference.select(scores[0], jnp.arange(6), 3)
+    assert bool(jnp.array_equal(kept[0] > -jnp.inf, want))
+
+
+def test_below_topk_tokens_it_is_dense_causal_gqa_attention():
+    q, k, v, qi, ki, wi = _attention_inputs(16)
+    o, l_i, kept = attention_mod.sparse_select_xla(q, k, v, qi, ki, wi,
+                                                   topk=16)
+    sc = jnp.einsum("bthd,bshd->bhts", q, jnp.repeat(k, 2, axis=2)) \
+        * 8 ** -0.5
+    sc = jnp.where(jnp.tril(jnp.ones((16, 16), bool)), sc, -jnp.inf)
+    want = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(sc, -1),
+                      jnp.repeat(v, 2, axis=2))
+    assert float(jnp.abs(o - want).max()) < 1e-5
+    assert bool(jnp.array_equal(kept[0] > -jnp.inf,
+                                jnp.tril(jnp.ones((16, 16), bool))))
+    assert float(l_i) > 0.0
+
+
+def test_indexer_loss_value_is_the_references(system):
+    model, params, state = system
+    x, _ = _batch()
+    w = builder.reference_weights(params, CFG)
+    want = reference.indexer_loss(w, x - 1, HEADS)
+    block = model.modules[1]
+    emb = model.modules[0].apply(params["0"], state["0"], x)[0]
+    p_att = params["1"]["0"]
+    u = block.modules[0].modules[0].apply(p_att["0"], {}, emb)[0]
+    att = block.modules[0].modules[1]
+    l_i = att.indexer_loss(p_att["1"], u)
+    assert abs(float(l_i) - want[0]) < TOL * want[0]
+
+
+# --------------------------------------------------------------------------
+# the Pallas kernels, interpreted, against the jnp path
+# --------------------------------------------------------------------------
+
+KERNEL = dict(batch=2, h=4, g=2, d=32, j=2, di=16)
+
+
+def test_index_scores_kernel_matches_on_every_causal_pair():
+    from bigdl_tpu.ops.pallas import sparse_attention as sa
+    _, _, _, qi, ki, wi = _attention_inputs(256, **KERNEL)
+    got = sa.index_scores(qi.transpose(0, 2, 1, 3), ki, wi, interpret=True)
+    want = attention_mod.index_scores_xla(qi, ki, wi)
+    seen = jnp.tril(jnp.ones((256, 256), bool))
+    # float32 as two bfloat16 halves: 2^-16 of scores up to ~8, where
+    # ONE bfloat16 pass is off by 2^-8 of them
+    assert float(jnp.abs(jnp.where(seen, got - want, 0.0)).max()) < 1e-4
+    one_pass = attention_mod.index_scores_xla(
+        qi.astype(jnp.bfloat16), ki.astype(jnp.bfloat16), wi)
+    assert float(jnp.abs(one_pass - want).max()) > 1e-2
+
+
+@pytest.mark.parametrize("topk", [48, 128, 300])
+@pytest.mark.parametrize("quantised", [False, True], ids=["distinct", "ties"])
+def test_select_rows_kernel_is_exact(topk, quantised):
+    """Bisection over the float's bits against a sort; with scores
+    rounded to halves most thresholds are tied and the lower index must
+    win; what lies in a query's future may hold anything."""
+    from bigdl_tpu.ops.pallas import sparse_attention as sa
+    _, _, _, qi, ki, wi = _attention_inputs(256, seed=topk, **KERNEL)
+    scores = attention_mod.index_scores_xla(qi, ki, wi)
+    if quantised:
+        scores = jnp.round(scores * 2) / 2 * jnp.where(
+            jnp.arange(256) % 7 == 0, -1.0, 1.0)       # -0.0 among them
+    want, want_lse = attention_mod.select_topk_xla(scores, topk)
+    future = jnp.triu(jnp.ones((256, 256), bool), 1)
+    got, lse = sa.select_rows(jnp.where(future, jnp.nan, scores), topk,
+                              interpret=True)
+    assert bool(jnp.array_equal(got, want))
+    assert float(jnp.abs(lse[..., 0] - want_lse).max()) < 1e-5
+    for b in range(scores.shape[0]):
+        ref = reference.select(scores[b], jnp.arange(256), topk)
+        assert bool(jnp.array_equal(got[b] > -jnp.inf, ref))
+
+
+@pytest.fixture(scope="module")
+def kernel_and_jnp():
+    """Outputs and all six gradients of the interpreted kernels and of
+    the jnp path, under one random cotangent."""
+    from bigdl_tpu.ops.pallas.sparse_attention import sparse_select_attention
+    args = _attention_inputs(256, **KERNEL)
+    ct = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+
+    def run(fn):
+        return fn(*args), jax.grad(lambda *a: jnp.sum(fn(*a) * ct),
+                                   argnums=range(6))(*args)
+
+    return (run(lambda *a: sparse_select_attention(*a, topk=48,
+                                                   interpret=True)),
+            run(lambda *a: attention_mod.sparse_select_xla(*a, topk=48)[0]))
+
+
+def test_attention_kernel_matches_the_jnp_path(kernel_and_jnp):
+    (got, _), (want, _) = kernel_and_jnp
+    assert float(jnp.abs(got - want).max()) < 1e-5
+
+
+@pytest.mark.parametrize("arg", range(6),
+                         ids=["q", "k", "v", "qi", "ki", "wi"])
+def test_kernel_gradients_match_the_jnp_path(kernel_and_jnp, arg):
+    """q, k, v: the one-pass backward under the caller's cotangent; qi,
+    ki, wi: L_I's gradient through the two indexer-loss kernels."""
+    (_, got), (_, want) = kernel_and_jnp
+    assert _rel(got[arg], want[arg]) < 1e-5
+
+
+def test_kernels_split_a_batch_over_the_data_axis():
+    """Under a two-device data mesh each shard runs its own sequences
+    and L_I stays the mean over the WHOLE batch."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from bigdl_tpu.ops.pallas.sparse_attention import sparse_select_attention
+    args = _attention_inputs(128, **KERNEL)
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("data",))
+
+    def grads(*a):
+        return jax.grad(lambda *b: jnp.sum(sparse_select_attention(
+            *b, topk=32, interpret=True) ** 2), argnums=(0, 3))(*a)
+
+    want = grads(*args)
+    with jax.set_mesh(mesh):
+        sharded = [jax.device_put(a, NamedSharding(mesh, P("data")))
+                   for a in args]
+        got = jax.jit(grads)(*sharded)
+    for a, b in zip(got, want):
+        assert _rel(a, b) < 1e-5
+
+
+def test_the_schedule_is_stated_where_the_kernels_are_traced():
+    from bigdl_tpu.observability import trace
+    from bigdl_tpu.ops.pallas.sparse_attention import (sparse_schedule,
+                                                       sparse_select_attention)
+    sched = sparse_schedule(16384, 2048)
+    assert (sched.bq, sched.bk, sched.index_bq, sched.index_bk, sched.rows,
+            sched.chunk) == (512, 1024, 256, 512, 128, 2048)
+    # q block i walks i // 2 + 1 key steps of 1024
+    assert sched.tiles_computed == 272
+    # 2048 * 2049 / 2 + 14336 * 2048 kept pairs, 23.4% of the causal ones
+    assert abs(sched.tiles_needed - 31_458_304 / (512 * 1024)) < 1e-9
+    with pytest.raises(ValueError, match="multiple of 128"):
+        sparse_schedule(200, 48)
+    args = _attention_inputs(128, **KERNEL)
+    trace.clear()
+    trace.enable()
+    try:
+        jax.eval_shape(lambda *a: sparse_select_attention(
+            *a, topk=32, interpret=True), *args)
+        events = [e for e in trace.to_dict()["traceEvents"]
+                  if e["name"] == "sparse_schedule"]
+    finally:
+        trace.disable()
+    assert len(events) == 1 and events[0]["cat"] == "kernels"
+    assert events[0]["args"]["tiles_computed"] == 1
+
+
+# --------------------------------------------------------------------------
+# the expert layer
+# --------------------------------------------------------------------------
+
+D, F, TOTAL, TOP = 16, 8, 16, 4
+
+
+def _share(held, offset, params=None, seed=0):
+    layer = ExpertShare(D, F, TOTAL, TOP, experts_held=held,
+                        experts_offset=offset)
+    whole = ExpertShare(D, F, TOTAL, TOP).init(jax.random.PRNGKey(seed))
+    if params is None:
+        params = whole
+    mine = {k: (v if k == "router_weight" else v[offset:offset + held])
+            for k, v in params.items()}
+    return layer, mine
+
+
+def _ref_moe(params, x, offset):
+    lw = {"router_w": params["router_weight"], "gate_w": params["gate_weight"],
+          "up_w": params["up_weight"], "down_w": params["down_weight"]}
+    spec = reference.Spec(kv_heads=1, index_heads=1, topk=1,
+                          experts_total=TOTAL, experts_offset=offset,
+                          experts_per_token=TOP, rope_theta=1.0, eps=1e-6)
+    return reference._moe(lw, x, spec)
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """Eight chips of two experts each: their results, with nothing
+    computed alike on every chip (no shared expert), sum to the layer
+    that holds all sixteen — in the program and in the reference."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 24, D))
+    whole_layer, whole = _share(TOTAL, 0)
+    want = whole_layer.apply(whole, whole_layer.init_state(), x)[0]
+    total = jnp.zeros_like(want)
+    for chip in range(8):
+        layer, mine = _share(2, 2 * chip, whole)
+        y, state = layer.apply(mine, layer.init_state(), x)
+        ref = _ref_moe(mine, x.reshape(-1, D), 2 * chip).reshape(x.shape)
+        assert float(jnp.abs(y - ref).max()) < 1e-5
+        total = total + y
+    assert float(jnp.abs(total - want).max()) < 1e-5
+    assert float(jnp.abs(want - _ref_moe(
+        whole, x.reshape(-1, D), 0).reshape(x.shape)).max()) < 1e-5
+
+
+def test_dropless_under_imbalance():
+    """A router biased so that ONE held expert is every token's first
+    choice: it takes all 64 tokens (a capacity of 1.25 x the mean would
+    hold 5), nothing is dropped and the result is the reference's."""
+    layer, mine = _share(4, 8)
+    mine = dict(mine, router_weight=mine["router_weight"].at[9].set(0.0))
+    x = jax.random.normal(jax.random.PRNGKey(4), (64, D))
+    x = x.at[:, 0].set(3.0)
+    mine["router_weight"] = mine["router_weight"].at[9, 0].set(4.0)
+    y, state = layer.apply(mine, layer.init_state(), x)
+    assert float(state["moe_held_load_max"]) == 64.0
+    assert float(state["moe_tokens_without_local"]) == 0.0
+    assert float(jnp.abs(y - _ref_moe(mine, x, 8)).max()) < 1e-5
+    top, c = layer.route(mine, x)
+    assert bool(jnp.all(top[:, 0] == 9))
+    assert float(jnp.abs(c.sum(-1) - 1.0).max()) < 1e-6
+
+
+def test_a_token_with_no_expert_here_gets_zero_and_telemetry_says_so():
+    layer, mine = _share(2, 14)
+    x = jax.random.normal(jax.random.PRNGKey(5), (40, D))
+    y, state = layer.apply(mine, layer.init_state(), x)
+    top, _ = layer.route(mine, x)
+    away = ~jnp.any(top >= 14, axis=-1)
+    assert 0 < int(away.sum()) < 40
+    assert float(jnp.abs(y[away]).max()) == 0.0
+    assert float(jnp.abs(y[~away]).min(axis=-1).max()) > 0.0
+    assert abs(float(state["moe_tokens_without_local"])
+               - float(away.mean())) < 1e-6
+    assert abs(float(state["moe_local_assignment_share"])
+               - float((top >= 14).mean())) < 1e-6
+    stats = expert_mod.moe_state_stats({"blk": {"1": state}})
+    assert set(stats["blk/1"]) == set(expert_mod.SHARE_STATE_KEYS)
+    assert float(expert_mod.moe_aux_total({"blk": {"1": state}})) == 0.0
+
+
+@pytest.mark.parametrize("rows", [16, 64, 128])
+def test_chunks_of_sorted_rows_are_the_same_layer(monkeypatch, rows):
+    """The 256 sorted assignments a chunk of ``rows`` at a time — the
+    first always, the others behind a ``lax.cond`` and recomputed in the
+    backward pass, here with ~64 landing on the share so that some
+    chunks run and some do not: the same result, gradients (the
+    input's too) and telemetry as all 256 rows in one chunk."""
+    x = jax.random.normal(jax.random.PRNGKey(6), (64, D))
+
+    def run(chunk):
+        monkeypatch.setattr(expert_mod, "_chunk_rows", lambda *a: chunk)
+        layer, mine = _share(4, 4)
+        (loss, state), grads = jax.value_and_grad(
+            lambda p, x: (lambda y, st: (jnp.sum(y ** 2), st))(
+                *layer.apply(p, layer.init_state(), x)), argnums=(0, 1),
+            has_aux=True)(mine, x)
+        return loss, state, grads
+
+    (a, sa, ga), (b, sb, gb) = run(256), run(rows)
+    assert 16 < float(sa["moe_local_assignment_share"]) * 256 < 128
+    assert abs(float(a) - float(b)) < 1e-5 * abs(float(a))
+    assert jax.tree.map(float, sa) == jax.tree.map(float, sb)
+    for name in ga[0]:
+        assert _rel(ga[0][name], gb[0][name]) < 1e-5, name
+    assert _rel(ga[1], gb[1]) < 1e-5
+
+
+def test_no_loop_encloses_the_experts():
+    """A ``while`` around the experts is ONE device operation that spans
+    its body's, and the benchmark's scope readers would count both
+    (PERF.md section 7, PR 31): forward and backward hold none, and one
+    ``cond`` for each chunk after the first."""
+    layer, mine = _share(2, 4)
+    x = jax.random.normal(jax.random.PRNGKey(6), (64, D))
+    text = str(jax.make_jaxpr(jax.grad(lambda p: jnp.sum(layer.apply(
+        p, layer.init_state(), x)[0] ** 2)))(mine))
+    assert "while" not in text and "scan" not in text
+    assert expert_mod._chunk_rows(64 * TOP, 2, TOTAL) == 128
+    assert text.count(" cond[") == 2       # forward, backward: chunk 2
+
+
+def test_a_chunk_is_four_times_the_balanced_share():
+    """16 of 128 experts, 8 a token, 16384 tokens: two chunks of 65536
+    rows (16384 land here when the router is balanced); the whole layer
+    is one chunk; an odd count of assignments divides as far as it
+    can."""
+    assert expert_mod._chunk_rows(16384 * 8, 16, 128) == 65536
+    assert expert_mod._chunk_rows(16384 * 8, 128, 128) == 16384 * 8
+    assert expert_mod._chunk_rows(9 * 8, 2, 16) == 36
+    assert expert_mod._chunk_rows(7, 1, 16) == 7
+    assert expert_mod._chunk_rows(9, 1, 16) == 3
+
+
+def test_expert_share_refuses_experts_it_cannot_hold():
+    with pytest.raises(ValueError, match="not among"):
+        ExpertShare(D, F, 8, 2, experts_held=4, experts_offset=6)
+    with pytest.raises(ValueError, match="top_k"):
+        ExpertShare(D, F, 8, 9)
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["ragged_dot", "megablox-interpreted"])
+def test_grouped_products_backward_is_autodiff_of_a_per_expert_loop(interpret):
+    """Forward and both gradients of the grouped matrix product over
+    ragged groups (one empty, the tail for no expert here) against a
+    loop over experts; the TPU's megablox kernels interpreted too."""
+    sizes = jnp.asarray([40, 0, 88, 128], jnp.int32)   # last: elsewhere
+    x = jax.random.normal(jax.random.PRNGKey(0), (256, 128))
+    w = jax.random.normal(jax.random.PRNGKey(1), (3, 128, 128))
+    ct = jax.random.normal(jax.random.PRNGKey(2), (256, 128))
+
+    def loop(x, w):
+        out, at = [], 0
+        for e, n in enumerate([40, 0, 88]):
+            out.append(x[at:at + n] @ w[e].T)
+            at += n
+        return jnp.concatenate(out + [jnp.zeros((128, 128))])
+
+    def grouped(x, w):
+        return expert_mod.grouped_matmul(x, w, sizes, interpret=interpret)
+
+    assert float(jnp.abs(grouped(x, w) - loop(x, w)).max()) < 1e-4
+    got = jax.grad(lambda *a: jnp.sum(grouped(*a) * ct), argnums=(0, 1))(x, w)
+    want = jax.grad(lambda *a: jnp.sum(loop(*a) * ct), argnums=(0, 1))(x, w)
+    for a, b in zip(got, want):
+        assert _rel(a, b) < 1e-5
+
+
+def test_expert_share_on_megablox_interpreted_matches_ragged_dot(monkeypatch):
+    layer, mine = _share(4, 4)
+    x = jax.random.normal(jax.random.PRNGKey(6), (64, D))
+
+    def run():
+        return jax.value_and_grad(lambda p: jnp.sum(layer.apply(
+            p, layer.init_state(), x)[0] ** 2))(mine)
+
+    (a, ga) = run()
+    monkeypatch.setattr(expert_mod, "grouped_matmul", functools.partial(
+        expert_mod.grouped_matmul, interpret=True))
+    (b, gb) = run()
+    assert abs(float(a) - float(b)) < 1e-4 * abs(float(a))
+    for name in mine:
+        assert _rel(ga[name], gb[name]) < 1e-4, name
+
+
+def test_old_and_new_layers_share_one_router():
+    x = jax.random.normal(jax.random.PRNGKey(7), (10, D))
+    gate = jax.random.normal(jax.random.PRNGKey(8), (D, TOTAL))
+    probs, top_p, top = expert_mod.route_top_k(x, gate, TOP)
+    assert float(jnp.abs(probs.sum(-1) - 1).max()) < 1e-6
+    assert bool(jnp.all(jnp.take_along_axis(probs, top, -1) == top_p))
+    layer, mine = _share(TOTAL, 0)
+    got, c = layer.route(dict(mine, router_weight=gate.T), x)
+    assert bool(jnp.array_equal(got, top))
+    assert float(jnp.abs(c - top_p / top_p.sum(-1, keepdims=True)).max()) \
+        < 1e-6
+
+
+# --------------------------------------------------------------------------
+# the model as built
+# --------------------------------------------------------------------------
+
+def test_keye_lm_counts_the_cells_parameters():
+    """A layer's share at the published widths is 96,899,456 parameters,
+    embedding and head 77,793,280 (ISSUE 31's arithmetic)."""
+    from bigdl_tpu.models import KeyeLM
+    model = KeyeLM(vocab_size=18992, num_layers=1, experts_held=16,
+                   experts_offset=48)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    def count(tree):
+        return sum(int(np.prod(a.shape)) for a in jax.tree.leaves(tree))
+
+    assert count(shapes["1"]) == 96_899_456
+    assert count(shapes["2"]) == 2048                    # final norm
+    assert sum(count(shapes[i]) for i in "023") == 77_793_280
+    assert model.remat_policy == "per_block"
+
+
+def test_no_sequence_squared_array_outlives_a_block(system):
+    """What the backward keeps of the forward is the block boundaries:
+    with each block recomputed, not one array of S x S elements is among
+    the residuals; without, each layer's masked scores and probabilities
+    are."""
+    model, params, state = system
+    x, t = _batch(seq=128, rows=1)
+    crit = builder.criterion()
+
+    def largest_residual():
+        kept = jax.eval_shape(lambda p: jax.vjp(lambda q: crit.apply(
+            model.apply(q, state, x, training=True)[0], t), p)[1], params)
+        return max(leaf.size for leaf in jax.tree.leaves(kept))
+
+    assert largest_residual() < 128 * 128      # the logits: 128 x 50
+    policy, model.remat_policy = model.remat_policy, None
+    try:
+        assert largest_residual() >= 128 * 128
+    finally:
+        model.remat_policy = policy
+
+
+def test_the_layer_states_its_shapes_where_it_is_traced(system):
+    from bigdl_tpu.observability import trace
+    model, params, state = system
+    x, _ = _batch()
+    trace.clear()
+    trace.enable()
+    try:
+        jax.eval_shape(lambda p: model.apply_plain(p, state, x)[0], params)
+        events = trace.to_dict()["traceEvents"]
+    finally:
+        trace.disable()
+    sel = [e["args"] for e in events if e["name"] == "sparse_select"]
+    moe = [e["args"] for e in events if e["name"] == "moe_share"]
+    assert len(sel) == len(moe) == CFG["num_hidden_layers"]
+    assert sel[0]["selected_pairs"] == 2 * (12 * 13 // 2 + 36 * 12)
+    assert sel[0]["causal_pairs"] == 2 * 48 * 49 // 2
+    assert sel[0]["materialised_bytes"] == 2 * 48 * 48 * 4
+    assert moe[0] == dict(experts_total=8, experts_held=4, top_k=2,
+                          tokens=96, expected_local_assignments=96.0)
+
+
+def test_the_optimizer_trains_the_model_as_built(system):
+    """Through ``Optimizer``: the loss falls, and the indexer's leaves
+    move although no loss the optimizer sees depends on them."""
+    from bigdl_tpu.dataset.dataset import DataSet
+    from bigdl_tpu.dataset.sample import MiniBatch
+    from bigdl_tpu.optim import SGD, Optimizer
+    from bigdl_tpu.optim.trigger import max_iteration
+    model = builder.build(CFG)
+    model.materialize(jax.random.PRNGKey(0))
+    before = jax.tree.map(np.asarray, model.params["1"]["0"]["1"])
+    x, t = _batch(rows=4)
+    data = DataSet.iterator(lambda: iter([MiniBatch(np.asarray(x),
+                                                    np.asarray(t))] * 8),
+                            size=32)
+    losses = []
+
+    class Log:
+        def add_scalar(self, name, value, step):
+            if name == "Loss":
+                losses.append(float(value))
+
+    opt = Optimizer(model, data, builder.criterion())
+    opt.set_optim_method(SGD(learning_rate=0.5))
+    opt.set_train_summary(Log())
+    opt.set_end_when(max_iteration(8))
+    opt.optimize()
+    assert losses[-1] < losses[0]
+    after = model.params["1"]["0"]["1"]
+    for leaf in ("iq_weight", "ik_weight", "iw_weight", "ik_norm_weight",
+                 "ik_norm_bias"):
+        assert float(np.abs(np.asarray(after[leaf]) - before[leaf]).max()) > 0

@@ -393,3 +393,77 @@ class TestPredicatesMatchTheLowering:
             _lowers(_CE_GRADS, h, w, _sds((v,)), _sds((n,), jnp.int32))
         assert not linear_ce_supported(_sds((100, 128)), _sds((512, 128)))
         assert not linear_ce_supported(_sds((256, 96)), _sds((512, 96)))
+
+
+class TestSparseAttentionCompilesForTheV5e:
+    """Mosaic's verdict on the learned-sparse-attention kernels at the
+    benchmark cell's shape (keye-vl-2.0-30b-a3b.train.seq16384: 32 query
+    and 4 key/value heads of 16384 x 128, 16 indexer heads of 64,
+    top-2048), forward and backward in one program: VMEM for 128 whole
+    rows of index scores and their integer keys, for a key/value head's
+    dk/dv, the bisection's loops, the float32 index matmuls."""
+
+    S, TOPK = 16384, 2048
+
+    @pytest.fixture(scope="class")
+    def compiled(self, one_chip):
+        from bigdl_tpu.ops.pallas.sparse_attention import (
+            sparse_select_attention)
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        s = self.S
+        fn = _scalar_grads(lambda *a: sparse_select_attention(
+            *a, topk=self.TOPK), 6)
+        return jax.jit(fn).lower(
+            sds((1, s, 32, 128), BF16), sds((1, s, 4, 128), BF16),
+            sds((1, s, 4, 128), BF16), sds((1, s, 16, 64), jnp.float32),
+            sds((1, s, 64), jnp.float32),
+            sds((1, s, 16), jnp.float32)).compile()
+
+    def test_six_kernels_and_their_names(self, compiled):
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 6
+        for name in ("sparse_index_scores", "sparse_select_rows",
+                     "sparse_attention_fwd", "sparse_attention_dqdkdv",
+                     "sparse_kept_probs", "sparse_index_backward"):
+            assert name in text, name
+
+    def test_no_key_or_value_row_is_gathered_per_query(self, compiled):
+        """K and V reach the kernels whole and unrepeated: no gather, and
+        no array of (heads, S, top-k) elements or more."""
+        text = compiled.as_text()
+        assert not re.search(r"= \S+ gather\(", text)
+        assert f"[32,{self.S},{self.TOPK}" not in text
+
+    def test_one_sequence_squared_array_at_a_time(self, compiled):
+        """Scores, masked scores and d L_I / d I are ONE float32 (S, S)
+        buffer written in place: the program's temporaries hold it once
+        (1.07 GB), not three times."""
+        square = self.S * self.S * 4
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert square <= temp < 2 * square, temp / 1e9
+
+
+class TestGroupedMatmulCompilesForTheV5e:
+    """Mosaic's verdict on megablox's grouped matrix products as
+    ``parallel.expert.grouped_matmul`` tiles them, at one chunk of the
+    Keye cell's sorted rows: 32768 rows of 2048 against 16 experts
+    of 768, both products' shapes, forward and backward."""
+
+    @pytest.mark.parametrize("n,k", [(768, 2048), (2048, 768)],
+                             ids=["gate-up", "down"])
+    def test_fwd_bwd(self, one_chip, monkeypatch, n, k):
+        from bigdl_tpu.parallel import expert
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        fn = jax.value_and_grad(
+            lambda x, w, sizes: expert.grouped_matmul(x, w, sizes)
+            .astype(jnp.float32).sum(), argnums=(0, 1))
+        text = jax.jit(fn).lower(
+            jax.ShapeDtypeStruct((32768, k), BF16, sharding=one_chip),
+            jax.ShapeDtypeStruct((16, n, k), BF16, sharding=one_chip),
+            jax.ShapeDtypeStruct((17,), jnp.int32, sharding=one_chip)
+        ).compile().as_text()
+        # gmm forward, gmm for d rows, tgmm for d weights
+        assert text.count('custom_call_target="tpu_custom_call"') == 3
