@@ -162,8 +162,10 @@ class Remat(Container):
     because its backward consumes them; under autodiff those cached
     activations become XLA-saved residuals and, for bandwidth-bound models,
     HBM traffic. Wrapping a block in ``Remat`` saves only the block
-    boundary and recomputes the interior during backward — trading MXU
-    FLOPs (usually idle in memory-bound steps) for HBM bytes.
+    boundary (and, with no ``policy`` given, what optim/remat.py's
+    ``"per_block"`` keeps inside one: the values a module names as made
+    by an attention kernel) and recomputes the interior during backward —
+    trading MXU FLOPs (usually idle in memory-bound steps) for HBM bytes.
 
     Transparent to the param/state pytree: the child's tree IS this
     module's tree, so wrapping changes no checkpoint layout, golden
@@ -186,8 +188,12 @@ class Remat(Container):
         def inner(p, s, xx, r):
             return child.apply(p, s, xx, training=training, rng=r)
 
-        return jax.checkpoint(inner, policy=self.policy)(params, state, x,
-                                                         rng)
+        policy = self.policy
+        if policy is None:
+            # the child is a block: keep what "per_block" keeps
+            from bigdl_tpu.optim.remat import _checkpoint_policy
+            policy = _checkpoint_policy("per_block")
+        return jax.checkpoint(inner, policy=policy)(params, state, x, rng)
 
     def sync(self, params, state=None):
         Module.sync(self, params, state)

@@ -40,6 +40,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from bigdl_tpu.ops.pallas.flash_attention import (
@@ -262,7 +263,11 @@ def _eva_bhsd(q, k, v, ks, vs, window, chunk, scale, interpret):
 
 
 def _eva_fwd(q, k, v, ks, vs, window, chunk, scale, interpret):
+    """What the kernel made is NAMED: a recomputed block keeps it
+    (``optim/remat.py``, ``"per_block"``) and runs the kernel once."""
     o, lse = _fwd(q, k, v, ks, vs, window, chunk, scale, interpret)
+    o = checkpoint_name(o, "attention_out")
+    lse = checkpoint_name(lse, "attention_stats")
     return o, (q, k, v, ks, vs, o, lse)
 
 

@@ -26,8 +26,11 @@ future costs no FLOPs, no DMA and no write.
   cut by a second bisection over the bits of the column index (15 more
   passes at 16384, run ALWAYS: the kernel's time does not follow the
   data); writes I back IN PLACE with -inf on every pair not kept, and
-  each row's logsumexp over the kept. That one (S, S) float32 array is
-  the selection for everything after it: kept <=> finite.
+  each row's logsumexp over the kept, threshold key and tie column.
+  That one (S, S) float32 array is the selection for everything after
+  it: kept <=> finite. The two numbers a row ARE the selection: the
+  backward pass writes the array again from I and them, as the scores
+  kernel's epilogue (``index_scores(keep=...)``), and finds nothing.
 - ``attend``: flash attention's online softmax over (bq, bk) tiles with
   the tile of kept pairs as its mask; one grid step takes ALL the query
   heads of a key/value group, so K/V and the mask are read once a group
@@ -41,9 +44,16 @@ future costs no FLOPs, no DMA and no write.
 - ``index_backward``: d L_I / d (qI, kI, w) from d L_I / d I, the J
   heads' products recomputed a tile at a time (1 + 2 matmuls).
 
-No (S, S) array leaves the call: the masked scores live from the
-selection to the end of the backward pass of ONE layer (each block is
-recomputed, ``Sequential.set_remat``).
+No (S, S) array leaves the call, as an output or as a residual: the
+masked scores live from the selection to the end of the forward's
+attention, and in the backward pass of ONE layer from the scores written
+again to the indexer's gradients. What the backward needs that is costly
+to make again and small is NAMED (``jax.ad_checkpoint.checkpoint_name``:
+``attention_out`` o, ``attention_stats`` its row logsumexp,
+``attention_selection`` a row's threshold, tie column and index
+logsumexp), so that a recomputed block (``optim/remat.py``,
+``"per_block"``) keeps it and runs neither the selection nor the
+attention's forward a second time.
 
 The attention is DENSE-MASKED: every causal tile is computed and the
 unkept pairs are masked, because a query's kept keys are scattered
@@ -61,6 +71,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from bigdl_tpu.ops.pallas.flash_attention import (
@@ -148,14 +159,37 @@ def _params(*semantics):
 
 
 # --------------------------------------------------------------------------
+# the selection's rule, for the kernel that finds it and the one that
+# applies it again
+# --------------------------------------------------------------------------
+
+def _order_keys(x):
+    """Order-preserving int32 keys of float32 scores; -0.0 == +0.0."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    key = bits ^ ((bits >> 31) & 0x7FFFFFFF)
+    return jnp.where(x == 0.0, 0, key)
+
+
+def _among_kept(key, col, row, t, m):
+    """Pair (row, col) is kept: its key above the row's threshold ``t``,
+    or equal to it left of the row's tie column ``m``; never a future
+    pair."""
+    return ((key > t) | ((key == t) & (col < m))) & (col <= row)
+
+
+# --------------------------------------------------------------------------
 # the indexer's scores
 # --------------------------------------------------------------------------
 
-def _scores_kernel(qi_ref, kh_ref, kl_ref, wi_ref, out_ref, *, heads, bq,
-                   bk):
+def _scores_kernel(qi_ref, kh_ref, kl_ref, wi_ref, *rest, heads, bq, bk,
+                   reach):
     """One tile of I. ``qi_ref`` holds each query [high | low], the two
     key blocks [high | high] and [low | low]: two bf16 matmuls of twice
-    the depth give all four partial products of the float32 product."""
+    the depth give all four partial products of the float32 product.
+    With ``reach`` (the backward's call) two more inputs, each row's
+    threshold key and tie column, come before the output, and the tile
+    leaves as ``select_rows`` left it: -inf on every pair not kept."""
+    *keep_refs, out_ref = rest
     i, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(_visible(i, j, bq, bk))
@@ -166,24 +200,51 @@ def _scores_kernel(qi_ref, kh_ref, kl_ref, wi_ref, out_ref, *, heads, bq,
             q = qi_ref[0, h]
             r = _dot(q, kh, _NT) + _dot(q, kl, _NT)
             acc = acc + w[:, h:h + 1] * jnp.maximum(r, 0.0)
+        if reach:
+            row = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+            col = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            t_ref, m_ref = keep_refs
+            keep = _among_kept(_order_keys(acc), col, row, t_ref[0],
+                               m_ref[0])
+            acc = jnp.where(keep, acc, -jnp.inf)
         out_ref[0] = acc
+
+    if reach:
+        # a kernel that walks key steps ``reach`` wide reads past the
+        # diagonal's tile, where ``select_rows`` wrote -inf
+        @pl.when(jnp.logical_not(_visible(i, j, bq, bk))
+                 & (j <= _read_last(i, bq, bk, reach)))
+        def _future():
+            out_ref[0] = jnp.full((bq, bk), -jnp.inf, jnp.float32)
+
+
+def _read_last(i, bq, bk, reach):
+    """The last (bq, bk) tile of q block ``i`` that lies in a visible key
+    step of ``reach`` columns (a multiple of bk); with no ``reach``, the
+    last visible tile."""
+    if not reach:
+        return _last(i, bq, bk)
+    return (_last(i, bq, reach) + 1) * (reach // bk) - 1
 
 
 @functools.lru_cache(maxsize=16)
-def _scores_call(b, s, heads, di, bq, bk, interpret):
+def _scores_call(b, s, heads, di, bq, bk, reach, interpret):
     nq, nk = s // bq, s // bk
     key_spec = pl.BlockSpec((1, bk, 2 * di), lambda n, i, j: (
         n, jnp.minimum(j, _last(i, bq, bk)), 0))
+    row_spec = pl.BlockSpec((1, bq, 1), lambda n, i, j: (n, i, 0))
     return pl.pallas_call(
-        functools.partial(_scores_kernel, heads=heads, bq=bq, bk=bk),
+        functools.partial(_scores_kernel, heads=heads, bq=bq, bk=bk,
+                          reach=reach),
         grid=(b, nq, nk),
         in_specs=[
             pl.BlockSpec((1, heads, bq, 2 * di),
                          lambda n, i, j: (n, 0, i, 0)),
             key_spec, key_spec,
-            pl.BlockSpec((1, bq, heads), lambda n, i, j: (n, i, 0))],
+            pl.BlockSpec((1, bq, heads), lambda n, i, j: (n, i, 0)),
+            *([row_spec, row_spec] if reach else [])],
         out_specs=pl.BlockSpec((1, bq, bk), lambda n, i, j: (
-            n, i, jnp.minimum(j, _last(i, bq, bk)))),
+            n, i, jnp.minimum(j, _read_last(i, bq, bk, reach)))),
         out_shape=jax.ShapeDtypeStruct((b, s, s), jnp.float32),
         compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
@@ -191,7 +252,7 @@ def _scores_call(b, s, heads, di, bq, bk, interpret):
     )
 
 
-def index_scores(qi, ki, wi, *, interpret: bool = False):
+def index_scores(qi, ki, wi, *, keep=None, interpret: bool = False):
     """I (B, S, S) float32 from ``qi`` (B, J, S, DI), ``ki`` (B, S, DI),
     ``wi`` (B, S, J), all float32. The products are 2 x bfloat16, about
     16 mantissa bits: each operand goes to the MXU as two bfloat16
@@ -200,24 +261,30 @@ def index_scores(qi, ki, wi, *, interpret: bool = False):
     the halves side by side fill the MXU's depth at DI = 64, so this
     costs two passes). Only the causal tiles are written: what lies in a
     query block's future is left as it was allocated (``select_rows``
-    never reads a pair with s > t)."""
+    never reads a pair with s > t).
+
+    ``keep`` = (threshold keys, tie columns), two (B, S, 1) int32 of
+    ``select_rows``: the scores leave MASKED, bit for bit the array
+    ``select_rows`` wrote over them (the same products, the same
+    comparison), on every tile that ``attend``'s key steps reach; the
+    backward pass's way to the selection without finding it again."""
     b, heads, s, di = qi.shape
     sched = sparse_schedule(s)
     q_high, q_low = _halves(qi)
     k_high, k_low = _halves(ki)
     return _scores_call(b, s, heads, di, sched.index_bk, sched.index_bk,
-                        interpret)(
+                        sched.bk if keep else 0, interpret)(
         jnp.concatenate([q_high, q_low], axis=-1),
         jnp.concatenate([k_high, k_high], axis=-1),
-        jnp.concatenate([k_low, k_low], axis=-1), wi)
+        jnp.concatenate([k_low, k_low], axis=-1), wi, *(keep or ()))
 
 
 # --------------------------------------------------------------------------
 # the selection
 # --------------------------------------------------------------------------
 
-def _select_kernel(x_ref, out_ref, lse_ref, key_scr, t_scr, m_scr, *,
-                   topk, rows, chunk, s):
+def _select_kernel(x_ref, out_ref, lse_ref, t_ref, m_ref, key_scr, t_scr,
+                   m_scr, *, topk, rows, chunk, s):
     """One block of ``rows`` whole rows: keys, threshold, ties, write."""
     row0 = pl.program_id(1) * rows
     row = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
@@ -245,11 +312,8 @@ def _select_kernel(x_ref, out_ref, lse_ref, key_scr, t_scr, m_scr, *,
         # order-preserving int32 keys of the float32 scores; a pair in
         # the future sorts below everything
         at, col = cols(c)
-        x = x_ref[0, :, at]
-        bits = jax.lax.bitcast_convert_type(x, jnp.int32)
-        key = bits ^ ((bits >> 31) & 0x7FFFFFFF)
-        key = jnp.where(x == 0.0, 0, key)          # -0.0 == +0.0
-        key_scr[:, at] = jnp.where(col <= row, key, _INT_MIN)
+        key_scr[:, at] = jnp.where(col <= row, _order_keys(x_ref[0, :, at]),
+                                   _INT_MIN)
         return carry
 
     each_chunk(to_keys, 0)
@@ -292,11 +356,11 @@ def _select_kernel(x_ref, out_ref, lse_ref, key_scr, t_scr, m_scr, *,
                                     jnp.zeros((rows, 1), i32)), s)
 
     t, m = t_scr[:], m_scr[:]
+    t_ref[0], m_ref[0] = t, m
 
     def kept(c):
         at, col = cols(c)
-        key = key_scr[:, at]
-        keep = ((key > t) | ((key == t) & (col < m))) & (col <= row)
+        keep = _among_kept(key_scr[:, at], col, row, t, m)
         return at, jnp.where(keep, x_ref[0, :, at], -jnp.inf)
 
     def write(c, top):
@@ -325,15 +389,17 @@ def _select_kernel(x_ref, out_ref, lse_ref, key_scr, t_scr, m_scr, *,
 def _select_call(b, s, topk, rows, chunk, interpret):
     from jax.experimental.pallas import tpu as pltpu
     row_spec = pl.BlockSpec((1, rows, s), lambda n, i: (n, i, 0))
+    one_spec = pl.BlockSpec((1, rows, 1), lambda n, i: (n, i, 0))
     return pl.pallas_call(
         functools.partial(_select_kernel, topk=topk, rows=rows,
                           chunk=chunk, s=s),
         grid=(b, s // rows),
         in_specs=[row_spec],
-        out_specs=[row_spec,
-                   pl.BlockSpec((1, rows, 1), lambda n, i: (n, i, 0))],
+        out_specs=[row_spec, one_spec, one_spec, one_spec],
         out_shape=[jax.ShapeDtypeStruct((b, s, s), jnp.float32),
-                   jax.ShapeDtypeStruct((b, s, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, s, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((b, s, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((b, s, 1), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((rows, s), jnp.int32),
                         pltpu.VMEM((rows, 1), jnp.int32),
                         pltpu.VMEM((rows, 1), jnp.int32)],
@@ -345,11 +411,14 @@ def _select_call(b, s, topk, rows, chunk, interpret):
 
 
 def select_rows(scores, topk: int, *, interpret: bool = False):
-    """(kept, lse): ``scores`` (B, S, S) float32 with -inf on every pair
-    (t, s) that is NOT among query t's ``topk`` largest over s <= t
-    (every s <= t while t < topk; the lower s wins among equal scores),
-    written over the input, and the logsumexp (B, S, 1) of each row's
-    kept scores. Exact."""
+    """(kept, lse, threshold, tie): ``scores`` (B, S, S) float32 with
+    -inf on every pair (t, s) that is NOT among query t's ``topk``
+    largest over s <= t (every s <= t while t < topk; the lower s wins
+    among equal scores), written over the input; the logsumexp (B, S, 1)
+    of each row's kept scores; and the two int32 (B, S, 1) that ARE the
+    row's selection (``index_scores(keep=...)`` applies them again): the
+    order-preserving key of its topk-th largest score and the column
+    left of which a score equal to it is kept (S: all of them). Exact."""
     b, s, _ = scores.shape
     sched = sparse_schedule(s, topk)
     return _select_call(b, s, topk, sched.rows, sched.chunk,
@@ -668,13 +737,20 @@ def _index_bwd_call(b, s, heads, di, bq, bk, dtype, interpret):
 # --------------------------------------------------------------------------
 
 def _forward(q, k, v, qi, ki, wi, topk, scale, weight, interpret):
+    """The three forward kernels; the residuals hold what they made that
+    is small, by the names ``optim/remat.py``'s ``"per_block"`` keeps,
+    and not the (S, S) masked scores."""
     with jax.named_scope("indexer"):
         scores = index_scores(qi, ki, wi, interpret=interpret)
     with jax.named_scope("select_topk"):
-        kept, lse_i = select_rows(scores, topk, interpret=interpret)
+        kept, *rows = select_rows(scores, topk, interpret=interpret)
+        lse_i, t, m = (checkpoint_name(a, "attention_selection")
+                       for a in rows)
     with jax.named_scope("sparse_attention"):
         o, lse = attend(q, k, v, kept, scale=scale, interpret=interpret)
-    return o, (q, k, v, qi, ki, wi, kept, lse_i, o, lse)
+        o = checkpoint_name(o, "attention_out")
+        lse = checkpoint_name(lse, "attention_stats")
+    return o, (q, k, v, qi, ki, wi, lse_i, t, m, o, lse)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
@@ -686,11 +762,14 @@ def _core(q, k, v, qi, ki, wi, topk, scale, weight, interpret):
 
 
 def _core_bwd(topk, scale, weight, interpret, res, g):
-    q, k, v, qi, ki, wi, kept, lse_i, o, lse = res
+    q, k, v, qi, ki, wi, lse_i, t, m, o, lse = res
     bg, per, s, d = q.shape
     b, heads, _, di = qi.shape
     groups = bg // b
     sched = sparse_schedule(s, topk)
+    with jax.named_scope("indexer"):
+        # the forward's masked scores again, from a row's two numbers
+        kept = index_scores(qi, ki, wi, keep=(t, m), interpret=interpret)
     with jax.named_scope("sparse_attention"):
         delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), -1)
         dq, dk, dv = _attend_bwd_call(

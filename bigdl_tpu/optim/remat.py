@@ -15,9 +15,14 @@ cache-key component) rather than a per-model wrapper decision:
                        chains (cheap recompute, moderate savings)
 - ``"per_block"``    — checkpoint each top-level block of a
                        ``Sequential`` stack (transformer / inception):
-                       only block-boundary activations are saved, one
+                       block-boundary activations are saved, and inside
+                       a block only what a module NAMES as made by an
+                       attention kernel and small next to what it costs
+                       to make again (``KEPT_NAMES``); the rest of one
                        block's interior is recomputed at a time — the
-                       selective policy deep stacks want
+                       selective policy deep stacks want. A block whose
+                       modules name nothing keeps nothing: what is in
+                       the traced block decides, not a setting
 - ``"nothing_saveable"`` — save only the checkpointed region's inputs;
                        maximum savings, one full forward of recompute
 
@@ -40,13 +45,20 @@ import logging
 
 logger = logging.getLogger("bigdl_tpu.optim")
 
-__all__ = ["REMAT_POLICIES", "known_remat_policies", "check_remat_policy",
-           "remat_forward", "saved_residual_bytes", "train_memory_probe"]
+__all__ = ["REMAT_POLICIES", "KEPT_NAMES", "known_remat_policies",
+           "check_remat_policy", "remat_forward", "saved_residual_bytes",
+           "train_memory_probe"]
 
-#: policy name -> jax.checkpoint policy factory (None = the whole-forward
-#: default policy, "save nothing"); "none"/"per_block" are handled
-#: structurally in remat_forward.
+#: the policy names; ``_checkpoint_policy`` gives each but "none" its
+#: ``jax.checkpoint`` policy, and "per_block" is also a structure (a
+#: region a block) in remat_forward.
 REMAT_POLICIES = ("none", "dots_saveable", "per_block", "nothing_saveable")
+
+#: what ``"per_block"`` keeps inside a block, by the name a module gives
+#: the value (``jax.ad_checkpoint.checkpoint_name``): an attention
+#: kernel's output, its row statistics, and an exact selection's
+#: per-row results (ops/pallas/sparse_attention.py, eva_attention.py)
+KEPT_NAMES = ("attention_out", "attention_stats", "attention_selection")
 
 
 def known_remat_policies() -> tuple:
@@ -63,12 +75,43 @@ def check_remat_policy(name):
 
 
 def _checkpoint_policy(name):
+    """The ``jax.checkpoint`` policy of every name but ``"none"``."""
     import jax
     if name == "dots_saveable":
         return jax.checkpoint_policies.dots_saveable
     if name == "nothing_saveable":
         return jax.checkpoint_policies.nothing_saveable
+    if name == "per_block":
+        return jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
     raise AssertionError(name)
+
+
+def _state_kept(model, blocks):
+    """One ``bigdl:optim:remat_kept`` instant: ``KEPT_NAMES`` and, for
+    each ``(block, args)`` of ``blocks``, how many values and bytes the
+    ``"per_block"`` policy holds for the block's backward over what a
+    checkpoint that keeps nothing holds (the block's inputs): the named
+    values it saved. Abstract ``jax.vjp`` of each block, as
+    :func:`saved_residual_bytes`; python runs this where the forward is
+    traced with tracing on, never in a step."""
+    import jax
+
+    from bigdl_tpu.observability import trace
+
+    def held(block, args, name):
+        fn = jax.checkpoint(block, policy=_checkpoint_policy(name))
+        leaves = jax.tree.leaves(jax.eval_shape(
+            lambda *a: jax.vjp(fn, *a)[1], *args))
+        return len(leaves), _tree_bytes(leaves)
+
+    per_block = [[a - b for a, b in zip(held(block, args, "per_block"),
+                                        held(block, args,
+                                             "nothing_saveable"))]
+                 for block, args in blocks]
+    trace.instant("remat_kept", cat="optim", model=type(model).__name__,
+                  names=",".join(KEPT_NAMES), blocks=len(per_block),
+                  values=sum(n for n, _ in per_block),
+                  bytes=sum(b for _, b in per_block), per_block=per_block)
 
 
 def remat_forward(model, policy):
@@ -81,7 +124,9 @@ def remat_forward(model, policy):
     with the child-index rng fold and name scope mirrored from
     ``Sequential.apply`` so dropout draws land identically and a trace
     names the same modules; non-Sequential models degrade to a
-    whole-forward checkpoint (logged).
+    whole-forward checkpoint (logged). Either way the checkpoint keeps
+    the values named in ``KEPT_NAMES`` and says so where it is traced
+    (:func:`_state_kept`).
 
     A model may carry a policy of its own (``Sequential.set_remat``: the
     recipe it was built with). Its ``apply`` is then this function's
@@ -94,51 +139,49 @@ def remat_forward(model, policy):
 
     from bigdl_tpu.nn.containers import Sequential
     from bigdl_tpu.nn.module import _fold
+    from bigdl_tpu.observability import trace
 
     policy = check_remat_policy(policy)
     if policy == "none":
         return model.apply
     plain = getattr(model, "apply_plain", model.apply)
+    chk_policy = _checkpoint_policy(policy)
 
-    if policy == "per_block":
-        if not isinstance(model, Sequential):
-            logger.info(
-                "remat_policy='per_block' on a %s (not a Sequential "
-                "stack) — checkpointing the whole forward instead",
-                type(model).__name__)
-
-            def whole(params, state, x, *, training=False, rng=None):
-                def inner(p, s, xx, r):
-                    return plain(p, s, xx, training=training, rng=r)
-                return jax.checkpoint(inner)(params, state, x, rng)
-
-            return whole
-
+    if policy == "per_block" and isinstance(model, Sequential):
         def per_block(params, state, x, *, training=False, rng=None):
             # mirrors Sequential.apply exactly (same rng folds, same
             # state tree) with each block its own checkpoint region:
-            # only the residual stream at block boundaries is saved
-            new_state = {}
+            # the residual stream at block boundaries is saved, and what
+            # a block's modules name (KEPT_NAMES)
+            new_state, blocks = {}, []
             for i, m in enumerate(model.modules):
                 def block(p, s, xx, r, _m=m):
                     return _m.apply(p, s, xx, training=training, rng=r)
 
+                args = (params[str(i)], state[str(i)], x, _fold(rng, i))
+                blocks.append((block, args))
                 with jax.named_scope(m._name or f"{i}_{type(m).__name__}"):
-                    x, s = jax.checkpoint(block)(
-                        params[str(i)], state[str(i)], x, _fold(rng, i))
+                    x, s = jax.checkpoint(block, policy=chk_policy)(*args)
                 new_state[str(i)] = s
+            if trace.enabled():
+                _state_kept(model, blocks)
             return x, new_state
 
         return per_block
 
-    chk_policy = _checkpoint_policy(policy)
+    if policy == "per_block":
+        logger.info(
+            "remat_policy='per_block' on a %s (not a Sequential stack) — "
+            "checkpointing the whole forward instead", type(model).__name__)
 
     def whole_forward(params, state, x, *, training=False, rng=None):
         def inner(p, s, xx, r):
             return plain(p, s, xx, training=training, rng=r)
 
-        return jax.checkpoint(inner, policy=chk_policy)(params, state, x,
-                                                        rng)
+        args = (params, state, x, rng)
+        if policy == "per_block" and trace.enabled():
+            _state_kept(model, [(inner, args)])
+        return jax.checkpoint(inner, policy=chk_policy)(*args)
 
     return whole_forward
 
@@ -150,18 +193,13 @@ def saved_residual_bytes(loss_fn, *args) -> int:
     shape evaluation: nothing compiles, nothing executes — this is the
     activation-memory term a remat policy controls, measured the same
     on every backend."""
-    import numpy as np
-
     import jax
 
     def capture(*a):
         _, vjp = jax.vjp(loss_fn, *a)
         return vjp
 
-    shapes = jax.eval_shape(capture, *args)
-    return int(sum(int(np.prod(l.shape)) * np.dtype(l.dtype).itemsize
-                   for l in jax.tree.leaves(shapes)
-                   if hasattr(l, "shape")))
+    return _tree_bytes(jax.eval_shape(capture, *args))
 
 
 def _tree_bytes(tree) -> int:

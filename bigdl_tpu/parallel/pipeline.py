@@ -865,10 +865,12 @@ class PipelineParallel:
         per-child rng folds as ``Sequential.apply`` — dropout draws land
         exactly where the non-pipelined step's do."""
         from bigdl_tpu.nn.module import _fold
+        from bigdl_tpu.optim.remat import (_checkpoint_policy,
+                                           check_remat_policy)
 
         template, lc = self.template, self.lc
         state0 = self.model.state["0"]
-        policy = self.remat_policy
+        policy = check_remat_policy(self.remat_policy)
 
         def layer(h, xs):
             lp, gl = xs
@@ -876,10 +878,9 @@ class PipelineParallel:
                                   rng=_fold(rng_mb, gl))
             return y, None
 
-        if policy == "per_block":
-            layer = jax.checkpoint(layer)
-        elif policy in ("dots_saveable", "nothing_saveable"):
-            from bigdl_tpu.optim.remat import _checkpoint_policy
+        if policy != "none":
+            # a layer is a block: "per_block" keeps what optim/remat.py's
+            # keeps (KEPT_NAMES), spelled there alone
             layer = jax.checkpoint(layer, policy=_checkpoint_policy(policy))
 
         def chunk(p_chunk, x, g_global):

@@ -4,7 +4,8 @@
 per-layer shape (one sequence of 16384 tokens, 32 query / 4 key-value
 heads of 128, 16 indexer heads of 64, top-2048; 16 experts of 2048 x 768
 on 32768 sorted rows, half a chunk of ``ExpertShare``'s): the six kernels of
-``ops/pallas/sparse_attention.py`` one by one, the exact selection beside
+``ops/pallas/sparse_attention.py`` one by one (the scores kernel also as
+the backward pass runs it, masking again), the exact selection beside
 ``lax.top_k`` of the same rows (a sort; ``approx_max_k`` is not the
 model), a gather and a scatter-add of the experts' rows, and the
 grouped matrix products of ``parallel/expert.py`` through megablox and
@@ -29,6 +30,17 @@ def _ms(fn, *args, iters=5):
         out = fn(*args)
     jax.block_until_ready(out)
     return 1e3 * (time.perf_counter() - t0) / iters
+
+
+def _same_where_read(again, kept, sched):
+    """The masked scores written again equal the selection's array on
+    every column a key step of ``sched.bk`` reaches from a row."""
+    import jax.numpy as jnp
+    s = kept.shape[-1]
+    reach = (jnp.arange(s)[:, None] // sched.bq * sched.bq + sched.bq - 1) \
+        // sched.bk * sched.bk + sched.bk
+    read = jnp.arange(s)[None, :] < reach
+    return jnp.all(jnp.where(read, again == kept, True))
 
 
 def main():
@@ -63,7 +75,14 @@ def main():
     line("lax.top_k k=2048, the LAST 2048 rows only",
          _ms(jax.jit(lambda x: jax.lax.top_k(x, topk)[0][:, -1]), slab),
          "x 8 for a layer")
-    kept, lse_i = select_fn(scores + 0.0)
+    kept, lse_i, thr, tie = select_fn(scores + 0.0)
+    masked_fn = jax.jit(lambda a, b, c, t, m: sa.index_scores(
+        a, b, c, keep=(t, m)))
+    same = _same_where_read(masked_fn(qi, ki, wi, thr, tie), kept,
+                            sa.sparse_schedule(s))
+    line("index_scores, masked again (the backward's)",
+         _ms(masked_fn, qi, ki, wi, thr, tie),
+         f"equal to select_rows' wherever attend reads: {bool(same)}")
     attend_fn = jax.jit(lambda *a: sa.attend(*a, scale=scale))
     fwd = _ms(attend_fn, q, k, v, kept)
     pairs = s * (s + 1) // 2

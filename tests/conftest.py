@@ -26,3 +26,23 @@ def _deterministic_host_rng():
     from bigdl_tpu.utils.random import RandomGenerator
     RandomGenerator.set_seed(1)
     yield
+
+
+@pytest.fixture
+def kernel_calls():
+    """``kernel_calls(jaxpr) -> {kernel name: occurrences}`` of the
+    Pallas calls in a jaxpr, those inside nested jaxprs (a checkpoint
+    region, a custom_vjp) included."""
+    import jax
+
+    def count(jaxpr, found=None):
+        found = {} if found is None else found
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                found[name] = found.get(name, 0) + 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                count(sub, found)
+        return found
+
+    return count

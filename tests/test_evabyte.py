@@ -613,6 +613,50 @@ def test_recomputation_in_the_model_is_bit_identical_to_none(system):
         plain.apply, plain, data, labels)))(params).jaxpr) == []
 
 
+@pytest.fixture()
+def on_the_kernel(monkeypatch):
+    """``EvaAttention`` on its Pallas kernel, interpreted (the path it
+    takes on the TPU), at a window the kernel takes: (model with
+    ``per_block``, the same without, params, data, labels)."""
+    import functools
+
+    from bigdl_tpu.ops.pallas.eva_attention import eva_attention
+    monkeypatch.setattr(attention_mod, "eva_attention_xla",
+                        functools.partial(eva_attention, interpret=True))
+    cfg = dict(CFG, window_size=128, chunk_size=16)
+    model = builder.build(cfg)
+    data, labels = _batch(2 * cfg["window_size"])
+    return (model, builder.build(cfg).set_remat(None),
+            _sharp(model.init(jax.random.PRNGKey(0))), data, labels)
+
+
+def test_a_recomputed_block_runs_the_attention_kernel_once(on_the_kernel,
+                                                           kernel_calls):
+    """``o`` and its row statistics are named where the kernel made them
+    and ``per_block`` keeps what is named: the gradient's jaxpr holds ONE
+    ``eva_attention_fwd`` a layer, not a second in the recomputation."""
+    model, plain, params, data, labels = on_the_kernel
+    assert model.remat_policy == "per_block"
+    for m in (model, plain):
+        calls = kernel_calls(jax.make_jaxpr(jax.grad(_loss_of(
+            m.apply, m, data, labels)))(params).jaxpr)
+        assert calls == dict(eva_attention_fwd=CFG["num_hidden_layers"],
+                             eva_attention_dqdkdv=CFG["num_hidden_layers"])
+
+
+def test_on_the_kernel_recomputation_is_bit_identical_to_none(on_the_kernel):
+    model, plain, params, data, labels = on_the_kernel
+    with_remat = jax.value_and_grad(_loss_of(model.apply, model, data,
+                                             labels))(params)
+    without = jax.value_and_grad(_loss_of(plain.apply, plain, data,
+                                          labels))(params)
+    leaves = jax.tree.leaves_with_path(with_remat)
+    assert len(leaves) > 20
+    for (path, a), b in zip(leaves, jax.tree.leaves(without)):
+        np.testing.assert_array_equal(a, b, err_msg=jax.tree_util.keystr(
+            path))
+
+
 @pytest.mark.parametrize("policy", ["per_block", "nothing_saveable"])
 def test_an_optimizers_policy_wins_and_nothing_is_recomputed_twice(
         system, policy):

@@ -256,13 +256,62 @@ def test_select_rows_kernel_is_exact(topk, quantised):
             jnp.arange(256) % 7 == 0, -1.0, 1.0)       # -0.0 among them
     want, want_lse = attention_mod.select_topk_xla(scores, topk)
     future = jnp.triu(jnp.ones((256, 256), bool), 1)
-    got, lse = sa.select_rows(jnp.where(future, jnp.nan, scores), topk,
-                              interpret=True)
+    got, lse, _, _ = sa.select_rows(jnp.where(future, jnp.nan, scores),
+                                    topk, interpret=True)
     assert bool(jnp.array_equal(got, want))
     assert float(jnp.abs(lse[..., 0] - want_lse).max()) < 1e-5
     for b in range(scores.shape[0]):
         ref = reference.select(scores[b], jnp.arange(256), topk)
         assert bool(jnp.array_equal(got[b] > -jnp.inf, ref))
+
+
+def _tying_inputs(seq):
+    """Indexer inputs whose scores are small whole numbers: every row
+    with more than ``topk`` causal keys ties at its threshold."""
+    j, di = KERNEL["j"], KERNEL["di"]
+    qi = jnp.zeros((1, j, seq, di)).at[..., 0].set(1.0)
+    ki = jnp.zeros((1, seq, di)).at[..., 0].set(
+        (jnp.arange(seq) * 7 % 5 - 1.0)[None])
+    return qi, ki, jnp.ones((1, seq, j))
+
+
+@pytest.mark.parametrize("case,topk", [("short", 2048), ("random", 300),
+                                       ("ties", 300)])
+def test_the_mask_written_again_is_the_selections_bit_for_bit(case, topk):
+    """The backward's masked scores — ``index_scores`` with a row's
+    threshold key and tie column as its epilogue — against the array
+    ``select_rows`` wrote in place, wherever a key step of ``attend``
+    reaches: rows with fewer than ``topk`` causal keys (all 1024 of
+    ``short``, the first 300 of the others), random rows, and rows built
+    to tie at their threshold (the lower index wins, again). 1024 tokens:
+    two q blocks and a key step two score tiles wide, so the tile past
+    the diagonal's is read and must be -inf."""
+    from bigdl_tpu.ops.pallas import sparse_attention as sa
+    seq = 1024
+    sched = sa.sparse_schedule(seq, topk)
+    assert (sched.index_bk, sched.bk) == (512, 1024)
+    if case == "ties":
+        qi, ki, wi = _tying_inputs(seq)
+    else:
+        _, _, _, qi, ki, wi = _attention_inputs(seq, batch=1, h=4, g=2,
+                                                d=32, j=2, di=16)
+        qi = qi.transpose(0, 2, 1, 3)
+    scores = sa.index_scores(qi, ki, wi, interpret=True)
+    kept, _, t, m = sa.select_rows(scores + 0.0, topk, interpret=True)
+    again = sa.index_scores(qi, ki, wi, keep=(t, m), interpret=True)
+    row, col = jnp.arange(seq)[:, None], jnp.arange(seq)[None, :]
+    read = col < ((row // sched.bq * sched.bq + sched.bq - 1)
+                  // sched.bk * sched.bk + sched.bk)
+    assert bool(read[0, seq - 1]) and not bool(jnp.all(kept[0] == -jnp.inf))
+    assert bool(jnp.all(jnp.where(read, again[0] == kept[0], True)))
+    counts = np.asarray(jnp.sum(again[0] > -jnp.inf, axis=-1))
+    assert list(counts) == [min(r + 1, topk) for r in range(seq)]
+    if case == "short":
+        assert bool(jnp.all(t == -2 ** 31)) and bool(jnp.all(m == seq))
+    if case == "ties":
+        # whole numbers: most rows past topk have more keys equal to
+        # their threshold than still fit, and a tie column cuts them
+        assert float(jnp.mean(m[0, topk:, 0] < seq)) > 0.9
 
 
 @pytest.fixture(scope="module")
@@ -566,26 +615,121 @@ def test_keye_lm_counts_the_cells_parameters():
     assert model.remat_policy == "per_block"
 
 
+@pytest.fixture()
+def kernels(monkeypatch):
+    """``SparseSelectAttention`` on its Pallas kernels, interpreted: the
+    path it takes on the TPU."""
+    from bigdl_tpu.ops.pallas.sparse_attention import sparse_select_attention
+    monkeypatch.setattr(
+        attention_mod, "sparse_select_xla",
+        lambda *a, topk, scale: (sparse_select_attention(
+            *a, topk=topk, scale=scale, interpret=True), None))
+
+
+def _loss(model, state, x, t):
+    crit = builder.criterion()
+    return lambda p: crit.apply(model.apply(p, state, x, training=True)[0],
+                                t)
+
+
+def _residuals(model, params, state, x, t):
+    return jax.tree.leaves(jax.eval_shape(
+        lambda p: jax.vjp(_loss(model, state, x, t), p)[1], params))
+
+
+def _sequence_squared(leaves, seq):
+    """Residual leaves with two axes of the sequence's length."""
+    return [leaf.shape for leaf in leaves if leaf.shape.count(seq) >= 2]
+
+
 def test_no_sequence_squared_array_outlives_a_block(system):
     """What the backward keeps of the forward is the block boundaries:
-    with each block recomputed, not one array of S x S elements is among
-    the residuals; without, each layer's masked scores and probabilities
-    are."""
+    with each block recomputed, not one array with two axes of length S
+    is among the residuals; without, each layer's masked scores and
+    probabilities are (the jnp path, which autodiff differentiates)."""
     model, params, state = system
     x, t = _batch(seq=128, rows=1)
-    crit = builder.criterion()
+    assert _sequence_squared(_residuals(model, params, state, x, t),
+                             128) == []
+    plain = builder.build(CFG).set_remat(None)
+    assert _sequence_squared(_residuals(plain, params, state, x, t), 128)
 
-    def largest_residual():
-        kept = jax.eval_shape(lambda p: jax.vjp(lambda q: crit.apply(
-            model.apply(q, state, x, training=True)[0], t), p)[1], params)
-        return max(leaf.size for leaf in jax.tree.leaves(kept))
 
-    assert largest_residual() < 128 * 128      # the logits: 128 x 50
-    policy, model.remat_policy = model.remat_policy, None
+def test_on_the_kernels_no_sequence_squared_array_is_a_residual(system,
+                                                                 kernels):
+    """On the kernels the masked scores are no residual of the layer,
+    recomputed or not (the backward writes them again from a row's two
+    numbers); under recomputation the residuals hold what the attention
+    kernels made, o among them, which is why the bound is on axes and
+    not on elements."""
+    model, params, state = system
+    x, t = _batch(seq=128, rows=1)
+    kept = _residuals(model, params, state, x, t)
+    assert _sequence_squared(kept, 128) == []
+    heads = (CFG["num_key_value_heads"], HEADS // CFG["num_key_value_heads"])
+    assert sum(leaf.shape == (*heads, 128, CFG["head_dim"])
+               for leaf in kept) == CFG["num_hidden_layers"]
+    plain = builder.build(CFG).set_remat(None)
+    assert _sequence_squared(_residuals(plain, params, state, x, t),
+                             128) == []
+
+
+def test_a_recomputed_block_runs_selection_and_attention_once(
+        system, kernels, kernel_calls):
+    """In the gradient's jaxpr under ``per_block`` each layer has ONE
+    ``sparse_select_rows`` and ONE ``sparse_attention_fwd`` (what they
+    made is named and kept) and TWO ``sparse_index_scores`` (the
+    forward's, and the backward's that masks again)."""
+    model, params, state = system
+    x, t = _batch(seq=128, rows=1)
+    assert model.remat_policy == "per_block"
+    calls = kernel_calls(jax.make_jaxpr(jax.grad(_loss(
+        model, state, x, t)))(params).jaxpr)
+    n = CFG["num_hidden_layers"]
+    assert calls == dict(
+        sparse_index_scores=2 * n, sparse_select_rows=n,
+        sparse_attention_fwd=n, sparse_attention_dqdkdv=n,
+        sparse_kept_probs=n, sparse_index_backward=n)
+
+
+def test_on_the_kernels_recomputation_is_bit_identical_to_none(system,
+                                                               kernels):
+    """Loss and EVERY gradient leaf, ``per_block`` (the named values
+    kept, the rest made again) against no recomputation."""
+    model, params, state = system
+    x, t = _batch(seq=128, rows=1)
+    plain = builder.build(CFG).set_remat(None)
+    with_remat = jax.value_and_grad(_loss(model, state, x, t))(params)
+    without = jax.value_and_grad(_loss(plain, state, x, t))(params)
+    leaves = jax.tree.leaves_with_path(with_remat)
+    assert len(leaves) > 30
+    for (path, a), b in zip(leaves, jax.tree.leaves(without)):
+        np.testing.assert_array_equal(a, b, err_msg=jax.tree_util.keystr(
+            path))
+
+
+def test_what_a_recomputed_block_kept_is_stated_where_it_is_traced(
+        system, kernels):
+    from bigdl_tpu.observability import trace
+    from bigdl_tpu.optim.remat import KEPT_NAMES
+    model, params, state = system
+    x, t = _batch(seq=128, rows=1)
+    trace.clear()
+    trace.enable()
     try:
-        assert largest_residual() >= 128 * 128
+        jax.eval_shape(jax.grad(_loss(model, state, x, t)), params)
+        events = trace.to_dict()["traceEvents"]
     finally:
-        model.remat_policy = policy
+        trace.disable()
+    (said,) = [e["args"] for e in events if e["name"] == "remat_kept"]
+    assert said["names"] == ",".join(KEPT_NAMES)
+    # a layer: o, its row logsumexp, and a row's index logsumexp,
+    # threshold key and tie column
+    a_layer = 4 * (HEADS * 128 * CFG["head_dim"] + HEADS * 128 + 3 * 128)
+    assert said["per_block"] == [[0, 0]] + [[5, a_layer]] * CFG[
+        "num_hidden_layers"] + [[0, 0], [0, 0]]
+    assert said["values"] == 5 * CFG["num_hidden_layers"]
+    assert said["bytes"] == a_layer * CFG["num_hidden_layers"]
 
 
 def test_the_layer_states_its_shapes_where_it_is_traced(system):

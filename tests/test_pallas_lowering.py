@@ -423,8 +423,11 @@ class TestSparseAttentionCompilesForTheV5e:
             sds((1, s, 16), jnp.float32)).compile()
 
     def test_six_kernels_and_their_names(self, compiled):
+        """Seven calls of six kernels: the scores kernel once more in
+        the backward pass, where it writes the masked scores again."""
         text = compiled.as_text()
-        assert text.count('custom_call_target="tpu_custom_call"') == 6
+        assert text.count('custom_call_target="tpu_custom_call"') == 7
+        assert len(re.findall(r"%sparse_index_scores[.\d]* = ", text)) == 2
         for name in ("sparse_index_scores", "sparse_select_rows",
                      "sparse_attention_fwd", "sparse_attention_dqdkdv",
                      "sparse_kept_probs", "sparse_index_backward"):

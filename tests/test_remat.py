@@ -160,6 +160,136 @@ class TestPolicyRegistry:
         assert r["none"] / r["nothing_saveable"] >= 1.5
 
 
+class _NamesItsSquare(nn.Module):
+    """A parameter-free layer that names one value it makes, as an
+    attention kernel's module names its output."""
+
+    def init(self, rng):
+        return {}
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        from jax.ad_checkpoint import checkpoint_name
+        return jnp.sin(checkpoint_name(x * x, "attention_out")), state
+
+
+class TestWhatPerBlockKeeps:
+    """``per_block`` keeps the block boundary and what a module names
+    (``KEPT_NAMES``); a model that names nothing is the program it was."""
+
+    def _lm(self):
+        from bigdl_tpu.models import TransformerLM
+        model = TransformerLM(32, d_model=16, num_heads=2, num_layers=2,
+                              max_len=8, with_log_softmax=False)
+        model.materialize(jax.random.PRNGKey(0))
+        data = jnp.asarray(np.random.default_rng(0).integers(
+            1, 33, size=(2, 8)))
+        return model, data
+
+    @staticmethod
+    def _loss(fwd, model, data):
+        def loss(p):
+            y, _ = fwd(p, model.state, data, training=True, rng=None)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+        return loss
+
+    def test_a_model_that_names_nothing_keeps_what_it_kept(self,
+                                                           monkeypatch):
+        """``TransformerLM``: every residual leaf of the loss's backward
+        (shape and dtype, in order) and the lowered gradient program are
+        what a bare ``jax.checkpoint(block)`` — the spelling before the
+        policy — gives."""
+        from bigdl_tpu.optim import remat
+        model, data = self._lm()
+
+        def receipt():
+            loss = self._loss(remat.remat_forward(model, "per_block"),
+                              model, data)
+            kept = jax.eval_shape(lambda p: jax.vjp(loss, p)[1],
+                                  model.params)
+            return ([(leaf.shape, leaf.dtype)
+                     for leaf in jax.tree.leaves(kept)],
+                    remat.saved_residual_bytes(loss, model.params),
+                    jax.jit(jax.grad(loss)).lower(model.params).as_text())
+
+        now = receipt()
+        policy = remat._checkpoint_policy
+        monkeypatch.setattr(
+            remat, "_checkpoint_policy",
+            lambda name: None if name == "per_block" else policy(name))
+        before = receipt()
+        assert now[0] == before[0] and now[1] == before[1] > 0
+        assert now[2] == before[2]
+
+    def test_a_named_value_is_kept_and_its_maker_runs_once(self):
+        from bigdl_tpu.optim.remat import (KEPT_NAMES, remat_forward,
+                                           saved_residual_bytes)
+        assert KEPT_NAMES == ("attention_out", "attention_stats",
+                              "attention_selection")
+        model = nn.Sequential(nn.Linear(8, 8), _NamesItsSquare(),
+                              nn.Linear(8, 8))
+        model.materialize(jax.random.PRNGKey(0))
+        x = jnp.ones((4, 8))
+
+        def loss(policy):
+            return self._loss(remat_forward(model, policy), model, x)
+
+        kept = saved_residual_bytes(loss("per_block"), model.params)
+        bare = saved_residual_bytes(loss("nothing_saveable"), model.params)
+        assert kept > bare
+        g = jax.grad(loss("per_block"))(model.params)
+        g0 = jax.grad(loss("none"))(model.params)
+        for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g0)):
+            np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("named", [True, False])
+    def test_the_instant_states_the_names_and_the_bytes(self, named):
+        """One ``bigdl:optim:remat_kept`` instant a traced forward, with
+        tracing on: per block, the values and bytes kept beyond the
+        block's inputs."""
+        from bigdl_tpu.observability import trace
+        from bigdl_tpu.optim.remat import KEPT_NAMES, remat_forward
+        model = nn.Sequential(
+            nn.Linear(8, 8), _NamesItsSquare() if named else nn.Tanh(),
+            nn.Linear(8, 8))
+        model.materialize(jax.random.PRNGKey(0))
+        loss = self._loss(remat_forward(model, "per_block"), model,
+                          jnp.ones((4, 8)))
+        jax.eval_shape(jax.grad(loss), model.params)       # tracing off
+        trace.clear()
+        trace.enable()
+        try:
+            jax.eval_shape(jax.grad(loss), model.params)
+            events = trace.to_dict()["traceEvents"]
+        finally:
+            trace.disable()
+        (said,) = [e for e in events if e["name"] == "remat_kept"]
+        assert said["cat"] == "optim"
+        middle = [1, 4 * 8 * 4] if named else [0, 0]
+        assert said["args"] == dict(
+            model="Sequential", names=",".join(KEPT_NAMES), blocks=3,
+            values=middle[0], bytes=middle[1],
+            per_block=[[0, 0], middle, [0, 0]])
+
+    def test_the_containers_checkpoint_keeps_the_same_names(self):
+        """``nn.Remat`` with no policy of its own and the pipeline's
+        ``per_block`` take the policy from optim/remat.py."""
+        from bigdl_tpu.optim.remat import saved_residual_bytes
+        inner = nn.Sequential(nn.Linear(8, 8), _NamesItsSquare())
+        wrapped = nn.Remat(inner)
+        wrapped.materialize(jax.random.PRNGKey(0))
+        x = jnp.ones((4, 8))
+
+        def loss(module):
+            return lambda p: jnp.sum(module.apply(
+                p, wrapped.state, x, training=True)[0])
+
+        bare = nn.Remat(inner, policy=jax.checkpoint_policies
+                        .nothing_saveable)
+        assert (saved_residual_bytes(loss(wrapped), wrapped.params)
+                - saved_residual_bytes(loss(bare), wrapped.params)
+                == 4 * 8 * 4)
+
+
 class TestOptimizerWiring:
     def _run(self, policy):
         import bigdl_tpu.optim as optim
