@@ -11,7 +11,7 @@ from bigdl_tpu.models.vgg.model import VggForCifar10, Vgg_16, Vgg_19
 from bigdl_tpu.models.resnet.model import (ResNet, ShortcutType, DatasetType,
                                      model_init)
 from bigdl_tpu.models.rnn.model import SimpleRNN, BatchedSimpleRNN
-from bigdl_tpu.models.transformer.model import (EvaByteLM, KeyeLM,
+from bigdl_tpu.models.transformer.model import (EvaByteLM, KeyeLM, KimiLM,
                                                 TransformerBlock,
                                                 TransformerLM)
 
@@ -22,5 +22,5 @@ __all__ = [
     "VggForCifar10", "Vgg_16", "Vgg_19",
     "ResNet", "ShortcutType", "DatasetType", "model_init",
     "SimpleRNN", "BatchedSimpleRNN",
-    "TransformerLM", "TransformerBlock", "EvaByteLM", "KeyeLM",
+    "TransformerLM", "TransformerBlock", "EvaByteLM", "KeyeLM", "KimiLM",
 ]

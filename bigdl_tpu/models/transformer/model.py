@@ -19,7 +19,7 @@ from bigdl_tpu.nn.module import Container, Module
 from bigdl_tpu.tensor import activation_dtype, default_dtype
 
 __all__ = ["TransformerLM", "TransformerBlock", "PreNormBlock", "EvaByteLM",
-           "KeyeLM", "decode_meta"]
+           "KeyeLM", "KimiLM", "decode_meta"]
 
 
 class _Residual(Container):
@@ -78,15 +78,18 @@ class _TokenAndPosition(Module):
     position enters through the attention rotation instead)."""
 
     def __init__(self, vocab: int, d_model: int, max_len: int,
-                 with_pos: bool = True, out_dtype=None):
+                 with_pos: bool = True, out_dtype=None,
+                 init_std: float | None = None):
         super().__init__()
         self.vocab, self.d_model, self.max_len = vocab, d_model, max_len
         self.with_pos = with_pos
         self.out_dtype = out_dtype      # None: the policy's activations
+        self.init_std = init_std        # None: d_model^-1/2
 
     def init(self, rng):
         k1, k2 = jax.random.split(rng)
-        scale = 1.0 / np.sqrt(self.d_model)
+        scale = 1.0 / np.sqrt(self.d_model) if self.init_std is None \
+            else self.init_std
         p = {"tok": jax.random.normal(
             k1, (self.vocab, self.d_model), default_dtype()) * scale}
         if self.with_pos:
@@ -261,6 +264,91 @@ def KeyeLM(vocab_size: int = 151936, d_model: int = 2048,
                         init_method=_small_head(d_model),
                         output_dtype=jnp.float32).set_name("lm_head"))
     model.no_decode_path = "SparseSelectAttention: ROADMAP B11"
+    return model.set_remat(remat)
+
+
+KIMI_EMBEDDING_STD = 1.0
+
+
+def KimiLM(vocab_size: int = 163840, d_model: int = 2048,
+           num_heads: int = 16, qk_nope: int = 128, qk_rope: int = 64,
+           v_dim: int = 128, kv_rank: int = 512, num_layers: int = 27,
+           dense_layers: int = 1, ffn_dim: int = 11264,
+           expert_dim: int = 1408, experts_total: int = 64,
+           experts_per_token: int = 6, shared_experts: int = 2,
+           route_scale: float = 2.446, bias_update_rate: float = 0.001,
+           experts_held: int | None = None, experts_offset: int = 0,
+           rope_theta: float = 8e5, rms_eps: float = 1e-5,
+           remat: str | None = "per_block") -> nn.Sequential:
+    """The language model of Kimi-VL-A3B-Instruct
+    (huggingface.co/moonshotai/Kimi-VL-A3B-Instruct; the DeepSeek-V3
+    style decoder Moonlight-16B-A3B): pre-norm blocks
+    x + MLA(RMSNorm(x)); x + F(RMSNorm(x)) — ``nn.LatentAttention``
+    (keys and values from one RMS-normed latent of ``kv_rank``, a score
+    of a ``qk_nope``-wide content part and a ``qk_rope``-wide rotary
+    part whose key all heads share, values ``v_dim`` wide) — where F is
+    a dense SwiGLU of ``ffn_dim`` (``nn.GatedFFN``) in the first
+    ``dense_layers`` blocks and ``parallel.expert.ExpertShare`` after
+    them: a float32 SIGMOID router over ``experts_total``, the
+    ``experts_per_token`` largest of score + bias chosen, the scores
+    alone normalised over the chosen and scaled by ``route_scale``,
+    SwiGLU experts of ``expert_dim`` of which ``experts_held`` from
+    ``experts_offset`` live here, dropless, plus ONE shared SwiGLU of
+    ``shared_experts`` x ``expert_dim`` every token passes. The bias is
+    module state the step updates from its own expert counts at
+    ``bias_update_rate`` (aux-loss-free balancing); there is no balance
+    loss. A float32 residual stream, no bias term anywhere, no position
+    table, an untied head of float32 logits over ``vocab_size`` rows (a
+    chip's slice of the vocabulary is a smaller ``vocab_size``). tokens
+    (B, S) 1-based; text positions only, no vision tower.
+    docs/latent_attention.md and docs/expert_share.md have the
+    equations.
+
+    The token embedding is drawn at UNIT deviation an element
+    (``KIMI_EMBEDDING_STD``: the scale a model that multiplies its
+    embedding by sqrt(d) starts from), not at ``TransformerLM``'s
+    d^-1/2. At d^-1/2 the blocks' outputs outweigh the embedding from
+    the first layer on, averaging attention makes the residual stream
+    one vector common to every token, and an untrained router sends
+    every token to the same experts (PERF.md section 6, PRs 31 and 33);
+    at one the stream stays the token's own and the router spreads the
+    tokens over the experts as a trained one does. The price: the
+    stream's scale divides every gradient that passes the final norm,
+    so the blocks' gradients are smaller beside the head's than at
+    d^-1/2 (the attention's 14-34 times, the embedding's 57; PERF.md
+    section 6, PR 33).
+
+    ``remat`` as ``EvaByteLM``'s; the same ``embed`` / ``block_i`` /
+    ``final_norm`` / ``lm_head`` children and no ``lm_meta``: there is
+    no decode path over a latent cache yet (``decode_meta``)."""
+    from bigdl_tpu.parallel.expert import ExpertShare
+
+    def norm():
+        return nn.RMSNorm(d_model, eps=rms_eps, fp32=True)
+
+    model = (nn.Sequential()
+             .add(_TokenAndPosition(vocab_size, d_model, 0, with_pos=False,
+                                    out_dtype=jnp.float32,
+                                    init_std=KIMI_EMBEDDING_STD)
+                  .set_name("embed")))
+    for i in range(num_layers):
+        ffn = nn.GatedFFN(d_model, ffn_dim) if i < dense_layers else \
+            ExpertShare(d_model, expert_dim, experts_total,
+                        experts_per_token, experts_held=experts_held,
+                        experts_offset=experts_offset, scoring="sigmoid",
+                        route_scale=route_scale,
+                        bias_update_rate=bias_update_rate,
+                        shared_width=shared_experts * expert_dim)
+        model.add(PreNormBlock(
+            norm,
+            nn.LatentAttention(d_model, num_heads, qk_nope, qk_rope, v_dim,
+                               kv_rank, rope_theta, rms_eps),
+            ffn, residual_dtype=jnp.float32).set_name(f"block_{i}"))
+    model.add(nn.RMSNorm(d_model, eps=rms_eps).set_name("final_norm"))
+    model.add(nn.Linear(d_model, vocab_size, with_bias=False,
+                        init_method=_small_head(d_model),
+                        output_dtype=jnp.float32).set_name("lm_head"))
+    model.no_decode_path = "LatentAttention: ROADMAP B5"
     return model.set_remat(remat)
 
 

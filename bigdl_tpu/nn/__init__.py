@@ -36,7 +36,7 @@ from bigdl_tpu.nn.table_ops import (CAddTable, CSubTable, CMulTable,
 from bigdl_tpu.nn.recurrent import (Cell, RnnCell, RNN, LSTM, GRU, Recurrent,
                                     BiRecurrent, TimeDistributed)
 from bigdl_tpu.nn.attention import (MultiHeadAttention, EvaAttention,
-                                    SparseSelectAttention)
+                                    SparseSelectAttention, LatentAttention)
 from bigdl_tpu.nn.criterion import (
     ClassNLLCriterion, MSECriterion, BCECriterion, CrossEntropyCriterion,
     ClassSimplexCriterion, AbsCriterion, CosineEmbeddingCriterion,

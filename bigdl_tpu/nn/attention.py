@@ -16,8 +16,9 @@ from bigdl_tpu.nn.module import Module
 from bigdl_tpu.tensor import activation_dtype, compute_dtype, default_dtype
 
 __all__ = ["MultiHeadAttention", "EvaAttention", "SparseSelectAttention",
-           "apply_rope", "eva_chunk_summaries", "eva_attention_xla",
-           "index_scores_xla", "select_topk_xla", "sparse_select_xla"]
+           "LatentAttention", "apply_rope", "eva_chunk_summaries",
+           "eva_attention_xla", "index_scores_xla", "select_topk_xla",
+           "sparse_select_xla"]
 
 
 def apply_rope(x, positions, theta: float = 10000.0):
@@ -512,3 +513,113 @@ class SparseSelectAttention(Module):
                 f"heads={self.num_heads}/{self.num_kv_heads}x"
                 f"{self.head_dim}, indexer={self.index_heads}x"
                 f"{self.index_dim}, topk={self.topk})")
+
+
+class LatentAttention(Module):
+    """Multi-head latent attention (DeepSeek-V2's MLA, arXiv:2405.04434,
+    as the language model of Kimi-VL-A3B trains it): causal
+    self-attention over (batch, seq, embed) whose keys and values are
+    expanded from ONE compressed latent a position, and whose score has
+    two parts — a per-head content part and a rotary part whose key is
+    one vector shared by all heads. docs/latent_attention.md has the
+    equations.
+
+    No bias anywhere. ``q_weight`` projects the input directly to
+    ``num_heads`` x (``qk_nope`` + ``qk_rope``) (no query latent);
+    ``kva_weight`` to ``kv_rank`` + ``qk_rope``: the latent c, RMS-normed
+    (``kv_norm``, a weight of ``kv_rank``), and the rotary key r;
+    ``kvb_weight`` expands the normed latent to ``num_heads`` x
+    (``qk_nope`` + ``v_dim``): a head's content key and its value;
+    ``out_weight`` takes the heads' ``v_dim``-wide sums back to embed.
+    RoPE (``apply_rope``'s half-split pairs) on q's rotary part and on
+    r. The softmax scale (qk_nope + qk_rope)^-1/2 rides W_q — multiplied
+    into the float32 weight before it is rounded to the compute dtype —
+    so the core runs at scale 1 and no score tile is multiplied by it.
+    The latent and r keep their float32 accumulators through the norm
+    and the rotation and are rounded once.
+
+    On the TPU the core is the Pallas kernels
+    (``ops/pallas/latent_attention.py``: the shared rotary key is never
+    copied over the heads, its gradient is summed over them in the
+    kernel) and shapes they do not take are an error, never another
+    path; elsewhere (the CPU tests) it is ``latent_attention_xla``."""
+
+    def __init__(self, embed_dim: int, num_heads: int, qk_nope: int,
+                 qk_rope: int, v_dim: int, kv_rank: int,
+                 rope_theta: float = 10000.0, eps: float = 1e-6):
+        super().__init__()
+        assert qk_rope % 2 == 0, "rope needs an even qk_rope"
+        self.embed_dim, self.num_heads = embed_dim, num_heads
+        self.qk_nope, self.qk_rope, self.v_dim = qk_nope, qk_rope, v_dim
+        self.kv_rank, self.rope_theta, self.eps = kv_rank, rope_theta, eps
+
+    def init(self, rng):
+        e, h = self.embed_dim, self.num_heads
+        shapes = {"q_weight": (h * (self.qk_nope + self.qk_rope), e),
+                  "kva_weight": (self.kv_rank + self.qk_rope, e),
+                  "kvb_weight": (h * (self.qk_nope + self.v_dim),
+                                 self.kv_rank),
+                  "out_weight": (e, h * self.v_dim)}
+        p = {name: init_mod.init_weight(init_mod.Xavier, key, shape,
+                                        fan_in=shape[1], fan_out=shape[0])
+             for (name, shape), key in zip(
+                 shapes.items(), jax.random.split(rng, len(shapes)))}
+        p["kv_norm"] = jnp.ones((self.kv_rank,), default_dtype())
+        return p
+
+    def _latent_norm(self, c, w):
+        """RMS norm of the float32 latent over its ``kv_rank``."""
+        return c * jax.lax.rsqrt(jnp.mean(jnp.square(c), -1, keepdims=True)
+                                 + self.eps) * w.astype(jnp.float32)
+
+    def core_inputs(self, params, x):
+        """(qN, qR, kN, kR, v) of ``latent_attention_xla`` from the
+        layer's normed input; q carries the softmax scale."""
+        b, s, _ = x.shape
+        cd, f32 = compute_dtype(), jnp.float32
+        h, heads = x.astype(cd), self.num_heads
+        pos = jnp.arange(s)
+        scale = (self.qk_nope + self.qk_rope) ** -0.5
+        q = jnp.matmul(h, (params["q_weight"].astype(f32) * scale)
+                       .astype(cd).T).reshape(b, s, heads, -1)
+        qn = q[..., :self.qk_nope]
+        qr = apply_rope(q[..., self.qk_nope:], pos, self.rope_theta)
+        cr = jnp.matmul(h, params["kva_weight"].astype(cd).T,
+                        preferred_element_type=f32)
+        c, r = cr[..., :self.kv_rank], cr[..., self.kv_rank:]
+        kv = jnp.matmul(self._latent_norm(c, params["kv_norm"]).astype(cd),
+                        params["kvb_weight"].astype(cd).T) \
+            .reshape(b, s, heads, -1)
+        kr = apply_rope(r[:, :, None, :], pos, self.rope_theta)[:, :, 0]
+        return (qn, qr, kv[..., :self.qk_nope], kr.astype(cd),
+                kv[..., self.qk_nope:])
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        from bigdl_tpu.observability import trace
+        from bigdl_tpu.ops.pallas import latent_attention as kernels
+        b, s, _ = x.shape
+        on_kernels = jax.default_backend() == "tpu"
+        # python runs this when the layer is traced for a compile, never
+        # in a step. The kernels write no score array and no broadcast
+        # key; the jnp path writes float32 scores for every pair
+        trace.instant("latent_attention", cat="nn", seq=s,
+                      heads=self.num_heads, qk_nope=self.qk_nope,
+                      qk_rope=self.qk_rope, v_dim=self.v_dim,
+                      kv_rank=self.kv_rank,
+                      causal_pairs=b * s * (s + 1) // 2,
+                      materialised_bytes=0 if on_kernels
+                      else b * self.num_heads * s * s * 4)
+        with jax.named_scope("mla_project"):
+            inputs = self.core_inputs(params, x)
+        with jax.named_scope("mla_attention"):
+            core = kernels.latent_attention if on_kernels \
+                else kernels.latent_attention_xla
+            o = core(*inputs)
+        y = jnp.matmul(o.reshape(b, s, self.num_heads * self.v_dim),
+                       params["out_weight"].astype(compute_dtype()).T)
+        return y.astype(activation_dtype()), state
+
+    def __repr__(self):
+        return (f"LatentAttention({self.embed_dim}, heads={self.num_heads}"
+                f"x({self.qk_nope}+{self.qk_rope}|{self.v_dim}), "
+                f"latent={self.kv_rank})")

@@ -62,17 +62,37 @@ MOE_STATE_KEYS = ("moe_aux", "moe_dropped_rank_frac",
 SHARE_STATE_KEYS = ("moe_held_load_max", "moe_held_load_mean",
                     "moe_local_assignment_share", "moe_tokens_without_local")
 
+#: and, where it balances its router by a selection bias: the bias
+#: itself ((experts_total,), state the STEP updates, no gradient) and
+#: two floats over ALL experts — the largest |bias|, and the busiest
+#: expert's assignments over the mean one's (DeepSeek's MaxVio + 1)
+BIAS_STATE_KEY = "moe_bias"
+BALANCE_STATE_KEYS = ("moe_bias_abs_max", "moe_load_max_over_mean")
 
-def route_top_k(x, gate_w, k: int, precision=None):
+
+def route_top_k(x, gate_w, k: int, precision=None, *,
+                scoring: str = "softmax", bias=None):
     """The router both layers share: float32 logits ``x @ gate_w``
-    (``gate_w``: (d, E)), a float32 softmax over all E experts, the k
-    largest probabilities of each token (lower expert number first among
-    equals). Returns (probs (T, E), top_p (T, k), top (T, k))."""
+    (``gate_w``: (d, E)), scored over all E experts by a float32
+    ``"softmax"`` or an elementwise ``"sigmoid"``, the k largest scores
+    of each token (lower expert number first among equals). With a
+    ``bias`` (E,) the CHOICE is the k largest of score + bias and the
+    weights are the scores alone (aux-loss-free balancing,
+    arXiv:2408.15664): the bias steers the choice, no gradient reaches
+    it. Returns (scores (T, E), top_p (T, k), top (T, k))."""
     f32 = jnp.float32
     logits = jnp.matmul(x.astype(f32), gate_w.astype(f32),
                         precision=precision)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top = jax.lax.top_k(probs, k)
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"route_top_k: scoring={scoring!r}")
+    probs = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
+        else jax.nn.sigmoid(logits)
+    if bias is None:
+        top_p, top = jax.lax.top_k(probs, k)
+    else:
+        _, top = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias.astype(f32)), k)
+        top_p = jnp.take_along_axis(probs, top, axis=-1)
     return probs, top_p, top
 
 
@@ -402,7 +422,11 @@ def _chunk_rows(assignments: int, held: int, total: int) -> int:
     the nearest whole division of ``assignments``. Four, because an
     untrained router sends every token to the same k experts (PERF.md
     section 6, PR 31): the share that lands here is then j / k with j of
-    them held, and j > 4 of 8 has 7 chances in 10000 at 16 of 128."""
+    them held, and j > 4 of 8 has 7 chances in 10000 at 16 of 128; at 8
+    of 64 and k = 6 (two chunks, each HALF of all T k rows) the second
+    chunk is reached at j > 3 of 6, 15 chances in 10000 (a sigmoid
+    router orders the experts as a softmax does, so it collapses
+    alike)."""
     most = max(1, total // (4 * held))
     return assignments // max(n for n in range(1, most + 1)
                               if assignments % n == 0)
@@ -491,11 +515,33 @@ class ExpertShare(_Module):
     busiest held expert, and of the mean one),
     ``moe_local_assignment_share`` (assignments landing here over all
     T k) and ``moe_tokens_without_local`` (share of tokens that get
-    zero)."""
+    zero).
+
+    The router's variants (``route_top_k``): ``scoring`` ``"softmax"``
+    or ``"sigmoid"``; ``route_scale`` multiplies the normalised weights
+    (DeepSeek-V3's ``routed_scaling_factor``); with a
+    ``bias_update_rate`` the choice is made by score PLUS a bias that is
+    module STATE (``moe_bias``, zeros at first): it enters the choice
+    only, no gradient reaches it, and a training step hands on
+    ``bias + rate * sign(mean(count) - count)``, ``count`` the step's
+    assignments to each of ALL ``experts_total`` experts (the whole
+    router runs here, so every chip of a layer computes the same update.
+    Under the jit / GSPMD step a ``data`` mesh axis splits the tokens
+    and the count is over all of them, so replicas hold one bias; a
+    step mapped per shard would count its shard alone, and nothing here
+    reconciles that yet: ROADMAP B4). ``moe_bias_abs_max`` and
+    ``moe_load_max_over_mean`` ride the state beside it.
+    ``shared_width`` > 0 adds a SHARED expert: one
+    ``GatedFFN`` of that width (scope ``moe_shared``) that every token
+    passes and every chip of a layer computes whole — summed over a
+    layer's shares it must be counted once."""
 
     def __init__(self, d_model: int, d_ff: int, experts_total: int,
                  top_k: int, *, experts_held: int | None = None,
-                 experts_offset: int = 0):
+                 experts_offset: int = 0, scoring: str = "softmax",
+                 route_scale: float = 1.0,
+                 bias_update_rate: float | None = None,
+                 shared_width: int = 0):
         super().__init__()
         held = experts_total if experts_held is None else experts_held
         if not 0 <= experts_offset <= experts_total - held:
@@ -507,6 +553,12 @@ class ExpertShare(_Module):
         self.d_model, self.d_ff = int(d_model), int(d_ff)
         self.experts_total, self.experts_held = int(experts_total), held
         self.experts_offset, self.top_k = int(experts_offset), int(top_k)
+        self.scoring, self.route_scale = scoring, float(route_scale)
+        self.bias_update_rate = bias_update_rate
+        self.shared = None
+        if shared_width:
+            from bigdl_tpu.nn.linear import GatedFFN
+            self.shared = GatedFFN(self.d_model, int(shared_width))
 
     def init(self, rng):
         from bigdl_tpu.nn import init as init_mod
@@ -520,17 +572,41 @@ class ExpertShare(_Module):
         p["router_weight"] = init_mod.init_weight(
             init_mod.Xavier, kr, (self.experts_total, d), fan_in=d,
             fan_out=self.experts_total)
+        if self.shared is not None:
+            p["shared"] = self.shared.init(jax.random.fold_in(rng, 1))
         return p
 
     def init_state(self):
-        return {key: jnp.zeros((), jnp.float32) for key in SHARE_STATE_KEYS}
+        state = {key: jnp.zeros((), jnp.float32) for key in SHARE_STATE_KEYS}
+        if self.bias_update_rate is not None:
+            state.update({key: jnp.zeros((), jnp.float32)
+                          for key in BALANCE_STATE_KEYS})
+            state[BIAS_STATE_KEY] = jnp.zeros((self.experts_total,),
+                                              jnp.float32)
+        return state
 
-    def route(self, params, tokens):
+    def route(self, params, tokens, bias=None):
         """(numbers of each token's ``top_k`` experts (T, k), their
-        weights normalised over the k)."""
+        weights normalised over the k and scaled)."""
         _, top_p, top = route_top_k(tokens, params["router_weight"].T,
-                                    self.top_k, precision="highest")
-        return top, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+                                    self.top_k, precision="highest",
+                                    scoring=self.scoring, bias=bias)
+        c = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        return top, c if self.route_scale == 1.0 else c * self.route_scale
+
+    def _balance(self, bias, top, training):
+        """The state a step hands on where the router has a bias: the
+        bias after this step's update (unchanged outside training) and
+        the two balance figures, from the assignments to ALL experts."""
+        counts = jnp.sum(
+            top.reshape(-1)[:, None] == jnp.arange(self.experts_total),
+            axis=0, dtype=jnp.float32)
+        mean = jnp.mean(counts)
+        if training:
+            bias = bias + self.bias_update_rate * jnp.sign(mean - counts)
+        return {BIAS_STATE_KEY: bias,
+                "moe_bias_abs_max": jnp.max(jnp.abs(bias)),
+                "moe_load_max_over_mean": jnp.max(counts) / mean}
 
     def _chunk(self, weights, tokens, cw, where, *, lo, rows):
         """What sorted assignment rows ``lo .. lo + rows - 1`` add to the
@@ -570,9 +646,12 @@ class ExpertShare(_Module):
         trace.instant("moe_share", cat="nn", experts_total=self.experts_total,
                       experts_held=held, top_k=k, tokens=t,
                       expected_local_assignments=t * k * held
-                      / self.experts_total)
+                      / self.experts_total, scoring=self.scoring,
+                      shared_width=self.shared.d_ff if self.shared else 0,
+                      bias_update_rate=self.bias_update_rate or 0.0)
+        bias = state.get(BIAS_STATE_KEY)
         with jax.named_scope("moe_router"):
-            top, c = self.route(params, tokens)
+            top, c = self.route(params, tokens, bias)
             local = top - self.experts_offset
             here = (local >= 0) & (local < held)
             # assignments by expert held, those for elsewhere last
@@ -592,6 +671,10 @@ class ExpertShare(_Module):
                       ("gate_weight", "up_weight", "down_weight")),
                 tokens.astype(cd), jnp.where(here, c, 0.0),
                 (order, inverse, starts), starts[held])
+        if self.shared is not None:
+            with jax.named_scope("moe_shared"):
+                y = y + self.shared.apply(params["shared"], {}, tokens)[0] \
+                    .astype(jnp.float32)
         f32 = jnp.float32
         loads = counts.astype(f32)
         stats = {
@@ -601,6 +684,9 @@ class ExpertShare(_Module):
             "moe_tokens_without_local":
                 1.0 - jnp.mean(jnp.any(here, axis=-1), dtype=f32),
         }
+        if bias is not None:
+            with jax.named_scope("moe_router"):
+                stats.update(self._balance(bias, top, training))
         return (y.reshape(x.shape).astype(activation_dtype()),
                 jax.tree.map(jax.lax.stop_gradient, stats))
 
@@ -640,7 +726,7 @@ def moe_state_stats(mstate) -> dict:
     def walk(tree, path):
         if isinstance(tree, dict):
             keys = [key for key in MOE_STATE_KEYS + SHARE_STATE_KEYS
-                    if key in tree]
+                    + BALANCE_STATE_KEYS if key in tree]
             if keys:
                 found["/".join(path) or "moe"] = {
                     key: tree[key] for key in keys}

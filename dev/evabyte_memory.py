@@ -2,7 +2,8 @@
 """Compile-only memory analysis of a one-chip training cell for a
 described v5e (benchmarks/README.md, rehearsal ladder step 2; no chip,
 no chip time) — the EvaByte cell, or with ``--cell`` another whose
-reference has ``_layer_vjp`` / ``_head_vjp`` (keye-vl-2.0-30b-a3b.train.
+reference has ``_layer_vjp`` / ``_head_vjp`` (the last layer's is the
+one compiled: kimi's first is its dense one; keye-vl-2.0-30b-a3b.train.
 seq16384):
 
     JAX_PLATFORMS=cpu python3 dev/evabyte_memory.py [--cell NAME] \
@@ -126,7 +127,7 @@ def main():
         x = jax.ShapeDtypeStruct((args.seq, cfg["hidden_size"]),
                                  jnp.float32, sharding=chip)
         report("check (reference, a layer's vjp)",
-               reference._layer_vjp.lower(w["layers"][0], x, x,
+               reference._layer_vjp.lower(w["layers"][-1], x, x,
                                           cfg["num_attention_heads"],
                                           w.spec))
         report("check (reference, the head's vjp)",

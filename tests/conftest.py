@@ -46,3 +46,15 @@ def kernel_calls():
         return found
 
     return count
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _dtype_policy_put_back():
+    """The dtype policy is process-global and a benchmark rehearsal sets
+    it (bfloat16 compute); a file that leaves it set changes the numbers
+    of whatever file the same xdist worker runs next. Every file hands
+    the policy on as it found it."""
+    from bigdl_tpu.tensor import get_policy, set_policy
+    old = get_policy()
+    yield
+    set_policy(old)

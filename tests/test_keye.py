@@ -750,7 +750,9 @@ def test_the_layer_states_its_shapes_where_it_is_traced(system):
     assert sel[0]["causal_pairs"] == 2 * 48 * 49 // 2
     assert sel[0]["materialised_bytes"] == 2 * 48 * 48 * 4
     assert moe[0] == dict(experts_total=8, experts_held=4, top_k=2,
-                          tokens=96, expected_local_assignments=96.0)
+                          tokens=96, expected_local_assignments=96.0,
+                          scoring="softmax", shared_width=0,
+                          bias_update_rate=0.0)
 
 
 def test_the_optimizer_trains_the_model_as_built(system):

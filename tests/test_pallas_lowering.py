@@ -470,3 +470,43 @@ class TestGroupedMatmulCompilesForTheV5e:
         ).compile().as_text()
         # gmm forward, gmm for d rows, tgmm for d weights
         assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+class TestLatentAttentionCompilesForTheV5e:
+    """Mosaic's verdict on the latent-attention kernels at the benchmark
+    cell's shape (kimi-vl-a3b-instruct.train.seq8192: 2 x 8192 tokens, 16
+    heads, 128 | 64 | 128 wide), forward and backward in one program:
+    36 MiB resident in the one-pass backward (over flash's budget: the
+    kernels ask for 100 MiB of VMEM), 64-lane blocks, the shared key's
+    accumulator over a batch row's heads."""
+
+    B, S, H = 2, 8192, 16
+
+    @pytest.fixture(scope="class")
+    def compiled(self, one_chip):
+        from bigdl_tpu.ops.pallas.latent_attention import latent_attention
+
+        def sds(*shape):
+            return jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+        b, s, h = self.B, self.S, self.H
+        return jax.jit(_scalar_grads(latent_attention, 5)).lower(
+            sds(b, s, h, 128), sds(b, s, h, 64), sds(b, s, h, 128),
+            sds(b, s, 64), sds(b, s, h, 128)).compile()
+
+    def test_two_kernels_and_their_names(self, compiled):
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        for name in ("latent_attention_fwd", "latent_attention_dqdkdv"):
+            assert name in text, name
+
+    def test_the_shared_key_is_never_copied_over_the_heads(self, compiled):
+        """No array of (B, S, 16, 192) or (B x 16, S, 192) elements — a
+        key with the rotary part beside the content part — and none
+        with two axes of the sequence's length; the shared key's
+        gradient leaves the kernel as (B, S, 64)."""
+        text = compiled.as_text()
+        b, s, h = self.B, self.S, self.H
+        assert not re.search(r"[\[,]192[,\]]", text)
+        assert not re.search(rf"\[(\d+,)*{s},{s}[,\]]", text)
+        assert re.search(rf"bf16\[{b},{s},64\]", text)
