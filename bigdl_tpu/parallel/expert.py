@@ -60,7 +60,8 @@ MOE_STATE_KEYS = ("moe_aux", "moe_dropped_rank_frac",
 
 #: module-state keys ``ExpertShare`` maintains (floats, as above)
 SHARE_STATE_KEYS = ("moe_held_load_max", "moe_held_load_mean",
-                    "moe_local_assignment_share", "moe_tokens_without_local")
+                    "moe_local_assignment_share", "moe_tokens_without_local",
+                    "moe_product_row_share", "moe_chunks_run")
 
 #: and, where it balances its router by a selection bias: the bias
 #: itself ((experts_total,), state the STEP updates, no gradient) and
@@ -426,7 +427,17 @@ def _chunk_rows(assignments: int, held: int, total: int) -> int:
     of 64 and k = 6 (two chunks, each HALF of all T k rows) the second
     chunk is reached at j > 3 of 6, 15 chances in 10000 (a sigmoid
     router orders the experts as a softmax does, so it collapses
-    alike)."""
+    alike). The grouped products' work follows the rows the held experts
+    were sent, but the gather of a chunk's rows, the pass between the
+    products and the combine's backward are the chunk's size whatever
+    it holds: chunks of TWICE the balanced share were timed on the chip
+    (dev/expert_chunks.py; PERF.md section 6, PR 34) and run the layer
+    faster at a balanced router and at a collapsed one, but every chunk
+    is a body of its own in the compiled step (a ``lax.cond`` each, no
+    loop), eleven grouped-product kernels a body: twice the bodies made
+    the step's executable a quarter larger and a warm start 15% longer,
+    more than the benchmark's bound on ``setup_s`` allows. Four stays
+    until a chunk's body holds fewer kernels (ROADMAP A10)."""
     most = max(1, total // (4 * held))
     return assignments // max(n for n in range(1, most + 1)
                               if assignments % n == 0)
@@ -494,9 +505,12 @@ class ExpertShare(_Module):
     ``lax.cond`` each, every chunk recomputed in the backward pass), so
     one expert may take every token and the step is sized for the
     routing it meets, not for the worst. A chunk's rows for experts
-    elsewhere ride in its last held expert's group with weight zero: a
-    chunk's work is its rows, whatever the routing, so the layer's time
-    moves only when another chunk is reached. With ``experts_held ==
+    elsewhere are the products' LAST group, the one no matrix here is
+    for: they cost no product work and come out zeros (their combine
+    weight is zero too), so the products' work is the rows the held
+    experts were sent, forward and backward; the gather of a chunk's
+    rows, the pass between the products and the combine are still a
+    chunk's size. With ``experts_held ==
     experts_total`` it is the whole layer in one chunk. On one chip it
     runs without an exchange and nothing here stands in for the absent
     chips: summed over the shares of a layer, the results are the uncut
@@ -514,8 +528,11 @@ class ExpertShare(_Module):
     ``moe_held_load_max`` / ``moe_held_load_mean`` (assignments of the
     busiest held expert, and of the mean one),
     ``moe_local_assignment_share`` (assignments landing here over all
-    T k) and ``moe_tokens_without_local`` (share of tokens that get
-    zero).
+    T k), ``moe_tokens_without_local`` (share of tokens that get
+    zero), ``moe_chunks_run`` (chunks of sorted rows the step worked
+    on) and ``moe_product_row_share`` (rows in the held experts' groups
+    over the rows of those chunks: the part of a chunk the grouped
+    products multiply).
 
     The router's variants (``route_top_k``): ``scoring`` ``"softmax"``
     or ``"sigmoid"``; ``route_scale`` multiplies the normalised weights
@@ -616,15 +633,13 @@ class ExpertShare(_Module):
         assignments, its inverse, and (held + 1,) the sorted row at
         which each held expert's group starts and the last one ends."""
         order, inverse, starts = where
-        held = self.experts_held
         idx = jax.lax.slice_in_dim(order, lo, lo + rows)
         at = jnp.where((inverse >= lo) & (inverse < lo + rows),
                        inverse - lo, rows).reshape(cw.shape)
-        edges = jnp.clip(starts, lo, lo + rows)
-        # the rows past the last held expert's are for experts elsewhere:
-        # they ride in its group (weight zero), so the products' work is
-        # this chunk's rows whatever the routing
-        sizes = jnp.diff(edges.at[held].set(lo + rows), append=lo + rows)
+        # ``held`` groups for the held experts' rows in this chunk and the
+        # last for the rest of it, rows for experts elsewhere: they cost
+        # the products no work and come out zeros
+        sizes = jnp.diff(jnp.clip(starts, lo, lo + rows), append=lo + rows)
         x = _rows_by_expert(tokens, idx, at, self.top_k)
         gate, up, down = (functools.partial(grouped_matmul, w=w,
                                             group_sizes=sizes)
@@ -646,7 +661,8 @@ class ExpertShare(_Module):
         trace.instant("moe_share", cat="nn", experts_total=self.experts_total,
                       experts_held=held, top_k=k, tokens=t,
                       expected_local_assignments=t * k * held
-                      / self.experts_total, scoring=self.scoring,
+                      / self.experts_total, chunk_rows=rows,
+                      chunks=t * k // rows, scoring=self.scoring,
                       shared_width=self.shared.d_ff if self.shared else 0,
                       bias_update_rate=self.bias_update_rate or 0.0)
         bias = state.get(BIAS_STATE_KEY)
@@ -677,12 +693,17 @@ class ExpertShare(_Module):
                     .astype(jnp.float32)
         f32 = jnp.float32
         loads = counts.astype(f32)
+        # the chunk at 0 always runs, the one at ``lo`` when live > lo
+        live = starts[held]
+        chunks = jnp.maximum(1, -(-live // rows))
         stats = {
             "moe_held_load_max": jnp.max(loads),
             "moe_held_load_mean": jnp.mean(loads),
             "moe_local_assignment_share": jnp.sum(loads) / (t * k),
             "moe_tokens_without_local":
                 1.0 - jnp.mean(jnp.any(here, axis=-1), dtype=f32),
+            "moe_product_row_share": live / (chunks * rows),
+            "moe_chunks_run": chunks.astype(f32),
         }
         if bias is not None:
             with jax.named_scope("moe_router"):

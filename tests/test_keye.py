@@ -482,7 +482,9 @@ def test_chunks_of_sorted_rows_are_the_same_layer(monkeypatch, rows):
     first always, the others behind a ``lax.cond`` and recomputed in the
     backward pass, here with ~64 landing on the share so that some
     chunks run and some do not: the same result, gradients (the
-    input's too) and telemetry as all 256 rows in one chunk."""
+    input's too) and telemetry as all 256 rows in one chunk — but for
+    the two keys that say how the rows were chunked (PR 34), which must
+    be the chunks the live rows reach and their share of those."""
     x = jax.random.normal(jax.random.PRNGKey(6), (64, D))
 
     def run(chunk):
@@ -497,10 +499,172 @@ def test_chunks_of_sorted_rows_are_the_same_layer(monkeypatch, rows):
     (a, sa, ga), (b, sb, gb) = run(256), run(rows)
     assert 16 < float(sa["moe_local_assignment_share"]) * 256 < 128
     assert abs(float(a) - float(b)) < 1e-5 * abs(float(a))
-    assert jax.tree.map(float, sa) == jax.tree.map(float, sb)
+    sa, sb = jax.tree.map(float, sa), jax.tree.map(float, sb)
+    live = round(sa["moe_local_assignment_share"] * 256)
+    for state, each in ((sa, 256), (sb, rows)):
+        ran = -(-live // each)
+        assert state.pop("moe_chunks_run") == ran
+        assert state.pop("moe_product_row_share") == pytest.approx(
+            live / (ran * each))
+    assert sa == sb
     for name in ga[0]:
         assert _rel(ga[0][name], gb[0][name]) < 1e-5, name
     assert _rel(ga[1], gb[1]) < 1e-5
+
+
+def _what_the_chunks_are_handed(monkeypatch, layer, mine, x):
+    """``_in_chunks``' arguments as ``apply`` makes them for ``x``, as
+    concrete arrays: (rows, weights, tokens, cw, where, live)."""
+    seen = []
+
+    def keep(chunk, rows, weights, tokens, cw, where, live):
+        seen.append((rows, weights, tokens, cw, where, live))
+        return jnp.zeros(tokens.shape, jnp.float32)
+
+    with monkeypatch.context() as m:
+        m.setattr(expert_mod, "_in_chunks", keep)
+        layer.apply(mine, layer.init_state(), x)
+    (args,) = seen
+    return args
+
+
+def _riders_in_the_last_held_group(held, grouped=expert_mod.grouped_matmul):
+    """``grouped_matmul`` as commit 0aa00c8's ``_chunk`` called it: the
+    rows for experts elsewhere put into the LAST held expert's group, so
+    every product multiplied all of the chunk's rows."""
+    def parents(x, w, group_sizes):
+        return grouped(x, w, group_sizes.at[held - 1].add(group_sizes[held])
+                       .at[held].set(0))
+    return parents
+
+
+@pytest.mark.parametrize("which", ["wholly live", "partly live",
+                                   "past live"])
+def test_a_chunks_products_get_the_held_groups_and_the_rest_apart(
+        monkeypatch, which):
+    """What ``_chunk`` hands ``grouped_matmul``: ``held`` groups that
+    sum to the chunk's LIVE rows, each the held expert's rows that fall
+    into the chunk, and a last group that is the rest of it — so the
+    products' work follows the rows the held experts were sent. For a
+    chunk wholly live, partly live and past ``live`` (nothing but the
+    last group), all three products alike."""
+    rows = 32
+    monkeypatch.setattr(expert_mod, "_chunk_rows", lambda *a: rows)
+    layer, mine = _share(4, 4)
+    x = jax.random.normal(jax.random.PRNGKey(6), (64, D))
+    _, weights, tokens, cw, where, live = _what_the_chunks_are_handed(
+        monkeypatch, layer, mine, x)
+    live = int(live)
+    assert rows < live < 256 - rows and live % rows
+    lo = {"wholly live": 0, "partly live": live // rows * rows,
+          "past live": 256 - rows}[which]
+    handed = []
+    real = expert_mod.grouped_matmul
+
+    def record(x, w, group_sizes):
+        handed.append(np.asarray(group_sizes))
+        return real(x, w, group_sizes)
+
+    monkeypatch.setattr(expert_mod, "grouped_matmul", record)
+    y = layer._chunk(weights, tokens, cw, where, lo=lo, rows=rows)
+    top, _ = layer.route(mine, x)
+    group = np.sort(np.where((top >= 4) & (top < 8), top - 4, 4).reshape(-1))
+    want = np.bincount(group[lo:lo + rows], minlength=5)
+    assert len(handed) == 3
+    for sizes in handed:
+        np.testing.assert_array_equal(sizes, want)
+    in_live = min(max(live - lo, 0), rows)
+    assert want[:4].sum() == in_live and want[4] == rows - in_live
+    assert {"wholly live": in_live == rows, "partly live": 0 < in_live < rows,
+            "past live": in_live == 0}[which]
+    assert (float(jnp.abs(y).max()) == 0.0) == (which == "past live")
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["ragged_dot", "megablox-interpreted"])
+def test_rows_that_cost_no_product_give_the_parents_layer(monkeypatch,
+                                                          interpret):
+    """The same mathematics with less multiplication: result, state and
+    every gradient — the experts', the router's, the input's, and the
+    combine weights' taken alone — equal what the layer gave when the
+    rows for experts elsewhere rode in the last held expert's group
+    (weight zero), over eight chunks of which two run. Only a weight
+    that is zero because its expert is elsewhere had a gradient then
+    (the row's finite garbage, which ``apply``'s ``where`` discarded)
+    and has an exact zero now."""
+    monkeypatch.setattr(expert_mod, "_chunk_rows", lambda *a: 32)
+    layer, mine = _share(4, 4)
+    x = jax.random.normal(jax.random.PRNGKey(6), (64, D))
+    grouped = functools.partial(expert_mod.grouped_matmul,
+                                interpret=interpret)
+
+    def run(product):
+        monkeypatch.setattr(expert_mod, "grouped_matmul", product)
+        (loss, (y, state)), grads = jax.value_and_grad(
+            lambda p, x: (lambda y, st: (jnp.sum(y ** 2), (y, st)))(
+                *layer.apply(p, layer.init_state(), x)), argnums=(0, 1),
+            has_aux=True)(mine, x)
+        rows, weights, tokens, cw, where, live = \
+            _what_the_chunks_are_handed(monkeypatch, layer, mine, x)
+        assert 32 < int(live) < 64
+        d_cw = jax.grad(lambda cw: jnp.sum(expert_mod._in_chunks(
+            layer._chunk, rows, weights, tokens, cw, where, live) ** 2))(cw)
+        return y, state, dict(grads[0], x=grads[1], elsewhere=d_cw[cw == 0],
+                              combine_weights=jnp.where(cw != 0, d_cw, 0.0))
+
+    y, state, grads = run(grouped)
+    was_y, was_state, was = run(_riders_in_the_last_held_group(4, grouped))
+    assert float(jnp.abs(y - was_y).max()) <= 1e-6 * float(jnp.abs(y).max())
+    assert jax.tree.map(float, state) == jax.tree.map(float, was_state)
+    assert float(jnp.abs(grads.pop("elsewhere")).max()) == 0.0
+    assert float(jnp.abs(was.pop("elsewhere")).max()) > 0.0
+    for name in grads:
+        assert float(jnp.abs(was[name]).max()) > 0, name
+        assert _rel(grads[name], was[name]) < 1e-6, name
+
+
+@pytest.mark.parametrize("rows,routing", [
+    (None, "as it falls"), (32, "as it falls"), (32, "one expert takes all"),
+    (32, "none held is chosen")])
+def test_telemetry_says_what_part_of_the_chunks_was_multiplied(
+        monkeypatch, rows, routing):
+    """``moe_chunks_run``: the chunk at 0 and every later one the live
+    rows reach; ``moe_product_row_share``: the live rows over the rows
+    of those chunks (about a quarter where the chunk is four times the
+    balanced share and the router is even; 0 when no chosen expert is
+    held). ``moe_state_stats`` reads both."""
+    if rows is not None:
+        monkeypatch.setattr(expert_mod, "_chunk_rows", lambda *a: rows)
+    layer, mine = _share(2, 8)
+    rows = rows or expert_mod._chunk_rows(64 * TOP, 2, TOTAL)
+    x = jax.random.normal(jax.random.PRNGKey(5), (64, D))
+    steer = {"as it falls": None, "one expert takes all": 4.0,
+             "none held is chosen": -40.0}[routing]
+    if steer is not None:
+        x = x.at[:, 0].set(3.0)
+        mine["router_weight"] = mine["router_weight"].at[9, 0].set(steer) \
+            .at[8, 0].set(min(steer, 0.0))
+    y, state = layer.apply(mine, layer.init_state(), x)
+    top, _ = layer.route(mine, x)
+    live = int(((top >= 8) & (top < 10)).sum())
+    chunks = max(1, -(-live // rows))
+    assert float(state["moe_chunks_run"]) == chunks
+    assert float(state["moe_product_row_share"]) == pytest.approx(
+        live / (chunks * rows))
+    if routing == "as it falls":
+        assert 0.5 * 32 < live < 1.5 * 32       # 2 of 16 held: an eighth
+        if rows == 128:                         # four times that
+            assert chunks == 1
+            assert 0.125 < float(state["moe_product_row_share"]) < 0.375
+    elif routing == "one expert takes all":
+        assert live >= 64 and chunks >= 2
+    else:
+        assert live == 0 and chunks == 1
+        assert float(state["moe_product_row_share"]) == 0.0
+        assert float(jnp.abs(y).max()) == 0.0
+    stats = expert_mod.moe_state_stats({"blk": {"1": state}})
+    assert {"moe_product_row_share", "moe_chunks_run"} <= set(stats["blk/1"])
+    assert stats["blk/1"]["moe_chunks_run"] is state["moe_chunks_run"]
 
 
 def test_no_loop_encloses_the_experts():
@@ -751,6 +915,7 @@ def test_the_layer_states_its_shapes_where_it_is_traced(system):
     assert sel[0]["materialised_bytes"] == 2 * 48 * 48 * 4
     assert moe[0] == dict(experts_total=8, experts_held=4, top_k=2,
                           tokens=96, expected_local_assignments=96.0,
+                          chunk_rows=192, chunks=1,
                           scoring="softmax", shared_width=0,
                           bias_update_rate=0.0)
 

@@ -522,11 +522,66 @@ def test_replicas_of_the_one_program_hold_one_bias():
     assert not np.allclose(halves[0]["moe_bias"], halves[1]["moe_bias"])
 
 
+@pytest.mark.parametrize("rows", [None, 48])
+def test_rows_for_experts_elsewhere_cost_no_product_and_change_nothing(
+        monkeypatch, rows):
+    """This router's layer (sigmoid, a bias in the choice, the shared
+    expert beside the routed ones) with a chunk's rows for experts
+    elsewhere in the products' last group, as ``_chunk`` hands them
+    over, against the same rows riding in the last held expert's group
+    (commit 0aa00c8): result, the state a training step hands on and
+    every gradient are equal, in the module's own chunk (all 384 rows)
+    and in chunks of 48 of which the live ~96 reach two or three; the
+    two new state keys say what part of those chunks the products
+    multiplied."""
+    if rows is not None:
+        monkeypatch.setattr(expert_mod, "_chunk_rows", lambda *a: rows)
+    layer, params = _share(4, 4)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 64, D))
+    state = dict(layer.init_state(),
+                 moe_bias=0.1 * jax.random.normal(jax.random.PRNGKey(3),
+                                                  (TOTAL,)))
+    real = expert_mod.grouped_matmul
+
+    def riders(x, w, group_sizes):
+        return real(x, w, group_sizes.at[3].add(group_sizes[4]).at[4].set(0))
+
+    def run(product):
+        monkeypatch.setattr(expert_mod, "grouped_matmul", product)
+        (_, (y, new)), grads = jax.value_and_grad(
+            lambda p, x: (lambda y, st: (jnp.sum(y ** 2), (y, st)))(
+                *layer.apply(p, state, x, training=True)), argnums=(0, 1),
+            has_aux=True)(params, x)
+        return y, new, jax.tree.leaves(grads)
+
+    y, new, grads = run(real)
+    was_y, was_new, was_grads = run(riders)
+    assert float(jnp.abs(y - was_y).max()) <= 1e-6 * float(jnp.abs(y).max())
+    assert new.keys() == was_new.keys()
+    for key in new:
+        np.testing.assert_array_equal(new[key], was_new[key])
+    for a, b in zip(grads, was_grads):
+        assert float(jnp.abs(b).max()) > 0 and _rel(a, b) < 1e-6
+    top = layer.route(params, x.reshape(-1, D), state["moe_bias"])[0]
+    live = int(((top >= 4) & (top < 8)).sum())
+    each = rows or expert_mod._chunk_rows(128 * TOP, 4, TOTAL)
+    assert 64 < live < 128
+    ran = -(-live // each)
+    assert float(new["moe_chunks_run"]) == ran
+    assert float(new["moe_product_row_share"]) == pytest.approx(
+        live / (ran * each))
+
+
 def test_keyes_expert_share_lowers_to_the_parents_text():
     """``route_top_k`` gained a scoring and a bias; the softmax router
     without a bias — the keye cell's — must lower, forward and backward,
-    to the text it lowered to before (the digest is of commit 7dc00b9's
-    program, made by this very code in a checkout of it)."""
+    to ONE pinned text whatever the other router's arguments grow into.
+    The digest is of PR 34's program, the commit that follows 0aa00c8,
+    made by this very code: that PR changed the text on purpose (a
+    chunk's rows for experts elsewhere are the products' last group,
+    two more state keys). Through PR 33 it was 58ca92f9...af5e0f7,
+    commit 7dc00b9's, made in a checkout of it: PR 33 left that text
+    as it was."""
     layer = ExpertShare(16, 8, 16, 4, experts_held=4, experts_offset=4)
     params = jax.eval_shape(layer.init, jax.random.PRNGKey(0))
     x = jax.ShapeDtypeStruct((2, 24, 16), jnp.float32)
@@ -539,7 +594,7 @@ def test_keyes_expert_share_lowers_to_the_parents_text():
         text = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
             params, x).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        "58ca92f9377a79d0b2470770298c99dfe00cb94f29870f69adf63d734af5e0f7"
+        "208df886cb768ae895a36a14c2d5ba3f3303a5c5f8ff68f909a05da47b7e541b"
     assert set(layer.init_state()) == set(expert_mod.SHARE_STATE_KEYS)
 
 
@@ -686,6 +741,7 @@ def test_what_the_layers_are_is_stated_where_they_are_traced(system,
     moe = [e["args"] for e in events if e["name"] == "moe_share"]
     assert moe[0] == dict(experts_total=8, experts_held=4, top_k=3,
                           tokens=128, expected_local_assignments=192.0,
+                          chunk_rows=384, chunks=1,
                           scoring="sigmoid", shared_width=32,
                           bias_update_rate=0.05)
 
