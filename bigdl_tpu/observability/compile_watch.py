@@ -102,24 +102,12 @@ def _sig_diff(old: tuple | None, new: tuple) -> str:
     return "; ".join(parts) if parts else "structure changed"
 
 
-def executable_stats(executable) -> dict:
-    """Cost/memory table of one compiled executable (the extraction
-    models/utils/perf.py does inline at :157/:326, shared).
-
-    Every field is best-effort: backends differ in what they expose
-    (CPU has cost_analysis but may lack memory_analysis), and telemetry
-    must never break the caller."""
+def memory_stats(executable) -> dict:
+    """What the compiler says one device holds while the executable
+    runs: ``arg_bytes``, ``output_bytes``, ``temp_bytes``,
+    ``alias_bytes``, ``code_bytes`` and their ``peak_hbm_bytes``. Empty
+    where the backend gives no memory analysis."""
     out: dict[str, float] = {}
-    try:
-        cost = executable.cost_analysis()
-    except Exception:
-        cost = None
-    if cost:
-        for key, name in (("flops", "flops"),
-                          ("bytes accessed", "bytes_accessed")):
-            v = cost.get(key)
-            if v is not None:
-                out[name] = float(v)
     try:
         mem = executable.memory_analysis()
     except Exception:
@@ -140,6 +128,28 @@ def executable_stats(executable) -> dict:
             out["peak_hbm_bytes"] = max(
                 out["arg_bytes"] + out["output_bytes"]
                 + out["temp_bytes"] - out.get("alias_bytes", 0.0), 0.0)
+    return out
+
+
+def executable_stats(executable) -> dict:
+    """Cost/memory table of one compiled executable (the extraction
+    models/utils/perf.py does inline at :157/:326, shared).
+
+    Every field is best-effort: backends differ in what they expose
+    (CPU has cost_analysis but may lack memory_analysis), and telemetry
+    must never break the caller."""
+    out: dict[str, float] = {}
+    try:
+        cost = executable.cost_analysis()
+    except Exception:
+        cost = None
+    if cost:
+        for key, name in (("flops", "flops"),
+                          ("bytes accessed", "bytes_accessed")):
+            v = cost.get(key)
+            if v is not None:
+                out[name] = float(v)
+    out.update(memory_stats(executable))
     return out
 
 

@@ -46,7 +46,8 @@ import time
 
 __all__ = ["Tracer", "get_tracer", "enable", "disable", "enabled",
            "span", "instant", "host_sync", "export", "to_dict", "clear",
-           "ProgramScopes", "matmuls_fed_by"]
+           "ProgramScopes", "matmuls_fed_by", "in_profiler_session",
+           "state"]
 
 _annotation_cls = None
 
@@ -85,6 +86,30 @@ def _annotation(name: str, cat: str, args: dict):
         return cls(label, **{k: v for k, v in args.items()
                              if isinstance(v, (bool, int, float, str))})
     return cls(label)
+
+
+def in_profiler_session() -> bool:
+    """Whether a profiler session is running: what a statement that
+    costs something to make asks first."""
+    return _annotation_class().is_enabled()
+
+
+def _payload(payload: dict) -> str:
+    """``payload`` as the JSON an annotation's ``long_name`` stat
+    carries. (The profiler's encoding ends an annotation's stats at
+    ``#``.)"""
+    return json.dumps(payload, separators=(",", ":")).replace("#", "_")
+
+
+def state(name: str, cat: str, payload: dict) -> None:
+    """What the program knows and a trace cannot show, stated into the
+    running profiler session as ONE annotation ``bigdl:<cat>:<name>``
+    whose ``long_name`` stat is ``payload`` as JSON: a reader of the
+    trace finds it by its label, on the device's clock. Nothing outside
+    a session."""
+    if in_profiler_session():
+        with _annotation(name, cat, {"long_name": _payload(payload)}):
+            pass
 
 
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(")
@@ -209,25 +234,38 @@ class ProgramScopes:
     ``bigdl:compile:step_scopes``, so whoever reads the trace can name
     each device operation of that program's runs by the part of the step
     it belongs to. The table is that annotation's ``long_name`` stat, as
-    JSON, serialised in ``add`` and not inside the session. (The
-    profiler's encoding ends an annotation's stats at ``#``.)"""
+    JSON, serialised in ``add`` and not inside the session.
+
+    The table's ``"memory"`` is what the compiler says the program
+    holds (``compile_watch.memory_stats``: ``arg_bytes``,
+    ``output_bytes``, ``alias_bytes``, ``temp_bytes``, ``code_bytes``
+    and ``peak_hbm_bytes`` = arguments + outputs + temporaries -
+    aliased; one device's, under a mesh), or ``null`` where the
+    executable gives no memory analysis: never zeros."""
 
     def __init__(self):
         self._tables: list[str] = []
         self._written = 0
 
     def add(self, compiled) -> None:
+        from bigdl_tpu.observability.compile_watch import memory_stats
+        log = logging.getLogger(__name__)
         try:
             table = _program_scopes(compiled.as_text())
-            self._tables.append(json.dumps(
-                table, separators=(",", ":")).replace("#", "_"))
         except Exception as e:    # a trace aid must not stop training
-            logging.getLogger(__name__).warning(
+            log.warning(
                 "no scope table for a compiled step, so a profiler "
                 "trace cannot name its device operations by scope: %r", e)
+            return
+        table["memory"] = memory_stats(compiled) or None
+        if table["memory"] is None:
+            log.warning(
+                "no memory analysis for compiled step %s, so a profiler "
+                "trace cannot say what it holds", table["program"])
+        self._tables.append(_payload(table))
 
     def annotate(self) -> None:
-        if not _annotation_class().is_enabled():
+        if not in_profiler_session():
             self._written = 0
         elif self._written < len(self._tables):
             for table in self._tables[self._written:]:

@@ -767,7 +767,7 @@ def _core_bwd(topk, scale, weight, interpret, res, g):
     b, heads, _, di = qi.shape
     groups = bg // b
     sched = sparse_schedule(s, topk)
-    with jax.named_scope("indexer"):
+    with jax.named_scope("indexer"), jax.named_scope("recompute"):
         # the forward's masked scores again, from a row's two numbers
         kept = index_scores(qi, ki, wi, keep=(t, m), interpret=interpret)
     with jax.named_scope("sparse_attention"):

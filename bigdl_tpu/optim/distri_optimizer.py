@@ -177,28 +177,6 @@ class DistriOptimizer(Optimizer):
             st["peak_stash_microbatches"])
         return pp
 
-    def _publish_expert_telemetry(self, mstate) -> None:
-        """Epoch-boundary MoE telemetry publish: ONE batched
-        ``jax.device_get`` over every MoE layer's state leaves — the
-        loop never pays a per-step sync for it."""
-        if not self.expert_parallel:
-            return
-        from bigdl_tpu.parallel.expert import publish_moe_metrics
-        try:
-            stats = publish_moe_metrics(mstate)
-        except Exception as e:    # telemetry must never break training
-            logger.debug("moe telemetry publish failed: %s", e)
-            return
-        if stats and logger.isEnabledFor(logging.INFO):
-            for layer, vals in stats.items():
-                logger.info(
-                    "moe[%s]: dropped ranks %.1f%%, tokens %.1f%%, "
-                    "overflow %.0f, imbalance %.2f", layer,
-                    100 * vals.get("moe_dropped_rank_frac", 0.0),
-                    100 * vals.get("moe_dropped_token_frac", 0.0),
-                    vals.get("moe_overflow_tokens", 0.0),
-                    vals.get("moe_load_imbalance", 0.0))
-
     def _init_sharded_update(self, mesh, params):
         """Validate + build the ShardedWeightUpdate mechanics (None when
         the feature is off). Raises on configurations whose layouts
@@ -559,5 +537,4 @@ class DistriOptimizer(Optimizer):
             records_scale=jax.process_count(),
             on_first_compile=lambda compiled: self._account_collectives(
                 compiled, n_shards),
-            publish_telemetry=self._publish_expert_telemetry,
             export=export)

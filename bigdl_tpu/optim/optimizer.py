@@ -94,7 +94,7 @@ def _require_process_sharded(dataset, what: str):
 class _TrainRun:
     """What a subclass's ``_prepare_run`` hands the one training loop
     (``Optimizer._optimize_impl``): everything in which training one
-    program on one device and training over a mesh differ. The three
+    program on one device and training over a mesh differ. The two
     hooks default to what the local optimizer needs: nothing."""
     # the placed training state and the resume point
     params: Any
@@ -109,8 +109,6 @@ class _TrainRun:
     records_scale: int = 1             # processes feeding one batch
     # the first executable of the run, once (collective accounting)
     on_first_compile: Callable = lambda compiled: None
-    # at every epoch end and at exit (MoE dispatch telemetry)
-    publish_telemetry: Callable = lambda mstate: None
     # the params-shaped trees validation, a checkpoint and the exit
     # read: (params, opt_state, with_opt) -> (params tree, opt state);
     # opt state is re-shaped only where ``with_opt``
@@ -1090,7 +1088,7 @@ class Optimizer:
                           e["data_time"], e["device_time"])
 
     def _drain_pending(self, pending: list, driver_state: dict,
-                       reason: str) -> None:
+                       reason: str, mstate) -> None:
         """Drain the in-flight window: ONE packed ``jax.device_get`` for
         every pending loss (the sanctioned batched readback — the only
         host<-device sync in the steady-state loop), then emit each
@@ -1098,18 +1096,35 @@ class Optimizer:
         ``neval``. The readback wait cannot be attributed to a single
         step once dispatch runs ahead, so it is amortized evenly across
         the window (window-amortized device time, docs/PERFORMANCE.md).
+
+        Inside a profiler session the experts' routing telemetry that
+        ``mstate`` holds (the module state the window's last step
+        returned: alive until the next dispatch donates it) rides the
+        same readback and is stated as one ``bigdl:optim:expert_state``
+        annotation: ``{"step": ..., "layers": {path: {stat: float}}}``.
+        What the state holds decides, no flag; no program is compiled.
         """
         if not pending:
             return
         depth = len(pending)
         self.metrics.set("dispatch depth", depth)
+        experts = {}
+        if trace.in_profiler_session():
+            from bigdl_tpu.parallel.expert import moe_state_stats
+            experts = moe_state_stats(mstate)
         t0 = time.perf_counter()
         with trace.span("loss drain", host_sync="packed loss readback",
                         depth=depth, reason=reason,
                         first_step=pending[0]["neval"],
                         last_step=pending[-1]["neval"]):
-            losses = jax.device_get([e["loss"] for e in pending])
+            losses, experts = jax.device_get(
+                ([e["loss"] for e in pending], experts))
         share = (time.perf_counter() - t0) / depth
+        if experts:
+            trace.state("expert state", "optim", {
+                "step": pending[-1]["neval"],
+                "layers": {layer: {k: float(v) for k, v in stats.items()}
+                           for layer, stats in experts.items()}})
         # the user's summary callbacks run in here; releasing the drained
         # steps' loss arrays belongs to it (on the TPU the runtime then
         # walks the step's donated buffers: ~0.5 ms at 309 leaves)
@@ -1121,6 +1136,24 @@ class Optimizer:
                 self._emit_step(e, loss)
                 driver_state["loss"] = loss
             pending.clear()
+
+    def _publish_expert_telemetry(self, mstate) -> None:
+        """Publish the experts' routing telemetry the module state
+        HOLDS (``MoE``'s keys, ``ExpertShare``'s, or none: the state
+        decides, no flag) to the metric registry, at every epoch end and
+        at exit: ONE batched ``jax.device_get`` over every layer's
+        state leaves — the loop never pays a per-step sync for it."""
+        from bigdl_tpu.parallel.expert import publish_moe_metrics
+        try:
+            stats = publish_moe_metrics(mstate)
+        except Exception as e:    # telemetry must never break training
+            logger.debug("moe telemetry publish failed: %s", e)
+            return
+        if logger.isEnabledFor(logging.INFO):
+            for layer, vals in stats.items():
+                logger.info("moe[%s]: %s", layer, ", ".join(
+                    f"{key.removeprefix('moe_')} {val:.4g}"
+                    for key, val in vals.items()))
 
     def _resume(self, optim, params):
         """Rebuild (opt_state, rng, count_this_epoch, batches_to_skip) from
@@ -1347,10 +1380,12 @@ class Optimizer:
                                     "compiled": compiled_this_iter})
                     if len(pending) >= window:
                         self._drain_pending(pending, driver_state,
-                                            lockstep or "window full")
+                                            lockstep or "window full",
+                                            mstate)
                     driver_state["neval"] += 1
                     if count_this_epoch >= epoch_size:
-                        self._drain_pending(pending, driver_state, "epoch end")
+                        self._drain_pending(pending, driver_state,
+                                            "epoch end", mstate)
                         self._emit_input_wait_fraction(driver_state["neval"])
                         # epoch-end checkpoint barrier: pending async saves
                         # commit before the next epoch dispatches (bounds
@@ -1372,7 +1407,7 @@ class Optimizer:
                             run.place, records_scale=run.records_scale)
                         # once per epoch: one batched readback, never
                         # per-step
-                        run.publish_telemetry(mstate)
+                        self._publish_expert_telemetry(mstate)
                     fire_val, fire_ckpt = self._fires(driver_state)
                     ptree, opt_export = params, opt_state
                     if fire_val or fire_ckpt:
@@ -1381,7 +1416,8 @@ class Optimizer:
                         # module tree every iteration is pure host overhead:
                         # a tree walk on deep models)
                         self._drain_pending(pending, driver_state,
-                                            "validation/checkpoint trigger")
+                                            "validation/checkpoint trigger",
+                                            mstate)
                         with trace.span("model sync"):
                             ptree, opt_export = run.export(
                                 params, opt_state, fire_ckpt)
@@ -1394,12 +1430,12 @@ class Optimizer:
         finally:
             pipeline.close()
 
-        self._drain_pending(pending, driver_state, "training end")
+        self._drain_pending(pending, driver_state, "training end", mstate)
         # exit barrier: every handed-off checkpoint is committed (and any
         # background save error raised) before optimize() returns
         self._ckpt_shutdown(raise_errors=True)
         self._stop_profiler()
-        run.publish_telemetry(mstate)
+        self._publish_expert_telemetry(mstate)
         ptree, _ = run.export(params, opt_state, False)
         model.sync(ptree, mstate)
         model.evaluate()

@@ -467,8 +467,15 @@ def _in_chunks_bwd(chunk, rows, res, dy):
     weights, tokens, cw, where, live = res
 
     def grads(lo):
-        return jax.vjp(lambda *a: chunk(*a, where, lo=lo, rows=rows),
-                       weights, tokens, cw)[1](dy)
+        # the chunk's forward made again, and its backward: the scopes
+        # are how a trace tells recomputation done by hand from the
+        # backward products. (A custom_vjp's backward rule is named after
+        # where its FORWARD was called, so it carries both.)
+        with jax.named_scope("recompute"):
+            _, pull = jax.vjp(lambda *a: chunk(*a, where, lo=lo, rows=rows),
+                              weights, tokens, cw)
+        with jax.named_scope("pullback"):
+            return pull(dy)
 
     acc = grads(0)
     for lo in range(rows, where[0].shape[0], rows):

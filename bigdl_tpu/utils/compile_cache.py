@@ -9,6 +9,16 @@ again, so it is never a temp dir, a pid or a timestamp.
 
 A process pinned to the CPU platform is left alone — the test suite
 must not start depending on a warm cache.
+
+Wherever the cache is on, its key covers the program's METADATA too
+(``jax_compilation_cache_include_metadata_in_key``). jax leaves it out
+by default, and an executable read back under such a key carries the
+``op_name``s of whichever program was compiled first: two programs that
+differ in a ``jax.named_scope`` alone share an entry, and the
+instruction -> scope table a training loop states into a profiler
+session (``observability/tracing.ProgramScopes``) would name the other
+one's scopes. The price: an edit that moves a traced line compiles
+again.
 """
 from __future__ import annotations
 
@@ -36,6 +46,8 @@ def configure(config=None) -> str | None:
     if (config.jax_compilation_cache_dir is None
             and config.jax_platforms != "cpu"):
         config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    if config.jax_compilation_cache_dir is not None:
+        config.update("jax_compilation_cache_include_metadata_in_key", True)
     return config.jax_compilation_cache_dir
 
 

@@ -101,6 +101,13 @@ def test_compile_cache_is_placed_from_outside():
     assert compile_cache.configure(Config(None, "")) == fixed
     assert compile_cache.configure(Config(None, "tpu")) == fixed
     assert compile_cache.configure(Config(None, "cpu")) is None
+    # wherever it is on, the key covers the metadata: a scope is how a
+    # trace names the program's operations (tracing.ProgramScopes)
+    for config, on in ((Config("/outside", "cpu"), True),
+                       (Config(None, ""), True), (Config(None, "cpu"), False)):
+        compile_cache.configure(config)
+        assert getattr(config, "jax_compilation_cache_include_metadata"
+                       "_in_key", False) is on
     # the real thing, in this CPU-pinned process
     assert compile_cache.cache_dir() is None
     with open(os.path.join(REPO, ".gitignore")) as f:

@@ -896,6 +896,97 @@ def test_what_a_recomputed_block_kept_is_stated_where_it_is_traced(
     assert said["bytes"] == a_layer * CFG["num_hidden_layers"]
 
 
+def _op_names(fn, *args):
+    """Every ``op_name`` of the compiled program's text: what a trace
+    of its runs is joined to (``tracing.ProgramScopes``)."""
+    import re
+    return set(re.findall(r'op_name="([^"]*)"',
+                          jax.jit(fn).lower(*args).compile().as_text()))
+
+
+def _metric_pattern(metric, key="include"):
+    import re
+    from benchmarks import manifest
+    return re.compile(
+        manifest.data_file("layer_metrics", metric)["params"][key])
+
+
+@pytest.fixture(scope="module")
+def step_op_names(system):
+    """Of loss and gradients on the kernels, interpreted (the
+    ``kernels`` fixture's patch, for the length of one compile)."""
+    from bigdl_tpu.ops.pallas.sparse_attention import sparse_select_attention
+    model, params, state = system
+    x, t = _batch(seq=128, rows=1)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(
+            attention_mod, "sparse_select_xla",
+            lambda *a, topk, scale: (sparse_select_attention(
+                *a, topk=topk, scale=scale, interpret=True), None))
+        return _op_names(jax.grad(_loss(model, state, x, t)), params)
+
+
+def test_what_the_backward_pass_makes_again_by_hand_carries_the_mark(
+        step_op_names):
+    """``step.recompute_ms`` reads recomputation by ONE pattern:
+    ``jax.checkpoint``'s ``rematted_computation`` and the program's own
+    ``recompute`` scope at the two places where it makes a forward
+    again by hand: a chunk's forward inside ``_in_chunks_bwd`` and the
+    masked index scores in ``_core_bwd``. Nothing in the forward pass
+    carries it."""
+    mark = _metric_pattern("step.recompute_ms")
+    marked = {n for n in step_op_names if mark.search(n)}
+    # (the CPU's compiler spells a ragged product as plain ones)
+    assert any("/moe_experts/recompute/" in n
+               and n.endswith("dot_general") for n in marked)
+    assert any("/indexer/recompute/" in n for n in marked)
+    assert any("/checkpoint/rematted_computation/" in n for n in marked)
+    assert not [n for n in marked if "transpose(" not in n]
+
+
+def test_a_chunks_backward_products_do_not_carry_the_mark(step_op_names):
+    """The chunk's backward runs under ``pullback``; a custom_vjp's
+    backward rule (the combine's, the row gather's; on the TPU
+    megablox's) is named after where its FORWARD was called, so its
+    name holds ``recompute`` too, behind ``pullback``: no
+    recomputation."""
+    mark = _metric_pattern("step.recompute_ms")
+    pulled = {n for n in step_op_names if "/moe_experts/pullback/" in n}
+    assert any(n.endswith("dot_general") for n in pulled)
+    assert any("/recompute/" in n for n in pulled)
+    assert not [n for n in pulled if mark.search(n)]
+    # the indexer's and the attention's backward kernels neither
+    assert not [n for n in step_op_names if mark.search(n)
+                and ("/sparse_attention/" in n or "/indexer_loss/" in n)
+                and "rematted_computation" not in n]
+
+
+def test_the_marks_lie_inside_the_scopes_the_other_metrics_read(
+        step_op_names):
+    moe = _metric_pattern("step.moe_ms")
+    sparse = _metric_pattern("step.sparse_attention_ms")
+    backward = _metric_pattern("step.backward_ms")
+    by_hand = {n for n in step_op_names
+               if "/recompute/" in n or "/pullback/" in n}
+    assert by_hand
+    for n in by_hand:
+        assert backward.search(n), n
+        assert (moe if "/moe_experts/" in n else sparse).search(n), n
+
+
+def test_the_marks_do_not_change_the_program(system, kernels, monkeypatch):
+    import contextlib
+    model, params, state = system
+    x, t = _batch(seq=128, rows=1)
+    fn = jax.jit(jax.grad(_loss(model, state, x, t)))
+    with_scopes = fn.lower(params).as_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    assert jax.jit(jax.grad(_loss(model, state, x, t))).lower(
+        params).as_text() == with_scopes
+
+
 def test_the_layer_states_its_shapes_where_it_is_traced(system):
     from bigdl_tpu.observability import trace
     model, params, state = system

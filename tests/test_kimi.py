@@ -715,6 +715,45 @@ def test_on_the_kernels_recomputation_is_bit_identical_to_none(system,
             path))
 
 
+def test_a_chunks_forward_made_again_carries_the_mark_its_backward_not(
+        system):
+    """``step.recompute_ms``'s pattern on this model's compiled
+    ``op_name``s (tests/test_keye.py has the sparse-attention site): the
+    chunk's forward inside ``_in_chunks_bwd`` and what
+    ``jax.checkpoint`` makes again carry the mark; the chunk's backward
+    products (under ``pullback``), the forward pass and the shared
+    expert's own backward do not; both lie where ``step.moe_routed_ms``
+    and ``step.backward_ms`` read."""
+    import re
+    from benchmarks import manifest
+
+    def pattern(metric):
+        return re.compile(manifest.data_file(
+            "layer_metrics", metric)["params"]["include"])
+
+    model, params, state = system
+    x, t = _batch()
+    names = set(re.findall(r'op_name="([^"]*)"', jax.jit(jax.grad(_loss(
+        model, state, x, t))).lower(params).compile().as_text()))
+    mark = pattern("step.recompute_ms")
+    marked = {n for n in names if mark.search(n)}
+    # (the CPU's compiler spells a ragged product as plain ones)
+    assert any("/moe_experts/recompute/" in n and n.endswith("dot_general")
+               for n in marked)
+    assert any("/checkpoint/rematted_computation/" in n for n in marked)
+    assert not [n for n in marked if "transpose(" not in n]
+    pulled = {n for n in names if "/moe_experts/pullback/" in n}
+    assert any(n.endswith("dot_general") for n in pulled)
+    assert any("/recompute/" in n for n in pulled)    # a custom_vjp's rule
+    assert not pulled & marked
+    assert not [n for n in marked if "/moe_shared/" in n
+                and "rematted_computation" not in n]
+    routed, backward = pattern("step.moe_routed_ms"), pattern(
+        "step.backward_ms")
+    for n in pulled | {n for n in names if "/recompute/" in n}:
+        assert routed.search(n) and backward.search(n), n
+
+
 def test_what_the_layers_are_is_stated_where_they_are_traced(system,
                                                              on_kernels):
     from bigdl_tpu.observability import trace

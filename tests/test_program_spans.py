@@ -9,7 +9,10 @@
 - the compiled step's instruction -> scope table, which is how a TPU
   trace (device operations named by HLO instruction only) is joined to
   those scopes, reaches the session as one ``bigdl:compile:step_scopes``
-  annotation.
+  annotation, with what the compiler says the step holds (``memory``);
+- the experts' routing telemetry of the module state reaches a session
+  at every loss drain as one ``bigdl:optim:expert_state`` annotation,
+  in the drain's ONE ``device_get`` (ISSUE 35).
 
 No share of time is asserted: on the CPU a toy step is all Python.
 """
@@ -145,6 +148,153 @@ def test_step_scope_table_reaches_the_session_once(profiled_run):
     assert table["program"] == "jit_train_step"
     assert any("optimizer_update" in k for k in table["scopes"])
     assert any("jvp(model)" in k for k in table["scopes"])
+
+
+def test_the_steps_memory_rides_its_scope_table(profiled_run):
+    """Once a program: the compiler's count of what the step holds, in
+    the table the step already writes."""
+    table, = [json.loads(e[2]["long_name"]) for e in profiled_run
+              if e[1] == "bigdl:compile:step_scopes"]
+    memory = table["memory"]
+    assert set(memory) == {"arg_bytes", "output_bytes", "alias_bytes",
+                           "temp_bytes", "code_bytes", "peak_hbm_bytes"}
+    assert memory["arg_bytes"] > 0 and memory["temp_bytes"] >= 0
+    assert memory["peak_hbm_bytes"] == pytest.approx(
+        memory["arg_bytes"] + memory["output_bytes"] + memory["temp_bytes"]
+        - memory["alias_bytes"])
+
+
+# ---------------------------------------------------------------------------
+# the experts' routing, stated at a loss drain inside a session
+# ---------------------------------------------------------------------------
+
+def _drained_run(tmp, expert, session):
+    """Four steps of a toy model through ``Optimizer``: ``(what each
+    drain handed its ``jax.device_get`` calls, the session's
+    annotations)``."""
+    from bigdl_tpu.optim.optimizer import Optimizer
+    from bigdl_tpu.parallel.expert import ExpertShare
+    middle = ExpertShare(16, 8, 4, 2, experts_held=2) if expert \
+        else nn.Tanh()
+    model = nn.Sequential(nn.Linear(2, 16), middle, nn.Linear(16, 2),
+                          nn.LogSoftMax())
+    o = optim.Optimizer(model=model,
+                        dataset=array(_samples()) >> SampleToBatch(BATCH),
+                        criterion=nn.ClassNLLCriterion())
+    o.set_optim_method(optim.SGD(learning_rate=0.5))
+    o.set_end_when(optim.max_iteration(4))
+    drains, calls = [], []
+    real_get, real_drain = jax.device_get, Optimizer._drain_pending
+
+    def device_get(tree):
+        calls.append(tree)
+        return real_get(tree)
+
+    def drain(self, pending, *args):
+        if pending:
+            seen = len(calls)
+            real_drain(self, pending, *args)
+            drains.append(calls[seen:])
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "device_get", device_get)
+        patch.setattr(Optimizer, "_drain_pending", drain)
+        if session:
+            jax.profiler.start_trace(str(tmp))
+        try:
+            o.optimize()
+        finally:
+            if session:
+                jax.profiler.stop_trace()
+    return drains, _host_annotations(str(tmp)) if session else []
+
+
+@pytest.fixture(scope="module")
+def expert_run(tmp_path_factory):
+    return _drained_run(tmp_path_factory.mktemp("expert_state"),
+                        expert=True, session=True)
+
+
+def test_expert_state_is_stated_at_each_drain_inside_a_session(expert_run):
+    from bigdl_tpu.parallel.expert import SHARE_STATE_KEYS
+    _, annotations = expert_run
+    stated = [json.loads(e[2]["long_name"]) for e in annotations
+              if e[1] == "bigdl:optim:expert_state"]
+    # the state the window's LAST step left: steps 2 and 4 of 1-2, 3-4
+    assert [s["step"] for s in stated] == [2, 4]
+    for s in stated:
+        (layer, stats), = s["layers"].items()
+        assert layer == "1" and set(stats) == set(SHARE_STATE_KEYS)
+        assert stats["moe_chunks_run"] == 1.0
+        assert 0.0 <= stats["moe_product_row_share"] <= 1.0
+        assert stats["moe_held_load_max"] >= stats["moe_held_load_mean"]
+    drains = [e for e in annotations if e[1] == "bigdl:host:loss_drain"]
+    line, = {e[0] for e in drains}
+    assert all(e[0] == line for e in annotations
+               if e[1] == "bigdl:optim:expert_state")
+
+
+@pytest.mark.parametrize("expert,session", [(True, True), (True, False),
+                                            (False, True)],
+                         ids=["stated", "no-session", "no-expert-state"])
+def test_a_drain_is_one_device_get_whatever_it_states(
+        expert, session, expert_run, tmp_path):
+    """The routing telemetry rides the losses' readback; outside a
+    session, or where the state holds none, the readback is the losses'
+    alone and nothing is stated."""
+    drains, annotations = expert_run if expert and session \
+        else _drained_run(tmp_path, expert, session)
+    assert len(drains) == 2
+    for calls in drains:
+        (losses, experts), = calls             # ONE device_get a drain
+        assert len(losses) == 2
+        assert bool(experts) == (expert and session)
+    stated = [e for e in annotations
+              if e[1] == "bigdl:optim:expert_state"]
+    assert len(stated) == (2 if expert and session else 0)
+
+
+@pytest.mark.parametrize("kind", ["local", "distri"])
+def test_expert_telemetry_is_published_where_the_state_holds_it(kind,
+                                                                caplog):
+    """Neither optimizer asks for ``expert_parallel``: what the module
+    state holds decides, and the log line names the keys that are
+    there."""
+    from bigdl_tpu.observability.registry import default_registry
+    from bigdl_tpu.parallel import Engine
+    from bigdl_tpu.parallel.expert import SHARE_STATE_KEYS, ExpertShare
+    Engine.reset()
+    mesh = Engine.init(axes={"data": 8}) if kind == "distri" else None
+    try:
+        for middle in (nn.Tanh(), ExpertShare(16, 8, 4, 2, experts_held=2)):
+            gauge = default_registry().get("moe_product_row_share")
+            if gauge is not None:
+                gauge.set(-1.0, layer="1")
+            model = nn.Sequential(nn.Linear(2, 16), middle,
+                                  nn.Linear(16, 2), nn.LogSoftMax())
+            o = optim.Optimizer(
+                model=model, criterion=nn.ClassNLLCriterion(), mesh=mesh,
+                dataset=array(_samples()) >> SampleToBatch(BATCH))
+            assert not o.expert_parallel
+            o.set_optim_method(optim.SGD(learning_rate=0.5))
+            o.set_end_when(optim.max_iteration(2))
+            caplog.clear()
+            with caplog.at_level("INFO", logger="bigdl_tpu.optim"):
+                o.optimize()
+            said = [r.getMessage() for r in caplog.records
+                    if r.getMessage().startswith("moe[")]
+            if isinstance(middle, nn.Tanh):
+                assert not said
+                continue
+            assert len(said) == 1 and said[0].startswith("moe[1]: ")
+            for key in SHARE_STATE_KEYS:
+                assert key.removeprefix("moe_") in said[0], key
+            assert "dropped" not in said[0]        # ``MoE``'s keys: not here
+            share = default_registry().get("moe_product_row_share") \
+                .value(layer="1")
+            assert 0.0 <= share <= 1.0
+    finally:
+        Engine.reset()
 
 
 def test_disabled_span_is_the_bare_annotation():
@@ -333,6 +483,58 @@ def test_a_step_without_text_costs_a_warning_not_the_run(caplog):
         scopes.add(NoText())
     assert "no scope table" in caplog.text
     scopes.annotate()                       # nothing kept, nothing written
+
+
+@pytest.mark.parametrize("analysis", ["raises", "none"])
+def test_a_step_without_memory_analysis_states_null_and_warns(caplog,
+                                                              analysis):
+    """A backend (or a deserialised executable) that gives no memory
+    analysis: the table is kept, its ``memory`` is ``null`` — never
+    zeros — and that costs a warning, not the run."""
+    class NoMemory:
+        def as_text(self):
+            return HLO
+
+        def cost_analysis(self):
+            return {"flops": 1.0}
+
+        def memory_analysis(self):
+            if analysis == "raises":
+                raise NotImplementedError("no memory analysis here")
+            return None
+
+    scopes = tracing.ProgramScopes()
+    with caplog.at_level("WARNING"):
+        scopes.add(NoMemory())
+    assert "no memory analysis for compiled step jit_train_step" \
+        in caplog.text
+    table, = map(json.loads, scopes._tables)
+    assert table["memory"] is None
+    assert table["scopes"] == tracing._program_scopes(HLO)["scopes"]
+
+
+def test_memory_fields_are_the_compilers_and_the_peak_their_sum():
+    class Memory:
+        argument_size_in_bytes, output_size_in_bytes = 100, 90
+        alias_size_in_bytes, temp_size_in_bytes = 80, 40
+        generated_code_size_in_bytes = 7
+
+    class Compiled:
+        def as_text(self):
+            return HLO
+
+        def cost_analysis(self):
+            return None
+
+        def memory_analysis(self):
+            return Memory()
+
+    scopes = tracing.ProgramScopes()
+    scopes.add(Compiled())
+    table, = map(json.loads, scopes._tables)
+    assert table["memory"] == {
+        "arg_bytes": 100.0, "output_bytes": 90.0, "alias_bytes": 80.0,
+        "temp_bytes": 40.0, "code_bytes": 7.0, "peak_hbm_bytes": 150.0}
 
 
 # ---------------------------------------------------------------------------
