@@ -417,57 +417,76 @@ _combine.defvjp(lambda out, cw, idx, at: (_combine(out, cw, idx, at),
                                           (out, cw, idx, at)), _combine_bwd)
 
 
-def _chunk_rows(assignments: int, held: int, total: int) -> int:
-    """Sorted assignment rows worked on at a time: FOUR times what lands
-    here when the router is balanced (``assignments held / total``), as
-    the nearest whole division of ``assignments``. Four, because an
-    untrained router sends every token to the same k experts (PERF.md
-    section 6, PR 31): the share that lands here is then j / k with j of
-    them held, and j > 4 of 8 has 7 chances in 10000 at 16 of 128; at 8
-    of 64 and k = 6 (two chunks, each HALF of all T k rows) the second
-    chunk is reached at j > 3 of 6, 15 chances in 10000 (a sigmoid
-    router orders the experts as a softmax does, so it collapses
-    alike). The grouped products' work follows the rows the held experts
-    were sent, but the gather of a chunk's rows, the pass between the
-    products and the combine's backward are the chunk's size whatever
-    it holds: chunks of TWICE the balanced share were timed on the chip
-    (dev/expert_chunks.py; PERF.md section 6, PR 34) and run the layer
-    faster at a balanced router and at a collapsed one, but every chunk
-    is a body of its own in the compiled step (a ``lax.cond`` each, no
-    loop), eleven grouped-product kernels a body: twice the bodies made
-    the step's executable a quarter larger and a warm start 15% longer,
-    more than the benchmark's bound on ``setup_s`` allows. Four stays
-    until a chunk's body holds fewer kernels (ROADMAP A10)."""
-    most = max(1, total // (4 * held))
+def _chunk_rows(assignments: int, held: int, total: int,
+                self_balancing: bool) -> int:
+    """The FIRST span of the sorted assignment rows, the one every step
+    works on: TWICE what lands here when the router is balanced
+    (``assignments held / total``) where the router holds itself
+    balanced (``self_balancing``: a selection bias that every step
+    updates), FOUR times where nothing does, as the nearest whole
+    division of ``assignments``. The rows past it are ONE second span,
+    worked on only when the live rows pass the first (``_in_chunks``): at
+    most two spans, so two bodies in the compiled step (a ``lax.cond``,
+    eleven grouped-product kernels a body, no loop) whatever the first
+    one's size — equal chunks of twice the share made four bodies, a
+    quarter more executable and a warm start 15% longer (PERF.md section
+    6, PR 34). With every expert held, or where the multiple of the share
+    covers all rows, the first span is all of them.
+
+    Twice, because the grouped products' work follows the rows the held
+    experts were sent, but the gather of a span's rows, the pass between
+    the products and the combine's backward are the span's size whatever
+    it holds: at a balanced router the live rows are 1.0 x the share
+    (PERF.md section 6, PRs 34 and 36: the layer runs a third faster
+    than at four times). Not less, because a spill costs the WHOLE second
+    span and what every span pays whatever its size (k gathers of T rows
+    out and back): more than the first span itself.
+
+    Four times where nothing holds the router balanced, because then the
+    step's TIME would follow the router's state. An untrained router
+    sends every token to the same k experts (PERF.md section 6, PR 31):
+    the share that lands here is j / k with j of them held, and twice
+    the share is passed at j > 2 of 8, 6.1% of the draws at 16 of 128
+    when the k are drawn without favour. The keye cell's router passed
+    it in 4-16% of layer-steps by seed, a step with 0 | 1 | 2 spills took
+    985 | 1017 | 1050 ms and the cell's runs spread 4% where they had
+    spread 0.9% (PERF.md section 6, PR 36); four times the share is
+    passed at j > 4, 7 draws in 10000, and never was in that cell. (A
+    sigmoid router orders the experts as a softmax does and collapses
+    alike, at 8 of 64 and k = 6 past twice the share at j > 1, 15.9%:
+    what keeps it from that is the bias, which is why the bias and not
+    the scoring decides.)"""
+    most = max(1, total // ((2 if self_balancing else 4) * held))
     return assignments // max(n for n in range(1, most + 1)
                               if assignments % n == 0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _in_chunks(chunk, rows, weights, tokens, cw, where, live):
-    """``sum over lo = 0, rows, 2 rows, ... of chunk(weights, tokens, cw,
-    where, lo=lo, rows=rows)``: the chunk at 0 always, a later one only
-    when ``live > lo`` (a ``lax.cond`` each). It keeps its arguments and
-    nothing else: the backward pass recomputes each chunk that ran and
-    adds its gradients into ONE set of accumulators handed through the
-    ``cond``s, where autodiff of the ``cond``s would return a
-    parameter-sized set of zeros from every chunk that did not run."""
-    total = where[0].shape[0]
-    y = chunk(weights, tokens, cw, where, lo=0, rows=rows)
-    for lo in range(rows, total, rows):
+def _in_chunks(chunk, first, weights, tokens, cw, where, live):
+    """``chunk(weights, tokens, cw, where, lo=0, rows=first)`` always,
+    plus the ONE span of all the rows past ``first`` when ``live >
+    first`` (a ``lax.cond``). It keeps its arguments and nothing else:
+    the backward pass recomputes each span that ran and adds its
+    gradients into ONE set of accumulators handed through the ``cond``,
+    where autodiff of the ``cond`` would return a parameter-sized set of
+    zeros from a span that did not run."""
+    rest = where[0].shape[0] - first
+    y = chunk(weights, tokens, cw, where, lo=0, rows=first)
+    if rest:
         y = jax.lax.cond(
-            live > lo,
-            lambda y, lo=lo: y + chunk(weights, tokens, cw, where, lo=lo,
-                                       rows=rows),
+            live > first,
+            lambda y: y + chunk(weights, tokens, cw, where, lo=first,
+                                rows=rest),
             lambda y: y, y)
     return y
 
 
-def _in_chunks_bwd(chunk, rows, res, dy):
+def _in_chunks_bwd(chunk, first, res, dy):
     weights, tokens, cw, where, live = res
+    rest = where[0].shape[0] - first
 
-    def grads(lo):
-        # the chunk's forward made again, and its backward: the scopes
+    def grads(lo, rows):
+        # the span's forward made again, and its backward: the scopes
         # are how a trace tells recomputation done by hand from the
         # backward products. (A custom_vjp's backward rule is named after
         # where its FORWARD was called, so it carries both.)
@@ -477,17 +496,17 @@ def _in_chunks_bwd(chunk, rows, res, dy):
         with jax.named_scope("pullback"):
             return pull(dy)
 
-    acc = grads(0)
-    for lo in range(rows, where[0].shape[0], rows):
+    acc = grads(0, first)
+    if rest:
         acc = jax.lax.cond(
-            live > lo,
-            lambda acc, lo=lo: jax.tree.map(jnp.add, acc, grads(lo)),
+            live > first,
+            lambda acc: jax.tree.map(jnp.add, acc, grads(first, rest)),
             lambda acc: acc, acc)
     return (*acc, None, None)
 
 
 _in_chunks.defvjp(
-    lambda chunk, rows, *args: (_in_chunks(chunk, rows, *args), args),
+    lambda chunk, first, *args: (_in_chunks(chunk, first, *args), args),
     _in_chunks_bwd)
 
 
@@ -504,21 +523,23 @@ class ExpertShare(_Module):
 
     A token none of whose experts live here gets zero. DROPLESS: there
     is no capacity. The T k assignments are sorted by expert, those for
-    experts elsewhere last, and the sorted rows are worked on a CHUNK at
-    a time (``_chunk_rows``: four times the balanced share), each through
+    experts elsewhere last, and the sorted rows are worked on as at most
+    TWO spans (``_chunk_rows``: the first is twice the balanced share
+    where a ``bias_update_rate`` holds the router balanced and four
+    times where nothing does, the second all the rest), each through
     grouped matrix products over ragged groups (``grouped_matmul``),
-    forward and backward. The first chunk always runs; a later one runs
-    only when the assignments that land here reach it (``_in_chunks``: a
-    ``lax.cond`` each, every chunk recomputed in the backward pass), so
-    one expert may take every token and the step is sized for the
-    routing it meets, not for the worst. A chunk's rows for experts
-    elsewhere are the products' LAST group, the one no matrix here is
-    for: they cost no product work and come out zeros (their combine
-    weight is zero too), so the products' work is the rows the held
-    experts were sent, forward and backward; the gather of a chunk's
-    rows, the pass between the products and the combine are still a
-    chunk's size. With ``experts_held ==
-    experts_total`` it is the whole layer in one chunk. On one chip it
+    forward and backward. The first
+    span always runs; the second runs only when the assignments that
+    land here pass the first (``_in_chunks``: one ``lax.cond``, each
+    span that ran recomputed in the backward pass), so one expert may
+    take every token and the step is sized for the routing it meets, not
+    for the worst. A span's rows for experts elsewhere are the products'
+    LAST group, the one no matrix here is for: they cost no product work
+    and come out zeros (their combine weight is zero too), so the
+    products' work is the rows the held experts were sent, forward and
+    backward; the gather of a span's rows, the pass between the products
+    and the combine are still a span's size. With ``experts_held ==
+    experts_total`` it is the whole layer in one span. On one chip it
     runs without an exchange and nothing here stands in for the absent
     chips: summed over the shares of a layer, the results are the uncut
     layer's (tests/test_keye.py).
@@ -536,10 +557,10 @@ class ExpertShare(_Module):
     busiest held expert, and of the mean one),
     ``moe_local_assignment_share`` (assignments landing here over all
     T k), ``moe_tokens_without_local`` (share of tokens that get
-    zero), ``moe_chunks_run`` (chunks of sorted rows the step worked
-    on) and ``moe_product_row_share`` (rows in the held experts' groups
-    over the rows of those chunks: the part of a chunk the grouped
-    products multiply).
+    zero), ``moe_chunks_run`` (spans of sorted rows the step worked
+    on: 1, or 2 when the second ran) and ``moe_product_row_share`` (rows
+    in the held experts' groups over the rows of those spans: the part
+    of them the grouped products multiply).
 
     The router's variants (``route_top_k``): ``scoring`` ``"softmax"``
     or ``"sigmoid"``; ``route_scale`` multiplies the normalised weights
@@ -662,14 +683,16 @@ class ExpertShare(_Module):
                              f"{self.d_model}, got feature dim {d}")
         tokens = x.reshape(-1, d)
         t = tokens.shape[0]
-        rows = _chunk_rows(t * k, held, self.experts_total)
+        first = _chunk_rows(t * k, held, self.experts_total,
+                            self.bias_update_rate is not None)
         # python runs this when the layer is traced for a compile, never
         # in a step
         trace.instant("moe_share", cat="nn", experts_total=self.experts_total,
                       experts_held=held, top_k=k, tokens=t,
                       expected_local_assignments=t * k * held
-                      / self.experts_total, chunk_rows=rows,
-                      chunks=t * k // rows, scoring=self.scoring,
+                      / self.experts_total, chunk_rows=first,
+                      chunks=1 + (first < t * k), rest_rows=t * k - first,
+                      scoring=self.scoring,
                       shared_width=self.shared.d_ff if self.shared else 0,
                       bias_update_rate=self.bias_update_rate or 0.0)
         bias = state.get(BIAS_STATE_KEY)
@@ -689,7 +712,7 @@ class ExpertShare(_Module):
             cd = compute_dtype()
             # the experts' matrices in the compute dtype, made ONCE
             y = _in_chunks(
-                self._chunk, rows,
+                self._chunk, first,
                 tuple(params[name].astype(cd) for name in
                       ("gate_weight", "up_weight", "down_weight")),
                 tokens.astype(cd), jnp.where(here, c, 0.0),
@@ -700,17 +723,18 @@ class ExpertShare(_Module):
                     .astype(jnp.float32)
         f32 = jnp.float32
         loads = counts.astype(f32)
-        # the chunk at 0 always runs, the one at ``lo`` when live > lo
+        # the first span always runs, the rest when live > first
         live = starts[held]
-        chunks = jnp.maximum(1, -(-live // rows))
+        spilled = live > first
         stats = {
             "moe_held_load_max": jnp.max(loads),
             "moe_held_load_mean": jnp.mean(loads),
             "moe_local_assignment_share": jnp.sum(loads) / (t * k),
             "moe_tokens_without_local":
                 1.0 - jnp.mean(jnp.any(here, axis=-1), dtype=f32),
-            "moe_product_row_share": live / (chunks * rows),
-            "moe_chunks_run": chunks.astype(f32),
+            "moe_product_row_share":
+                live / jnp.where(spilled, t * k, first),
+            "moe_chunks_run": 1.0 + spilled.astype(f32),
         }
         if bias is not None:
             with jax.named_scope("moe_router"):

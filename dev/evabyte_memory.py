@@ -89,7 +89,8 @@ def main():
               f"{m.temp_size_in_bytes / 1e9:.2f}; arguments + outputs - "
               f"aliased + temporaries "
               f"{(held + m.temp_size_in_bytes) / 1e9:.2f}"
-              f" GB (compiled in {time.time() - t:.0f} s)", flush=True)
+              f" GB; code {m.generated_code_size_in_bytes / 1e6:.1f} MB "
+              f"(compiled in {time.time() - t:.0f} s)", flush=True)
         return compiled
 
     only = args.only.split(",")
@@ -108,6 +109,9 @@ def main():
                 f.write(text)
         # a producer fused into a matmul's operand is evaluated again on
         # every pass over it (PERF.md section 6, PR 28)
+        kernels = text.count('custom_call_target="tpu_custom_call"')
+        print(f"step: {kernels} Pallas custom calls, "
+              f"{text.count(' conditional(')} conditionals", flush=True)
         fed = matmuls_fed_by(text, "exponential")
         backward = sum("transpose(" in scope for scope in fed.values())
         print(f"step: {len(fed)} matmul fusions read an exponential "

@@ -1,26 +1,37 @@
-"""What a chunk of ``parallel.expert.ExpertShare`` should be, on the chip:
+"""Where ``parallel.expert.ExpertShare`` should cut its sorted rows, on
+the chip:
 
     chiprun -- python3 dev/expert_chunks.py
 
 The layer alone (no shared expert), forward + backward in one jitted
 ``value_and_grad`` as a recomputed block's step runs it, at the two
-expert cells' shapes and dtype policy, for each way ``--divisions`` cuts
-the T k sorted assignment rows into chunks, under the routings the cells
+expert cells' shapes and dtype policy, under the routings the cells
 meet:
 
 - ``balanced``: random router, unit-variance tokens (the kimi cell);
 - ``collapsed j``: every token picks the SAME k experts, j of them held
-  (the keye cell at random weights: the live rows are j T), weighted by
-  the chance of each j when k of ``experts_total`` are drawn.
+  (the keye cell at random weights: the live rows are j T), EACH j with
+  its time and its chance when k of ``experts_total`` are drawn, and the
+  mean weighted by chance;
 
-Beside each time: ``moe_chunks_run`` and ``moe_product_row_share`` as
-the layer's state gives them. ``--parent`` also times, at the module's
-own chunk, the layer with the rows for experts elsewhere put into the
-last held expert's group (commit 0aa00c8's products over every row of a
-chunk). PERF.md section 6 (PR 34) quotes it; the numbers also go to
+for each schedule:
+
+- ``two spans``: the module's (PR 36): a first span of T k / n rows for
+  each n of ``--divisions`` (the module's own rule among them) and ONE
+  second span of the rest, run when the live rows pass the first: two
+  bodies in the compiled step whatever n;
+- ``equal chunks``: the module's until PR 36, kept HERE alone: T k / n
+  rows each for each n of ``--equal``, a body each (n = 4 is the twice-
+  the-share schedule PR 34 timed and left out for its four bodies).
+
+Beside each time: the spans or chunks run and the live rows' share of
+their rows. ``--parent`` also times, at the module's own cut, the layer
+with the rows for experts elsewhere put into the last held expert's
+group (commit 0aa00c8's products over every row of a span). PERF.md
+section 6 (PRs 34, 36) quotes it; the numbers also go to
 ``chiprun_out/expert_chunks.json``. Refuses to run without a TPU;
 ``--rehearsal`` walks the same code at the data files' tiny widths on
-any backend and is never a result.
+any backend and is never a result (the JSON says ``rehearsal``).
 """
 from __future__ import annotations
 
@@ -72,7 +83,47 @@ def _chance(j, held, total, k):
         / math.comb(total, k)
 
 
-def cell_alone(name, divisions, parent, rehearsal):
+def _equal_chunks():
+    """``_in_chunks`` as it was until PR 36: EQUAL chunks of ``rows``
+    sorted rows, the first always, each later one behind a ``lax.cond``
+    of its own (a body each in the compiled step)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+    def in_chunks(chunk, rows, weights, tokens, cw, where, live):
+        y = chunk(weights, tokens, cw, where, lo=0, rows=rows)
+        for lo in range(rows, where[0].shape[0], rows):
+            y = jax.lax.cond(
+                live > lo,
+                lambda y, lo=lo: y + chunk(weights, tokens, cw, where, lo=lo,
+                                           rows=rows),
+                lambda y: y, y)
+        return y
+
+    def bwd(chunk, rows, res, dy):
+        weights, tokens, cw, where, live = res
+
+        def grads(lo):
+            return jax.vjp(lambda *a: chunk(*a, where, lo=lo, rows=rows),
+                           weights, tokens, cw)[1](dy)
+
+        acc = grads(0)
+        for lo in range(rows, where[0].shape[0], rows):
+            acc = jax.lax.cond(
+                live > lo,
+                lambda acc, lo=lo: jax.tree.map(jnp.add, acc, grads(lo)),
+                lambda acc: acc, acc)
+        return (*acc, None, None)
+
+    in_chunks.defvjp(lambda chunk, rows, *args: (
+        in_chunks(chunk, rows, *args), args), bwd)
+    return in_chunks
+
+
+def cell_alone(name, divisions, equal, parent, rehearsal):
     import jax
     import jax.numpy as jnp
     from benchmarks import manifest, model_setup
@@ -88,9 +139,12 @@ def cell_alone(name, divisions, parent, rehearsal):
     params = layer.init(jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1),
                           (1, tokens, cfg["hidden_size"]), jnp.float32)
-    own = expert._chunk_rows(tokens * k, held, total)
+    all_rows = tokens * k
+    own = expert._chunk_rows(all_rows, held, total,
+                             layer.bias_update_rate is not None)
     print(f"{name}: {tokens} tokens, {held} of {total} experts, {k} a token, "
-          f"{tokens * k} sorted rows, the module's chunk {own}", flush=True)
+          f"{all_rows} sorted rows, the module's first span {own}",
+          flush=True)
 
     def loss(p, x):
         y, state = layer.apply(p, layer.init_state(), x, training=True)
@@ -107,60 +161,73 @@ def cell_alone(name, divisions, parent, rehearsal):
         routings.append((f"collapsed {j}", p, steered,
                          _chance(j, held, total, k)))
     found = []
-    chunks = sorted({tokens * k // n for n in divisions
-                     if tokens * k % n == 0} | {own}, reverse=True)
     products = [("", expert.grouped_matmul)]
     if parent:
         def riders(x, w, group_sizes, real=expert.grouped_matmul):
             return real(x, w, group_sizes.at[held - 1].add(group_sizes[held])
                         .at[held].set(0))
         products.append((" (products over every row)", riders))
-    real_rule = expert._chunk_rows
-    for rows in chunks:
-        for said, product in products if rows == own else products[:1]:
-            expert._chunk_rows = lambda *a, rows=rows: rows
-            expert.grouped_matmul = product
-            both = jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
-                                              has_aux=True))
-            expected = 0.0
-            print(f"  chunks of {rows} rows "
-                  f"({rows * total / (tokens * k * held):.2f} x the balanced "
-                  f"share){said}", flush=True)
-            for what, p, tok, chance in routings:
-                state = jax.device_get(both(p, tok)[0][1])
-                ms = _ms(both, p, tok)
-                if what != "balanced":
-                    expected += chance * ms
-                found.append(dict(
-                    cell=name, chunk_rows=rows, routing=what,
-                    products_over_every_row=bool(said), ms=ms,
-                    chance=chance, **{key: float(state[key]) for key in (
-                        "moe_local_assignment_share", "moe_chunks_run",
-                        "moe_product_row_share")}))
-                print(f"    {what:12s}: forward+backward {ms:8.3f} ms, live "
-                      f"share {float(state['moe_local_assignment_share']):.4f}"
-                      f", chunks run {float(state['moe_chunks_run']):.0f}, "
-                      f"rows multiplied / rows of those chunks "
-                      f"{float(state['moe_product_row_share']):.4f}"
-                      + ("" if what == "balanced"
-                         else f", chance {chance:.4f}"),
-                      flush=True)
-            print(f"    collapsed, weighted by chance: {expected:8.3f} ms",
-                  flush=True)
-            found.append(dict(cell=name, chunk_rows=rows,
-                              routing="collapsed, weighted",
-                              products_over_every_row=bool(said),
-                              ms=expected))
-    expert._chunk_rows, expert.grouped_matmul = real_rule, products[0][1]
+    schedules = [("two spans", rows, said, product)
+                 for rows in sorted({all_rows // n for n in divisions
+                                     if all_rows % n == 0} | {own},
+                                    reverse=True)
+                 for said, product in (products if rows == own
+                                       else products[:1])]
+    schedules += [("equal chunks", all_rows // n, *products[0])
+                  for n in equal if all_rows % n == 0]
+    real_rule, real_cut = expert._chunk_rows, expert._in_chunks
+    for schedule, rows, said, product in schedules:
+        expert._chunk_rows = lambda *a, rows=rows: rows
+        expert._in_chunks = real_cut if schedule == "two spans" \
+            else _equal_chunks()
+        expert.grouped_matmul = product
+        both = jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))
+        expected = 0.0
+        which = "the first" if schedule == "two spans" else "each"
+        print(f"  {schedule}, {which} {rows} rows "
+              f"({rows * total / (all_rows * held):.2f} x the balanced "
+              f"share){said}", flush=True)
+        for what, p, tok, chance in routings:
+            state = jax.device_get(both(p, tok)[0][1])
+            ms = _ms(both, p, tok)
+            live = round(float(state["moe_local_assignment_share"])
+                         * all_rows)
+            # the module's two keys describe ITS cut: count the equal
+            # chunks here
+            ran = max(1, -(-live // rows)) if schedule == "equal chunks" \
+                else int(state["moe_chunks_run"])
+            worked_on = ran * rows if schedule == "equal chunks" \
+                else (all_rows if ran == 2 else rows)
+            if what != "balanced":
+                expected += chance * ms
+            found.append(dict(
+                cell=name, schedule=schedule, chunk_rows=rows, routing=what,
+                products_over_every_row=bool(said), ms=ms, chance=chance,
+                live_rows=live, ran=ran, rows_worked_on=worked_on))
+            print(f"    {what:12s}: forward+backward {ms:8.3f} ms, live "
+                  f"rows {live}, ran {ran}, live / rows worked on "
+                  f"{live / worked_on:.4f}"
+                  + ("" if what == "balanced"
+                     else f", chance {chance:.4f}"), flush=True)
+        print(f"    collapsed, weighted by chance: {expected:8.3f} ms",
+              flush=True)
+        found.append(dict(cell=name, schedule=schedule, chunk_rows=rows,
+                          routing="collapsed, weighted",
+                          products_over_every_row=bool(said), ms=expected))
+    expert._chunk_rows, expert._in_chunks = real_rule, real_cut
+    expert.grouped_matmul = products[0][1]
     return found
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--cells", default=",".join(CELLS))
-    ap.add_argument("--divisions", default="2,4,6,8",
-                    help="chunks the T k rows are cut into (those that "
-                         "divide them)")
+    ap.add_argument("--divisions", default="2,4,8",
+                    help="two spans: the first is T k / n rows, for each "
+                         "n that divides them")
+    ap.add_argument("--equal", default="4",
+                    help="equal chunks: n of T k / n rows each")
     ap.add_argument("--parent", action="store_true")
     ap.add_argument("--rehearsal", action="store_true")
     args = ap.parse_args()
@@ -169,7 +236,8 @@ def main():
         sys.exit("dev/expert_chunks.py: no TPU")
     found = []
     for name in args.cells.split(","):
-        found += cell_alone(name, [int(n) for n in args.divisions.split(",")],
+        found += cell_alone(name, *([int(n) for n in ns.split(",") if n]
+                                    for ns in (args.divisions, args.equal)),
                             args.parent, args.rehearsal)
     out = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "chiprun_out")

@@ -51,7 +51,8 @@ def layer_alone(cfg, tokens):
     x = jax.random.normal(jax.random.PRNGKey(1),
                           (1, tokens, cfg["hidden_size"]), jnp.float32)
     x = x.at[..., 0].set(4.0)                 # the router's bias rides it
-    rows = expert._chunk_rows(tokens * k, held, total)
+    rows = expert._chunk_rows(tokens * k, held, total,
+                              layer.bias_update_rate is not None)
 
     def loss(p, x):
         y, state = layer.apply(p, layer.init_state(), x, training=True)
@@ -60,14 +61,14 @@ def layer_alone(cfg, tokens):
     both = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))
     fwd = jax.jit(lambda p, x: layer.apply(p, layer.init_state(), x)[0])
     print(f"ExpertShare alone: {tokens} tokens, {held} of {total} experts, "
-          f"{k} a token, chunks of {rows} sorted rows", flush=True)
+          f"{k} a token, a first span of {rows} sorted rows", flush=True)
     for bias in (-0.5, 0.0, 0.1, 0.2, 0.3, 0.5, 1.0):
         p = dict(params, router_weight=params["router_weight"].at[
             off:off + held, 0].set(bias))
         state = both(p, x)[0][1]
         share = float(state["moe_local_assignment_share"])
         print(f"  bias {bias:5.2f}: share here {share:.4f} "
-              f"({share * tokens * k / rows:.2f} chunks), busiest expert "
+              f"({share * tokens * k / rows:.2f} first spans), busiest expert "
               f"{float(state['moe_held_load_max']):.0f} rows, forward "
               f"{_ms(fwd, p, x):7.2f} ms, forward+backward "
               f"{_ms(both, p, x):7.2f} ms", flush=True)

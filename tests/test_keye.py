@@ -476,19 +476,26 @@ def test_a_token_with_no_expert_here_gets_zero_and_telemetry_says_so():
     assert float(expert_mod.moe_aux_total({"blk": {"1": state}})) == 0.0
 
 
+def _spans_run(live, first, total):
+    """(``moe_chunks_run``, the rows of the spans that ran): the first
+    span always, all ``total`` rows once the live ones pass it."""
+    return (2, total) if live > first else (1, first)
+
+
 @pytest.mark.parametrize("rows", [16, 64, 128])
 def test_chunks_of_sorted_rows_are_the_same_layer(monkeypatch, rows):
-    """The 256 sorted assignments a chunk of ``rows`` at a time — the
-    first always, the others behind a ``lax.cond`` and recomputed in the
-    backward pass, here with ~64 landing on the share so that some
-    chunks run and some do not: the same result, gradients (the
-    input's too) and telemetry as all 256 rows in one chunk — but for
-    the two keys that say how the rows were chunked (PR 34), which must
-    be the chunks the live rows reach and their share of those."""
+    """The 256 sorted assignments as a first span of ``rows`` and ONE
+    second span of the rest behind a ``lax.cond``, each span that ran
+    recomputed in the backward pass, here with ~62 landing on the share
+    so that the second span runs (16) or does not (64, 128): the same
+    result, gradients (the input's too) and telemetry as all 256 rows in
+    one span — but for the two keys that say how the rows were cut
+    (PR 34), which must be the spans that ran and the live rows' share
+    of those."""
     x = jax.random.normal(jax.random.PRNGKey(6), (64, D))
 
-    def run(chunk):
-        monkeypatch.setattr(expert_mod, "_chunk_rows", lambda *a: chunk)
+    def run(first):
+        monkeypatch.setattr(expert_mod, "_chunk_rows", lambda *a: first)
         layer, mine = _share(4, 4)
         (loss, state), grads = jax.value_and_grad(
             lambda p, x: (lambda y, st: (jnp.sum(y ** 2), st))(
@@ -497,15 +504,16 @@ def test_chunks_of_sorted_rows_are_the_same_layer(monkeypatch, rows):
         return loss, state, grads
 
     (a, sa, ga), (b, sb, gb) = run(256), run(rows)
-    assert 16 < float(sa["moe_local_assignment_share"]) * 256 < 128
+    assert 16 < float(sa["moe_local_assignment_share"]) * 256 < 64
     assert abs(float(a) - float(b)) < 1e-5 * abs(float(a))
     sa, sb = jax.tree.map(float, sa), jax.tree.map(float, sb)
     live = round(sa["moe_local_assignment_share"] * 256)
-    for state, each in ((sa, 256), (sb, rows)):
-        ran = -(-live // each)
+    for state, first in ((sa, 256), (sb, rows)):
+        ran, worked_on = _spans_run(live, first, 256)
+        assert ran == (2 if first == 16 else 1)
         assert state.pop("moe_chunks_run") == ran
         assert state.pop("moe_product_row_share") == pytest.approx(
-            live / (ran * each))
+            live / worked_on)
     assert sa == sb
     for name in ga[0]:
         assert _rel(ga[0][name], gb[0][name]) < 1e-5, name
@@ -539,25 +547,28 @@ def _riders_in_the_last_held_group(held, grouped=expert_mod.grouped_matmul):
 
 
 @pytest.mark.parametrize("which", ["wholly live", "partly live",
-                                   "past live"])
+                                   "past live", "all the rest"])
 def test_a_chunks_products_get_the_held_groups_and_the_rest_apart(
         monkeypatch, which):
     """What ``_chunk`` hands ``grouped_matmul``: ``held`` groups that
-    sum to the chunk's LIVE rows, each the held expert's rows that fall
-    into the chunk, and a last group that is the rest of it — so the
+    sum to the span's LIVE rows, each the held expert's rows that fall
+    into the span, and a last group that is the rest of it — so the
     products' work follows the rows the held experts were sent. For a
-    chunk wholly live, partly live and past ``live`` (nothing but the
-    last group), all three products alike."""
-    rows = 32
-    monkeypatch.setattr(expert_mod, "_chunk_rows", lambda *a: rows)
+    span wholly live, partly live and past ``live`` (nothing but the
+    last group), and for the second span as ``_in_chunks`` cuts it (all
+    the rows past the first 32), all three products alike."""
+    first = 32
+    monkeypatch.setattr(expert_mod, "_chunk_rows", lambda *a: first)
     layer, mine = _share(4, 4)
     x = jax.random.normal(jax.random.PRNGKey(6), (64, D))
     _, weights, tokens, cw, where, live = _what_the_chunks_are_handed(
         monkeypatch, layer, mine, x)
     live = int(live)
-    assert rows < live < 256 - rows and live % rows
-    lo = {"wholly live": 0, "partly live": live // rows * rows,
-          "past live": 256 - rows}[which]
+    assert first < live < 256 - first and live % first
+    lo, rows = {"wholly live": (0, first),
+                "partly live": (live // first * first, first),
+                "past live": (256 - first, first),
+                "all the rest": (first, 256 - first)}[which]
     handed = []
     real = expert_mod.grouped_matmul
 
@@ -576,7 +587,8 @@ def test_a_chunks_products_get_the_held_groups_and_the_rest_apart(
     in_live = min(max(live - lo, 0), rows)
     assert want[:4].sum() == in_live and want[4] == rows - in_live
     assert {"wholly live": in_live == rows, "partly live": 0 < in_live < rows,
-            "past live": in_live == 0}[which]
+            "past live": in_live == 0,
+            "all the rest": in_live == live - first}[which]
     assert (float(jnp.abs(y).max()) == 0.0) == (which == "past live")
 
 
@@ -588,7 +600,8 @@ def test_rows_that_cost_no_product_give_the_parents_layer(monkeypatch,
     every gradient — the experts', the router's, the input's, and the
     combine weights' taken alone — equal what the layer gave when the
     rows for experts elsewhere rode in the last held expert's group
-    (weight zero), over eight chunks of which two run. Only a weight
+    (weight zero), over a first span of 32 rows and the second of 224,
+    which the ~62 live rows reach. Only a weight
     that is zero because its expert is elsewhere had a gradient then
     (the row's finite garbage, which ``apply``'s ``where`` discarded)
     and has an exact zero now."""
@@ -628,15 +641,16 @@ def test_rows_that_cost_no_product_give_the_parents_layer(monkeypatch,
     (32, "none held is chosen")])
 def test_telemetry_says_what_part_of_the_chunks_was_multiplied(
         monkeypatch, rows, routing):
-    """``moe_chunks_run``: the chunk at 0 and every later one the live
-    rows reach; ``moe_product_row_share``: the live rows over the rows
-    of those chunks (about a quarter where the chunk is four times the
-    balanced share and the router is even; 0 when no chosen expert is
-    held). ``moe_state_stats`` reads both."""
+    """``moe_chunks_run``: 1, or 2 when the live rows pass the first
+    span and the second runs; ``moe_product_row_share``: the live rows
+    over the rows of the spans that ran (about a quarter where the first
+    span is four times the balanced share, as this router's is with no
+    bias to balance it, and the router is even; 0 when no chosen expert
+    is held). ``moe_state_stats`` reads both."""
     if rows is not None:
         monkeypatch.setattr(expert_mod, "_chunk_rows", lambda *a: rows)
     layer, mine = _share(2, 8)
-    rows = rows or expert_mod._chunk_rows(64 * TOP, 2, TOTAL)
+    rows = rows or expert_mod._chunk_rows(64 * TOP, 2, TOTAL, False)
     x = jax.random.normal(jax.random.PRNGKey(5), (64, D))
     steer = {"as it falls": None, "one expert takes all": 4.0,
              "none held is chosen": -40.0}[routing]
@@ -647,17 +661,17 @@ def test_telemetry_says_what_part_of_the_chunks_was_multiplied(
     y, state = layer.apply(mine, layer.init_state(), x)
     top, _ = layer.route(mine, x)
     live = int(((top >= 8) & (top < 10)).sum())
-    chunks = max(1, -(-live // rows))
+    chunks, worked_on = _spans_run(live, rows, 64 * TOP)
     assert float(state["moe_chunks_run"]) == chunks
     assert float(state["moe_product_row_share"]) == pytest.approx(
-        live / (chunks * rows))
+        live / worked_on)
     if routing == "as it falls":
         assert 0.5 * 32 < live < 1.5 * 32       # 2 of 16 held: an eighth
+        assert chunks == (2 if rows == 32 and live > 32 else 1)
         if rows == 128:                         # four times that
-            assert chunks == 1
             assert 0.125 < float(state["moe_product_row_share"]) < 0.375
     elif routing == "one expert takes all":
-        assert live >= 64 and chunks >= 2
+        assert live >= 64 and chunks == 2
     else:
         assert live == 0 and chunks == 1
         assert float(state["moe_product_row_share"]) == 0.0
@@ -671,26 +685,129 @@ def test_no_loop_encloses_the_experts():
     """A ``while`` around the experts is ONE device operation that spans
     its body's, and the benchmark's scope readers would count both
     (PERF.md section 7, PR 31): forward and backward hold none, and one
-    ``cond`` for each chunk after the first."""
+    ``cond`` each for the second span."""
     layer, mine = _share(2, 4)
     x = jax.random.normal(jax.random.PRNGKey(6), (64, D))
     text = str(jax.make_jaxpr(jax.grad(lambda p: jnp.sum(layer.apply(
         p, layer.init_state(), x)[0] ** 2)))(mine))
     assert "while" not in text and "scan" not in text
-    assert expert_mod._chunk_rows(64 * TOP, 2, TOTAL) == 128
-    assert text.count(" cond[") == 2       # forward, backward: chunk 2
+    assert expert_mod._chunk_rows(64 * TOP, 2, TOTAL, False) == 128
+    assert text.count(" cond[") == 2       # forward, backward: span 2
 
 
-def test_a_chunk_is_four_times_the_balanced_share():
-    """16 of 128 experts, 8 a token, 16384 tokens: two chunks of 65536
-    rows (16384 land here when the router is balanced); the whole layer
-    is one chunk; an odd count of assignments divides as far as it
-    can."""
-    assert expert_mod._chunk_rows(16384 * 8, 16, 128) == 65536
-    assert expert_mod._chunk_rows(16384 * 8, 128, 128) == 16384 * 8
-    assert expert_mod._chunk_rows(9 * 8, 2, 16) == 36
-    assert expert_mod._chunk_rows(7, 1, 16) == 7
-    assert expert_mod._chunk_rows(9, 1, 16) == 3
+def test_the_lowered_layer_holds_two_span_bodies(monkeypatch, kernel_calls):
+    """Every span is a body of its own in the compiled step, and a
+    body's grouped-product kernels are compiled whatever its size
+    (twelve in the jaxpr: three forward, three made again, six backward;
+    the TPU's compiled step counts eleven): the module's own cut (128 +
+    128 of 256 rows: the two EQUAL chunks this router's layer had until
+    PR 36) and the cut a router that balances itself gets (64 + 192)
+    lower to exactly the same kernels, twice the one-span layer's."""
+    layer, mine = _share(2, 4)
+    x = jax.random.normal(jax.random.PRNGKey(6), (64, D))
+    monkeypatch.setattr(expert_mod, "grouped_matmul", functools.partial(
+        expert_mod.grouped_matmul, interpret=True))
+    own_rule = expert_mod._chunk_rows
+
+    def calls(first):
+        monkeypatch.setattr(expert_mod, "_chunk_rows",
+                            lambda *a: first or own_rule(*a))
+        return kernel_calls(jax.make_jaxpr(jax.grad(lambda p: jnp.sum(
+            layer.apply(p, layer.init_state(), x)[0] ** 2)))(mine).jaxpr)
+
+    own, halves, unequal, one = (calls(first) for first in
+                                 (None, 128, 64, 256))
+    assert own == halves == unequal == {name: 2 * n
+                                        for name, n in one.items()}
+    assert sum(own.values()) == 24
+
+
+@pytest.mark.parametrize("assignments,held,total,first", [
+    (16384 * 6, 8, 64, 24576),          # the kimi cell: + one span of 73728
+    (16384 * 8, 16, 128, 32768),        # keye's shape, were it balanced
+    (16384 * 8, 128, 128, 16384 * 8),   # the whole layer: one span
+    (16384 * 8, 64, 128, 16384 * 8),    # twice the share is all of it
+    (64 * 4, 2, 16, 64), (9 * 8, 2, 16, 18), (7, 1, 16, 1), (9, 1, 16, 3),
+    (10 * 6, 1, 16, 10),                # 8 does not divide 60: 6 does
+    (96 * 2, 4, 8, 192), (128 * 3, 4, 8, 384)])
+def test_the_first_span_is_twice_the_balanced_share(assignments, held, total,
+                                                    first):
+    """Where the router balances itself: the nearest whole division of
+    the assignments to twice what lands here when the router is
+    balanced; the rest is ONE second span, so never more than two; an
+    odd count of assignments divides as far as it can. (Until PR 36
+    equal chunks of four times the share:
+    ``test_a_chunk_is_four_times_the_balanced_share``.)"""
+    assert expert_mod._chunk_rows(assignments, held, total, True) == first
+    assert assignments % first == 0
+    assert first >= min(assignments, 2 * assignments * held // total)
+
+
+@pytest.mark.parametrize("assignments,held,total,first", [
+    (16384 * 8, 16, 128, 65536),        # the keye cell: + one span of 65536
+    (16384 * 6, 8, 64, 49152),          # kimi's shape without its bias
+    (16384 * 8, 128, 128, 16384 * 8),   # the whole layer: one span
+    (16384 * 8, 32, 128, 16384 * 8),    # four times the share is all of it
+    (64 * 4, 2, 16, 128), (9 * 8, 2, 16, 36), (7, 1, 16, 7), (9, 1, 16, 3),
+    (10 * 6, 1, 32, 10)])               # 8 does not divide 60: 6 does
+def test_the_first_span_is_four_times_the_share_with_no_bias(
+        assignments, held, total, first):
+    """Where nothing holds the router balanced the step's time would
+    follow the router's state (PERF.md section 6, PR 36: the keye cell's
+    runs spread 4% for 0.9%): the first span stays at four times the
+    balanced share, the chunk the layer had until PR 36, and the one
+    second span takes the rest."""
+    assert expert_mod._chunk_rows(assignments, held, total, False) == first
+    assert assignments % first == 0
+    assert first >= min(assignments, 4 * assignments * held // total)
+
+
+SPAN_ROUTINGS = ["live < first", "live == first", "live == first + 1",
+                 "everything to one held expert", "nothing here"]
+
+
+def _first_span_for(routing, live):
+    """Where a case cuts the rows: at the live rows or one short of them
+    for the two edges, else where the module's own rule does."""
+    return {"live == first": live, "live == first + 1": live - 1}.get(routing)
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["ragged_dot", "megablox-interpreted"])
+@pytest.mark.parametrize("routing", SPAN_ROUTINGS)
+def test_two_spans_are_the_one_span_layer(two_spans_against_one, routing,
+                                          interpret):
+    """The softmax layer (2 of 16 held, 4 a token, 64 tokens, no bias
+    to balance it: a first span of 128 of the 256 rows by the module's
+    own rule) against the
+    same layer with all rows in one span: result, state and every
+    gradient, whether the live rows stay inside the first span, fill it
+    to the row, pass it by one, are all one held expert's, or are none;
+    the telemetry and the instant say which spans ran."""
+    layer, mine = _share(2, 8)
+    x = jax.random.normal(jax.random.PRNGKey(5), (64, D))
+    if routing in SPAN_ROUTINGS[3:]:
+        x = x.at[:, 0].set(3.0)
+        to_nine = 4.0 if routing == SPAN_ROUTINGS[3] else -40.0
+        mine["router_weight"] = mine["router_weight"].at[9, 0].set(to_nine) \
+            .at[8, 0].set(-40.0)
+    top, _ = layer.route(mine, x)
+    live = int(((top >= 8) & (top < 10)).sum())
+    state, ran, found, first = two_spans_against_one(
+        layer, mine, layer.init_state(), x, _first_span_for(routing, live),
+        interpret)
+    assert found == live
+    assert first == (_first_span_for(routing, live) or 128)
+    assert state["moe_local_assignment_share"] == pytest.approx(live / 256)
+    assert (ran, live) == {
+        "live < first": (1, live), "live == first": (1, first),
+        "live == first + 1": (2, first + 1),
+        "everything to one held expert": (1, 64),
+        "nothing here": (1, 0)}[routing]
+    if routing in SPAN_ROUTINGS[3:]:
+        assert state["moe_held_load_max"] == live
+    else:
+        assert 16 < live < 48
 
 
 def test_expert_share_refuses_experts_it_cannot_hold():
@@ -1006,7 +1123,7 @@ def test_the_layer_states_its_shapes_where_it_is_traced(system):
     assert sel[0]["materialised_bytes"] == 2 * 48 * 48 * 4
     assert moe[0] == dict(experts_total=8, experts_held=4, top_k=2,
                           tokens=96, expected_local_assignments=96.0,
-                          chunk_rows=192, chunks=1,
+                          chunk_rows=192, chunks=1, rest_rows=0,
                           scoring="softmax", shared_width=0,
                           bias_update_rate=0.0)
 

@@ -530,9 +530,10 @@ def test_rows_for_experts_elsewhere_cost_no_product_and_change_nothing(
     elsewhere in the products' last group, as ``_chunk`` hands them
     over, against the same rows riding in the last held expert's group
     (commit 0aa00c8): result, the state a training step hands on and
-    every gradient are equal, in the module's own chunk (all 384 rows)
-    and in chunks of 48 of which the live ~96 reach two or three; the
-    two new state keys say what part of those chunks the products
+    every gradient are equal, cut where the module's own rule cuts the
+    384 rows (a first span of 192 that holds the live ~96) and cut at
+    48, which they pass so that the second span of 336 runs; the two
+    state keys say what part of the spans that ran the products
     multiplied."""
     if rows is not None:
         monkeypatch.setattr(expert_mod, "_chunk_rows", lambda *a: rows)
@@ -564,24 +565,70 @@ def test_rows_for_experts_elsewhere_cost_no_product_and_change_nothing(
         assert float(jnp.abs(b).max()) > 0 and _rel(a, b) < 1e-6
     top = layer.route(params, x.reshape(-1, D), state["moe_bias"])[0]
     live = int(((top >= 4) & (top < 8)).sum())
-    each = rows or expert_mod._chunk_rows(128 * TOP, 4, TOTAL)
+    each = rows or expert_mod._chunk_rows(128 * TOP, 4, TOTAL, True)
     assert 64 < live < 128
-    ran = -(-live // each)
+    ran, worked_on = (2, 128 * TOP) if live > each else (1, each)
+    assert ran == (2 if rows else 1)
     assert float(new["moe_chunks_run"]) == ran
     assert float(new["moe_product_row_share"]) == pytest.approx(
-        live / (ran * each))
+        live / worked_on)
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["ragged_dot", "megablox-interpreted"])
+@pytest.mark.parametrize("routing", [
+    "live < first", "live == first", "live == first + 1",
+    "everything to one held expert", "nothing here"])
+def test_two_spans_are_the_one_span_layer(two_spans_against_one, routing,
+                                          interpret):
+    """This router's layer (sigmoid, a bias in the choice that the step
+    updates, the shared expert beside the routed ones; 2 of 16 held, 3 a
+    token, 64 tokens: a first span of 48 of the 192 rows by the module's
+    own rule) against the same layer with all rows in one span: result,
+    the state a training step hands on and every gradient, whether the
+    live rows stay inside the first span, fill it to the row, pass it by
+    one, are all one held expert's (64: the second span runs), or are
+    none (the shared expert's part is all there is)."""
+    layer, params = _share(2, 8)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 32, D))
+    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(3), (TOTAL,))
+    if routing == "everything to one held expert":
+        bias = bias.at[8].set(10.0).at[9].set(-10.0)
+    elif routing == "nothing here":
+        bias = bias.at[8:10].set(-10.0)
+    state = dict(layer.init_state(), moe_bias=bias)
+    top = layer.route(params, x.reshape(-1, D), bias)[0]
+    live = int(((top >= 8) & (top < 10)).sum())
+    cut_at = {"live == first": live, "live == first + 1": live - 1}.get(
+        routing)
+    new, ran, found, first = two_spans_against_one(
+        layer, params, state, x, cut_at, interpret)
+    assert found == live and first == (cut_at or 48)
+    assert (ran, live) == {
+        "live < first": (1, live), "live == first": (1, first),
+        "live == first + 1": (2, first + 1),
+        "everything to one held expert": (2, 64),
+        "nothing here": (1, 0)}[routing]
+    assert 0 < live < 48 or routing in ("everything to one held expert",
+                                        "nothing here")
+    assert new["moe_bias_abs_max"] > 0      # the step's update is in it
 
 
 def test_keyes_expert_share_lowers_to_the_parents_text():
     """``route_top_k`` gained a scoring and a bias; the softmax router
     without a bias — the keye cell's — must lower, forward and backward,
     to ONE pinned text whatever the other router's arguments grow into.
-    The digest is of PR 34's program, the commit that follows 0aa00c8,
-    made by this very code: that PR changed the text on purpose (a
-    chunk's rows for experts elsewhere are the products' last group,
-    two more state keys). Through PR 33 it was 58ca92f9...af5e0f7,
-    commit 7dc00b9's, made in a checkout of it: PR 33 left that text
-    as it was."""
+    The digest is of PR 36's program, the commit that follows 96f00a2,
+    made by this very code. It differs from the parent's in the
+    telemetry's two scalars alone (``moe_chunks_run`` is 1 + (the live
+    rows passed the first span) where it was a rounded-up division):
+    with no bias to balance it this router's first span stays at four
+    times the balanced share, here all 192 rows in one span as before,
+    and every other line of the text is the parent's.
+    Through PR 35 it was 208df886...7e541b, PR 34's, the commit that
+    follows 0aa00c8 (a chunk's rows for experts elsewhere became the
+    products' last group); through PR 33 58ca92f9...af5e0f7, commit
+    7dc00b9's."""
     layer = ExpertShare(16, 8, 16, 4, experts_held=4, experts_offset=4)
     params = jax.eval_shape(layer.init, jax.random.PRNGKey(0))
     x = jax.ShapeDtypeStruct((2, 24, 16), jnp.float32)
@@ -594,7 +641,7 @@ def test_keyes_expert_share_lowers_to_the_parents_text():
         text = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
             params, x).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        "208df886cb768ae895a36a14c2d5ba3f3303a5c5f8ff68f909a05da47b7e541b"
+        "e97853ad9ccbd12e9c394982dce521cd58e9b903d4f1f20262720ca36640d974"
     assert set(layer.init_state()) == set(expert_mod.SHARE_STATE_KEYS)
 
 
@@ -780,7 +827,7 @@ def test_what_the_layers_are_is_stated_where_they_are_traced(system,
     moe = [e["args"] for e in events if e["name"] == "moe_share"]
     assert moe[0] == dict(experts_total=8, experts_held=4, top_k=3,
                           tokens=128, expected_local_assignments=192.0,
-                          chunk_rows=384, chunks=1,
+                          chunk_rows=384, chunks=1, rest_rows=0,
                           scoring="sigmoid", shared_width=32,
                           bias_update_rate=0.05)
 
