@@ -15,7 +15,9 @@ docs/state_space.md has the equations, the chunked algorithm and its
 backward pass. The scan is ``ops/pallas/ssd.py`` on the TPU and
 ``ssd_chunked_xla`` (the same chunked algorithm in ``jax.numpy``)
 elsewhere; the per-position recurrence is the plain reference's
-(benchmarks/reference/nemotron.py) and the tests'.
+(benchmarks/reference/nemotron.py) and the tests'. The conv + silu and
+the gated norm are ``ops/pallas/ssm_pointwise.py`` on the TPU (one pass
+each way) and ``causal_conv1d`` / ``gated_rms_norm`` elsewhere.
 """
 from __future__ import annotations
 
@@ -127,9 +129,13 @@ class Mamba2Mixer(Module):
     float32, and Delta, A, the conv, the scan's decays and state, D x
     and the gated norm are float32. On the TPU the scan is the Pallas
     kernel (``ops/pallas/ssd.py``) and shapes it does not take are an
-    error, never another path; elsewhere ``ssd_chunked_xla``.
-    Scopes: ``ssm_project`` (W_in, the conv, the gated norm and W_out)
-    and ``ssm_scan``."""
+    error, never another path; elsewhere ``ssd_chunked_xla``. So with
+    the conv + silu and the gated norm: on the TPU one Pallas pass each
+    way (``ops/pallas/ssm_pointwise.py``: compute dtype in and out,
+    float32 inside, no float32 residual), elsewhere ``causal_conv1d``
+    and ``gated_rms_norm``. Scopes: ``ssm_project`` (W_in, the conv under
+    ``ssm_conv``, the gated norm under ``ssm_norm``, W_out) and
+    ``ssm_scan``."""
 
     def __init__(self, d_model: int, heads: int, head_dim: int,
                  groups: int, state: int, conv_kernel: int = 4,
@@ -175,34 +181,48 @@ class Mamba2Mixer(Module):
                                                fan_out=d),
         }
 
-    def scan_inputs(self, params, x):
+    def scan_inputs(self, params, x, *, fused: bool = False):
         """(z, x, Delta, A, B, C) of the scan from the layer's normed
         input (B, S, d): z and x (B, S, H, P) and B, C (B, S, G, N) in
-        the compute dtype, Delta (B, S, H) and A (H,) float32."""
+        the compute dtype, Delta (B, S, H) and A (H,) float32. W_in is
+        three products, z | xBC | dt, so that each pass reads its own
+        columns whole. ``fused``: the conv + silu + split as ONE pass
+        (``ops/pallas/ssm_pointwise.conv_silu_split``), else
+        ``causal_conv1d``."""
+        from bigdl_tpu.ops.pallas import ssm_pointwise as pointwise
         b, s, _ = x.shape
         cd = compute_dtype()
         h, inner, gn = self.heads, self.inner, self.groups * self.state_size
         w = params["in_weight"].astype(cd)
         hx = x.astype(cd)
-        zx = jnp.matmul(hx, w[:inner + self.conv_dim].T)
-        dt = jnp.matmul(hx, w[inner + self.conv_dim:].T,
-                        preferred_element_type=F32)
-        xbc = jax.nn.silu(causal_conv1d(zx[..., inner:],
-                                        params["conv_weight"],
-                                        params["conv_bias"]))
-        xs = xbc[..., :inner].astype(cd).reshape(b, s, h, self.head_dim)
-        bm = xbc[..., inner:inner + gn].reshape(b, s, self.groups,
-                                                self.state_size)
-        cm = xbc[..., inner + gn:].reshape(b, s, self.groups, self.state_size)
+        split = inner + self.conv_dim
+        z = jnp.matmul(hx, w[:inner].T)
+        xbc = jnp.matmul(hx, w[inner:split].T)
+        dt = jnp.matmul(hx, w[split:].T, preferred_element_type=F32)
+        with jax.named_scope("ssm_conv"):
+            if fused:
+                xs, bm, cm = pointwise.conv_silu_split(
+                    xbc, params["conv_weight"], params["conv_bias"],
+                    heads=h, head_dim=self.head_dim, groups=self.groups,
+                    state=self.state_size)
+            else:
+                xbc = jax.nn.silu(causal_conv1d(
+                    xbc, params["conv_weight"],
+                    params["conv_bias"])).astype(cd)
+                xs = xbc[..., :inner].reshape(b, s, h, self.head_dim)
+                bm, cm = (xbc[..., inner + i * gn:inner + (i + 1) * gn]
+                          .reshape(b, s, self.groups, self.state_size)
+                          for i in (0, 1))
         delta = jax.nn.softplus(dt + params["dt_bias"].astype(F32))
         a = -jnp.exp(params["A_log"].astype(F32))
-        return (zx[..., :inner], xs, delta, a, bm.astype(cd),
-                cm.astype(cd))
+        return z, xs, delta, a, bm, cm
 
     def apply(self, params, state, x, *, training=False, rng=None):
         from bigdl_tpu.observability import trace
         from bigdl_tpu.ops.pallas import ssd as kernels
+        from bigdl_tpu.ops.pallas import ssm_pointwise as pointwise
         b, s, _ = x.shape
+        cd = compute_dtype()
         on_kernel = jax.default_backend() == "tpu"
         # python runs this when the layer is traced for a compile, never
         # in a step
@@ -210,17 +230,27 @@ class Mamba2Mixer(Module):
                       head_dim=self.head_dim, groups=self.groups,
                       state=self.state_size, chunk=self.chunk,
                       conv_kernel=self.conv_kernel,
-                      scan="ssd_kernel" if on_kernel else "ssd_chunked_xla")
+                      scan="ssd_kernel" if on_kernel else "ssd_chunked_xla",
+                      pointwise="fused_kernel" if on_kernel else "xla",
+                      pointwise_bytes=pointwise.pointwise_bytes(
+                          b * s, self.inner, self.conv_dim,
+                          jnp.dtype(cd).itemsize))
         with jax.named_scope("ssm_project"):
-            z, xs, delta, a, bm, cm = self.scan_inputs(params, x)
+            z, xs, delta, a, bm, cm = self.scan_inputs(params, x,
+                                                       fused=on_kernel)
         with jax.named_scope("ssm_scan"):
             scan = kernels.ssd if on_kernel else ssd_chunked_xla
             y = scan(xs, delta, a, bm, cm, params["D"], chunk=self.chunk)
         with jax.named_scope("ssm_project"):
-            y = gated_rms_norm(y.reshape(b, s, self.inner), z,
-                               params["norm_weight"], self.groups, self.eps)
-            out = jnp.matmul(y.astype(compute_dtype()),
-                             params["out_weight"].astype(compute_dtype()).T)
+            with jax.named_scope("ssm_norm"):
+                if on_kernel:
+                    y = pointwise.gated_norm(y, z, params["norm_weight"],
+                                             self.groups, self.eps)
+                else:
+                    y = gated_rms_norm(y.reshape(b, s, self.inner), z,
+                                       params["norm_weight"], self.groups,
+                                       self.eps).astype(cd)
+            out = jnp.matmul(y, params["out_weight"].astype(cd).T)
         return out.astype(activation_dtype()), state
 
     def __repr__(self):

@@ -16,8 +16,11 @@ over its own norm, with that norm over the check's floor. Three of the
 controls lower a precision the configuration states: the scan's carried
 state rounded to bf16 every chunk, the states the backward reads
 rounded to bf16, and every matrix rounded to float8's mantissa (3 bits
-as e4m3, 2 as e5m2, where the policy's bf16 has 7). PERF.md section 6
-(PR 37) quotes them. Refuses to run
+as e4m3, 2 as e5m2, where the policy's bf16 has 7). The conv one step
+ahead and the gate after the norm are planted where the mixer runs them:
+in ``causal_conv1d`` / ``gated_rms_norm`` on the CPU, around the fused
+passes of ``ops/pallas/ssm_pointwise.py`` on the TPU. PERF.md section 6
+quotes them. Refuses to run
 without a TPU; ``--rehearsal`` walks the same code at the data files'
 tiny widths on any backend and is never a result.
 """
@@ -121,6 +124,7 @@ def _planted(stack, control, model):
 
     from bigdl_tpu.nn import state_space
     from bigdl_tpu.ops.pallas import ssd as kernels
+    from bigdl_tpu.ops.pallas import ssm_pointwise as pointwise
     mp = stack.enter_context(pytest.MonkeyPatch.context())
     if control == "state_carried_in_bf16":
         real = kernels._fwd_kernel
@@ -156,6 +160,7 @@ def _planted(stack, control, model):
             mp.setattr(mod, name, lambda x, dt, a, bm, cm, d, _r=real, **k:
                        _r(x, dt, a, bm, cm, d * 0, **k))
     elif control == "conv_one_step_ahead":
+        # the CPU's path: the conv's window one position later
         def ahead(x, w, b):
             k = w.shape[1]
             xs = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 2, 1), (0, 0)))
@@ -164,9 +169,16 @@ def _planted(stack, control, model):
             return b.astype(jnp.float32) + sum(xs[:, i:i + s] * w[:, i]
                                                for i in range(k))
         mp.setattr(state_space, "causal_conv1d", ahead)
+        # the TPU's: the fused pass reads its input one position later
+        # (x[t + 1] where x[t] was; the first input is lost to the three
+        # first outputs)
+        real = pointwise.conv_silu_split
+        mp.setattr(pointwise, "conv_silu_split",
+                   lambda x, w, b, _r=real, **k: _r(
+                       jnp.pad(x[:, 1:], ((0, 0), (0, 1), (0, 0))), w, b,
+                       **k))
     elif control == "gate_after_the_norm":
         def after(y, z, w, groups, eps):
-            import jax
             y = y.astype(jnp.float32)
             shape = y.shape
             g = y.reshape(*shape[:-1], groups, shape[-1] // groups)
@@ -175,6 +187,13 @@ def _planted(stack, control, model):
             return g.reshape(shape) * w.astype(jnp.float32) \
                 * jax.nn.silu(z.astype(jnp.float32))
         mp.setattr(state_space, "gated_rms_norm", after)
+        # the TPU's: the fused pass gated by a constant (silu(10), which
+        # the norm divides out, eps / 100 aside), the gate applied after
+        real = pointwise.gated_norm
+        mp.setattr(pointwise, "gated_norm",
+                   lambda y, z, w, groups, eps, _r=real, **k: (_r(
+                       y, jnp.full_like(z, 10.0), w, groups, eps, **k)
+                       * jax.nn.silu(z.astype(jnp.float32))).astype(y.dtype))
     else:
         assert control == "as_it_is", control
 

@@ -447,9 +447,12 @@ def test_what_the_mixer_runs_is_stated_where_it_is_traced(system,
         trace.disable()
     mixers = [e["args"] for e in events if e["name"] == "mamba_mixer"]
     assert len(mixers) == 4
+    # the pointwise passes' bytes at their bound: 128 tokens, 32 inner,
+    # 64 conv channels, float32 here
     assert mixers[0] == dict(seq=64, heads=4, head_dim=8, groups=2, state=8,
                              chunk=16, conv_kernel=4,
-                             scan="ssd_chunked_xla")
+                             scan="ssd_chunked_xla", pointwise="xla",
+                             pointwise_bytes=4 * 128 * (7 * 64 + 11 * 32))
     sched = [e["args"] for e in events if e["name"] == "ssd_schedule"]
     assert len(sched) == 4 and sched[0]["grid"] == [2, 2, 4]
     assert sched[0]["state_bytes"] == 2 * 4 * 4 * 8 * 8 * 4

@@ -550,3 +550,91 @@ class TestSsdCompilesForTheV5e:
         assert f"f32[{b},{h},{s // 128},{n},{p}]" in text
         assert f"bf16[{b},{h},{s // 128},{n},{p}]" not in text
         assert not re.search(rf"\[(\d+,)*{s},{s}[,\]]", text)
+
+
+def _entry(text):
+    """The compiled program's ENTRY computation: the buffers it holds in
+    HBM (a fusion's body computes in registers and VMEM)."""
+    return text[text.index("\nENTRY"):]
+
+
+class TestSsmPointwiseCompilesForTheV5e:
+    """Mosaic's verdict on the Mamba-2 mixer's pointwise passes
+    (``ops/pallas/ssm_pointwise.py``) at the Nemotron cell's shape, 2 x
+    8192 tokens of 6144 conv channels and 4096 inner columns in eight
+    groups, forward and backward in one program; and the whole mixer as
+    the TPU runs it: no float32 tensor of the tokens' size is held."""
+
+    B, S, C, I = 2, 8192, 6144, 4096
+
+    @pytest.fixture(scope="class")
+    def passes(self, one_chip):
+        from bigdl_tpu.ops.pallas import ssm_pointwise as pw
+
+        def sds(shape, dtype=BF16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        b, s, c, i = self.B, self.S, self.C, self.I
+        f32 = jnp.float32
+        conv = jax.jit(_scalar_grads(lambda *a: sum(
+            o.astype(f32).sum() for o in pw.conv_silu_split(
+                *a, heads=64, head_dim=64, groups=8, state=128)), 3)).lower(
+            sds((b, s, c)), sds((c, 4), f32), sds((c,), f32)).compile()
+        norm = jax.jit(_scalar_grads(
+            lambda y, z, w: pw.gated_norm(y, z, w, 8, 1e-5), 3)).lower(
+            sds((b, s, 64, 64)), sds((b, s, i)), sds((i,), f32)).compile()
+        return conv.as_text(), norm.as_text()
+
+    def test_one_kernel_a_direction_and_their_names(self, passes):
+        for text, names in zip(passes, (("ssm_conv_fwd", "ssm_conv_bwd"),
+                                        ("ssm_norm_fwd", "ssm_norm_bwd"))):
+            assert text.count('custom_call_target="tpu_custom_call"') == 2
+            for name in names:
+                assert name in text, name
+
+    def test_no_float32_tensor_of_the_tokens_size(self, passes):
+        b, s = self.B, self.S
+        for text, width in zip(passes, (self.C, self.I)):
+            assert f"bf16[{b},{s},{width}]" in _entry(text)
+            for shape in (f"f32[{b},{s},{width}]", f"f32[{b * s},{width}]"):
+                assert shape not in _entry(text), shape
+
+    def test_x_and_y_in_the_scans_layout(self, passes):
+        """The conv pass writes x as (B, H, S, P) and B, C as (B, G, S,
+        N), the norm reads y so and writes dy so: the scan kernels'
+        layouts, with no copy between."""
+        b, s = self.B, self.S
+        conv, norm = passes
+        assert f"bf16[{b},64,{s},64]" in _entry(conv)
+        assert f"bf16[{b},8,{s},128]" in _entry(conv)
+        assert f"bf16[{b},64,{s},64]" in _entry(norm)
+
+    def test_the_mixer_holds_no_float32_tensor_of_the_tokens_size(
+            self, one_chip, monkeypatch):
+        """W_in's three products, the conv pass, the scan, the norm pass
+        and W_out, forward and backward: every (tokens, 6144) and
+        (tokens, 4096) buffer is bf16, four Pallas calls a direction."""
+        from bigdl_tpu.nn import state_space
+        from bigdl_tpu.tensor import DTypePolicy, policy_scope
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        f32 = jnp.dtype("float32")
+        with policy_scope(DTypePolicy(param_dtype=f32, compute_dtype=BF16,
+                                      activation_dtype=BF16)):
+            m = state_space.Mamba2Mixer(2688, 64, 64, 8, 128)
+            params = jax.tree.map(
+                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                               sharding=one_chip),
+                jax.eval_shape(m.init, jax.random.PRNGKey(0)))
+            x = jax.ShapeDtypeStruct((self.B, self.S, 2688), BF16,
+                                     sharding=one_chip)
+            text = jax.jit(jax.value_and_grad(
+                lambda p, v: m.apply(p, {}, v)[0].astype(f32).sum(),
+                argnums=(0, 1))).lower(params, x).compile().as_text()
+        entry = _entry(text)
+        b, s = self.B, self.S
+        for name in ("ssm_conv_fwd", "ssm_conv_bwd", "ssm_norm_fwd",
+                     "ssm_norm_bwd", "ssd_fwd", "ssd_bwd"):
+            assert name in text, name
+        for width in (self.C, self.I, self.C + self.I):
+            for shape in (f"f32[{b},{s},{width}]", f"f32[{b * s},{width}]"):
+                assert shape not in entry, shape
