@@ -12,7 +12,7 @@ from bigdl_tpu.models.resnet.model import (ResNet, ShortcutType, DatasetType,
                                      model_init)
 from bigdl_tpu.models.rnn.model import SimpleRNN, BatchedSimpleRNN
 from bigdl_tpu.models.transformer.model import (EvaByteLM, KeyeLM, KimiLM,
-                                                NemotronHLM,
+                                                NemotronHLM, SmallThinkerLM,
                                                 TransformerBlock,
                                                 TransformerLM)
 
@@ -24,5 +24,5 @@ __all__ = [
     "ResNet", "ShortcutType", "DatasetType", "model_init",
     "SimpleRNN", "BatchedSimpleRNN",
     "TransformerLM", "TransformerBlock", "EvaByteLM", "KeyeLM", "KimiLM",
-    "NemotronHLM",
+    "NemotronHLM", "SmallThinkerLM",
 ]

@@ -19,8 +19,8 @@ from bigdl_tpu.nn.module import Container, Module
 from bigdl_tpu.tensor import activation_dtype, default_dtype
 
 __all__ = ["TransformerLM", "TransformerBlock", "PreNormBlock", "EvaByteLM",
-           "KeyeLM", "KimiLM", "NemotronHLM", "hybrid_pattern",
-           "decode_meta"]
+           "KeyeLM", "KimiLM", "NemotronHLM", "SmallThinkerLM",
+           "hybrid_pattern", "attention_layout", "decode_meta"]
 
 
 class _Residual(Container):
@@ -454,6 +454,147 @@ def NemotronHLM(vocab_size: int = 131072, d_model: int = 2688,
                         init_method=_small_head(d_model),
                         output_dtype=jnp.float32).set_name("lm_head"))
     model.no_decode_path = "Mamba2Mixer (no state cache): ROADMAP B7"
+    return model.set_remat(remat)
+
+
+class _RoutedBlock(Container):
+    """x' = x + attention(r), r = norm_in(x); y = x' + experts(
+    norm_post(x'), route_from=r): a block whose expert sublayer's router
+    reads the ATTENTION's normed input (``ExpertShare.apply(route_from=
+    )``). Children "0" norm_in, "1" attention, "2" norm_post, "3"
+    experts; the attention sublayer (projections, RoPE, core) runs under
+    the named scope ``scope``. With a ``residual_dtype`` the stream the
+    block hands on is kept in it, as ``_Residual`` keeps it."""
+
+    def __init__(self, norm_in: Module, attention: Module,
+                 norm_post: Module, experts: Module, scope: str,
+                 residual_dtype=None):
+        super().__init__(norm_in, attention, norm_post, experts)
+        self.scope, self.residual_dtype = scope, residual_dtype
+
+    def _add(self, x, h):
+        if self.residual_dtype is not None:
+            x, h = x.astype(self.residual_dtype), h.astype(self.residual_dtype)
+        return x + h
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        norm_in, attention, norm_post, experts = self.modules
+        r, s0 = norm_in.apply(params["0"], state["0"], x, training=training)
+        with jax.named_scope(self.scope):
+            a, s1 = attention.apply(params["1"], state["1"], r,
+                                    training=training, rng=rng)
+        x = self._add(x, a)
+        h, s2 = norm_post.apply(params["2"], state["2"], x, training=training)
+        y, s3 = experts.apply(params["3"], state["3"], h, training=training,
+                              route_from=r)
+        return self._add(x, y), {"0": s0, "1": s1, "2": s2, "3": s3}
+
+
+def attention_layout(window_layout, rope_layout, num_layers: int) -> list:
+    """Each of the first ``num_layers`` layers' attention as a
+    SmallThinker configuration lays it out: ``(windowed, rotary)`` from
+    ``sliding_window_layout`` and ``rope_layout`` (1 / 0 a layer). A
+    layout shorter than the model is refused by name."""
+    if min(len(window_layout), len(rope_layout)) < num_layers:
+        raise ValueError(f"attention layouts of {len(window_layout)} / "
+                         f"{len(rope_layout)} layers for {num_layers}")
+    return [(bool(w), bool(r)) for w, r in
+            zip(window_layout[:num_layers], rope_layout[:num_layers])]
+
+
+#: SmallThinkerLM's token embedding deviation, for KimiLM's reason: the
+#: stream's common part skews the untrained softmax router (PERF.md
+#: section 6 has the readings)
+SMALLTHINKER_EMBEDDING_STD = 4.0
+
+#: SmallThinker's published period: window layers but for every fourth
+SMALLTHINKER_LAYOUT = (0, 1, 1, 1)
+
+
+def SmallThinkerLM(vocab_size: int = 151936, d_model: int = 2560,
+                   num_heads: int = 28, num_kv_heads: int = 4,
+                   head_dim: int = 128, num_layers: int = 52,
+                   window: int = 4096,
+                   window_layout=SMALLTHINKER_LAYOUT * 13,
+                   rope_layout=SMALLTHINKER_LAYOUT * 13,
+                   rope_theta: float = 1.5e6, expert_dim: int = 768,
+                   experts_total: int = 64, experts_per_token: int = 6,
+                   experts_held: int | None = None, experts_offset: int = 0,
+                   rms_eps: float = 1e-6,
+                   remat: str | None = "per_block") -> nn.Sequential:
+    """The language model of SmallThinker-21BA3B-Instruct
+    (huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct): blocks of
+    ``_RoutedBlock``,
+
+        r = RMSNorm_in(x);  x' = x + Attn_l(r)
+        y = x' + sum over e in top-k(softmax(W_router r)) of
+            w_e W_down,e( relu(W_gate,e h) * (W_up,e h) ),
+        h = RMSNorm_post(x')
+
+    with ``Attn_l`` a causal GQA ``nn.MultiHeadAttention`` of
+    ``num_heads`` / ``num_kv_heads`` x ``head_dim`` and no bias, laid
+    out by ``window_layout`` / ``rope_layout`` (``attention_layout``):
+    a window layer sees the ``window`` keys up to its own
+    (``flash_window_*`` on the TPU) with RoPE of ``rope_theta``; a
+    global layer sees every earlier key with NO position embedding
+    (NoPE). The experts are ``parallel.expert.ExpertShare`` of kind
+    ``"relu"`` (ReGLU, ``expert_dim`` wide): a float32 softmax router
+    over ``experts_total`` that reads r, the attention's input, the
+    ``experts_per_token`` largest renormalised, ``experts_held`` from
+    ``experts_offset`` held here, dropless, no shared expert. A float32
+    residual stream, the norms hand float32 to the sublayers, an untied
+    head of float32 logits over ``vocab_size`` rows (a chip's slice of
+    the vocabulary is a smaller ``vocab_size``). The token embedding is
+    drawn at a deviation of 4 (``SMALLTHINKER_EMBEDDING_STD``), for
+    ``KimiLM``'s reason: below it the untrained softmax router sends
+    most tokens to the same few experts. tokens (B, S) 1-based.
+    docs/window_attention.md and docs/expert_share.md have the
+    equations.
+
+    Each block's attention runs under the named scope
+    ``attention_window`` or ``attention_global``, and the layout is
+    stated once at build as a ``bigdl:nn:attention_layout`` instant.
+    ``remat`` as ``EvaByteLM``'s; children ``embed`` / ``block_i`` /
+    ``final_norm`` / ``lm_head`` and no ``lm_meta``: the serving half
+    of the window is not there yet (``decode_meta``)."""
+    from bigdl_tpu.observability import trace
+    from bigdl_tpu.parallel.expert import ExpertShare
+
+    layout = attention_layout(window_layout, rope_layout, num_layers)
+
+    def norm():
+        return nn.RMSNorm(d_model, eps=rms_eps, fp32=True)
+
+    model = (nn.Sequential()
+             .add(_TokenAndPosition(vocab_size, d_model, 0, with_pos=False,
+                                    out_dtype=jnp.float32,
+                                    init_std=SMALLTHINKER_EMBEDDING_STD)
+                  .set_name("embed")))
+    for i, (windowed, rotary) in enumerate(layout):
+        attention = nn.MultiHeadAttention(
+            d_model, num_heads, causal=True, with_bias=False, rope=rotary,
+            num_kv_heads=num_kv_heads, head_dim=head_dim,
+            window=window if windowed else None, rope_theta=rope_theta)
+        experts = ExpertShare(d_model, expert_dim, experts_total,
+                              experts_per_token, experts_held=experts_held,
+                              experts_offset=experts_offset,
+                              expert_act="relu")
+        model.add(_RoutedBlock(
+            norm(), attention, norm(), experts,
+            "attention_window" if windowed else "attention_global",
+            residual_dtype=jnp.float32).set_name(f"block_{i}"))
+    model.add(nn.RMSNorm(d_model, eps=rms_eps).set_name("final_norm"))
+    model.add(nn.Linear(d_model, vocab_size, with_bias=False,
+                        init_method=_small_head(d_model),
+                        output_dtype=jnp.float32).set_name("lm_head"))
+    trace.instant("attention_layout", cat="nn", window=window,
+                  window_layers=[i for i, (w, _) in enumerate(layout) if w],
+                  global_layers=[i for i, (w, _) in enumerate(layout)
+                                 if not w],
+                  rope_layers=[i for i, (_, r) in enumerate(layout) if r],
+                  rope_theta=rope_theta)
+    model.no_decode_path = ("sliding-window attention (no paged window "
+                            "cache): ROADMAP B6")
     return model.set_remat(remat)
 
 

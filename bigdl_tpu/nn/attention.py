@@ -74,6 +74,12 @@ class MultiHeadAttention(Module):
     ``head_dim`` sets the heads' width where ``num_heads x head_dim`` is
     not ``embed_dim`` (Nemotron-H: 32 x 128 over 2688); q and the output
     projection are then ``num_heads x head_dim`` wide.
+
+    ``window`` W (causal only) limits query i to keys i - W < j <= i
+    (``dot_product_attention(window=)``; docs/window_attention.md), and
+    ``rope_theta`` is the rotation's base: one module for a model whose
+    layers differ in both (SmallThinker: windowed RoPE layers, global
+    layers without position embedding).
     """
 
     def __init__(self, embed_dim: int, num_heads: int,
@@ -81,7 +87,8 @@ class MultiHeadAttention(Module):
                  sequence_parallel: str | None = None,
                  mesh_axis: str = "seq", rope: bool = False,
                  num_kv_heads: int | None = None,
-                 head_dim: int | None = None):
+                 head_dim: int | None = None,
+                 window: int | None = None, rope_theta: float = 10000.0):
         super().__init__()
         assert head_dim is not None or embed_dim % num_heads == 0
         self.embed_dim, self.num_heads = embed_dim, num_heads
@@ -104,6 +111,9 @@ class MultiHeadAttention(Module):
         self.rope = rope
         if rope:
             assert self.head_dim % 2 == 0, "rope needs an even head_dim"
+        if window is not None and not causal:
+            raise ValueError(f"window={window} needs causal=True")
+        self.window, self.rope_theta = window, float(rope_theta)
 
     def init(self, rng):
         ks = jax.random.split(rng, 4)
@@ -140,8 +150,8 @@ class MultiHeadAttention(Module):
             b, s, self.num_kv_heads, self.head_dim)
         if self.rope:
             pos = jnp.arange(s)
-            q = apply_rope(q, pos)
-            k = apply_rope(k, pos)
+            q = apply_rope(q, pos, self.rope_theta)
+            k = apply_rope(k, pos, self.rope_theta)
         group = self.num_heads // self.num_kv_heads
         if group > 1 and self.sequence_parallel is None:
             # GQA: each kv head serves `group` query heads. The ring and
@@ -153,13 +163,15 @@ class MultiHeadAttention(Module):
             v = jnp.repeat(v, group, axis=2)
         if self.sequence_parallel == "ring":
             o = seq.ring_attention(q, k, v, causal=self.causal,
-                                   axis=self.mesh_axis, kv_groups=group)
+                                   axis=self.mesh_axis, kv_groups=group,
+                                   window=self.window)
         elif self.sequence_parallel == "ulysses":
             o = seq.ulysses_attention(q, k, v, causal=self.causal,
                                       axis=self.mesh_axis,
-                                      kv_groups=group)
+                                      kv_groups=group, window=self.window)
         else:
-            o = seq.dot_product_attention(q, k, v, causal=self.causal)
+            o = seq.dot_product_attention(q, k, v, causal=self.causal,
+                                          window=self.window)
         y = self._proj(params, "out",
                        o.reshape(b, s, self.num_heads * self.head_dim))
         return y.astype(activation_dtype()), state
@@ -167,7 +179,8 @@ class MultiHeadAttention(Module):
     def __repr__(self):
         return (f"MultiHeadAttention({self.embed_dim}, "
                 f"heads={self.num_heads}, causal={self.causal}, "
-                f"sp={self.sequence_parallel})")
+                f"sp={self.sequence_parallel}"
+                + (f", window={self.window}" if self.window else "") + ")")
 
 
 _NEG = -1e9  # finite mask value, as parallel/sequence.py

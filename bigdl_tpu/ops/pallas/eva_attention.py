@@ -53,7 +53,7 @@ __all__ = ["eva_attention", "eva_schedule", "EvaSchedule"]
 
 class EvaSchedule(NamedTuple):
     """What one call runs. ``local`` is the flash schedule of ONE window
-    (its ``tiles_computed`` / ``tiles_causal`` count ``block``-sided
+    (its ``tiles_computed`` / ``tiles_visible`` count ``block``-sided
     squares a window). ``tiles_*`` here count a head: local squares over
     all windows; remote tiles are bq x (W / C) — one q block against one
     earlier window's summaries — of which none is masked, so needed ==
@@ -95,7 +95,7 @@ def eva_schedule(sq: int, window: int, chunk: int, d: int,
     remote = qpw * windows * (windows - 1) // 2
     return EvaSchedule(local, windows, qpw, per_window,
                        tiles_computed=local.tiles_computed * windows,
-                       tiles_needed=local.tiles_causal * windows,
+                       tiles_needed=local.tiles_visible * windows,
                        remote_tiles_computed=remote,
                        remote_tiles_needed=remote)
 

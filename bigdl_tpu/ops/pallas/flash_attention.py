@@ -38,6 +38,16 @@ from (causal, sq, skv, d, dtype) alone (``_schedule``):
   backward; causal calls clamp the index maps so tiles above the
   diagonal are not fetched, and mask only where the diagonal cuts.
 
+A sliding WINDOW (``window=W``, causal self-attention only: query i
+sees keys j with i - W < j <= i) runs the same three families under
+names of their own (``flash_window_fwd``, ``flash_window_dqdkdv``,
+``flash_window_dq`` / ``_dkdv``): the looped walks start at the block
+holding key q0 - W + 1 and mask the one or two blocks the window's edge
+cuts as they mask the block on the diagonal; the streamed kernels' grids
+span the band of blocks a q (k) block sees, their index maps clamped
+below the window as above the diagonal. A tile wholly behind the window
+costs no FLOPs, no DMA and no grid step (docs/window_attention.md).
+
 The softmax scale never rides the score tiles when it is a power of two
 (D=64: 0.125): it is folded, exactly, into the kernel's outer operand;
 dq and dk take it once, in their accumulators. Measured on the v5e at
@@ -154,9 +164,10 @@ class Schedule(NamedTuple):
     step, the bq x bq block on the diagonal in ``block``-sided squares;
     the others run bq x bk tiles over the grid (``block`` 0).
     ``tiles_computed`` counts the score tiles (looped: squares) the
-    forward computes a head, ``tiles_causal`` the tiles' worth of scores
-    the mask leaves any weight in: their ratio is the schedule's waste
-    (1.0 without a mask)."""
+    forward computes a head, ``tiles_visible`` the tiles' worth of
+    scores the mask leaves any weight in (the causal pairs, or under a
+    ``window`` W the sum over rows i of min(i + 1, W)): their ratio is
+    the schedule's waste (1.0 without a mask)."""
 
     bq: int
     bk: int
@@ -166,7 +177,8 @@ class Schedule(NamedTuple):
     kv_resident: bool
     one_pass_backward: bool
     tiles_computed: int
-    tiles_causal: float
+    tiles_visible: float
+    window: int | None = None
 
 
 def _looped_tiles(sq: int) -> tuple[int, int, int] | None:
@@ -183,10 +195,45 @@ def _looped_tiles(sq: int) -> tuple[int, int, int] | None:
             side * min(sq // side, _BWD_BLOCKS_A_STEP))
 
 
+def _inside_and_edges(bq: int, window: int) -> tuple[int, int]:
+    """Of the bq-row blocks before a q block's own: how many lie wholly
+    inside every one of its rows' windows, and after them at most how
+    many the window's edge cuts (1 where bq divides W, else 2; 0 or 1
+    where W < bq)."""
+    inside = max(window // bq - 1, 0)
+    return inside, (window + bq - 2) // bq - inside
+
+
+def _first_k(i, bq, bk, window):
+    """The first K block (bk rows) q block ``i`` (bq rows) sees."""
+    return jnp.maximum(i * bq - window + 1, 0) // bk
+
+
+def _last_q(i, bq, bk, window, nq):
+    """The last q block whose rows see a key of K block ``i``."""
+    return jnp.minimum((i * bk + bk + window - 2) // bq, nq - 1)
+
+
+def _bands(sq: int, bq: int, bk: int, window: int) -> tuple[int, int]:
+    """Grid steps a streamed windowed call takes a q block (K blocks of
+    the widest band a q block sees) and a K block (q blocks)."""
+    nq, nk = sq // bq, sq // bk
+    return (max((i * bq + bq - 1) // bk - max(i * bq - window + 1, 0) // bk
+                + 1 for i in range(nq)),
+            max(min((i * bk + bk + window - 2) // bq, nq - 1)
+                - (i * bk) // bq + 1 for i in range(nk)))
+
+
 def _schedule(causal: bool, sq: int, skv: int, d: int,
-              itemsize: int) -> Schedule:
+              itemsize: int, window: int | None = None) -> Schedule:
     """Tile sizes, residency and the number of backward passes, from the
-    shapes alone (a tuning record keeps its precedence over the menus)."""
+    shapes alone (a tuning record keeps its precedence over the menus).
+    A ``window`` takes causal self-attention only."""
+    if window is not None and (not causal or sq != skv or window < 1):
+        raise ValueError(
+            f"flash_attention: window={window} needs causal "
+            f"self-attention (got causal={causal}, q_seq={sq}, "
+            f"kv_seq={skv})")
     rect = _pick_blocks(sq, skv)
     looped = _looped_tiles(sq) if causal and sq == skv else None
     lanes = -(-d // 128) * 128
@@ -195,7 +242,10 @@ def _schedule(causal: bool, sq: int, skv: int, d: int,
     bwd_bytes = 4 * head + 2 * sq * lanes * 4  # k, v, dk, dv; f32 dk, dv
     kv_resident = looped is not None and fwd_bytes <= _RESIDENT_BUDGET
     one_pass = looped is not None and bwd_bytes <= _RESIDENT_BUDGET
-    if causal:
+    if window is not None:
+        w = min(window, sq)
+        visible = w * (w + 1) / 2 + (sq - w) * w
+    elif causal:
         m = min(sq, skv)
         visible = m * (m + 1) / 2 + (sq - m) * skv
     else:
@@ -209,16 +259,31 @@ def _schedule(causal: bool, sq: int, skv: int, d: int,
         block = bq // _DIAGONAL_CUTS if bq % (128 * _DIAGONAL_CUTS) == 0 \
             else bq
         tile = block * block
-        computed = (sq // block) * (sq // block + 1) // 2
+        if window is None:
+            computed = (sq // block) * (sq // block + 1) // 2
+        else:
+            # the walk's blocks a q block: the edge's, those inside, and
+            # the diagonal's squares (_walk_to_diagonal)
+            cuts = bq // block
+            inside, edges = _inside_and_edges(bq, window)
+            computed = sum(
+                cuts * (cuts + 1) // 2 + cuts * cuts
+                * (min(qi, inside) + min(max(qi - inside, 0), edges))
+                for qi in range(sq // bq))
     else:
         (bq, bk), tile = rect, rect[0] * rect[1]
         nk = skv // bk
-        computed = sum(min(nk, (qi * bq + bq - 1) // bk + 1) if causal
-                       else nk for qi in range(sq // bq))
+        if window is None:
+            computed = sum(min(nk, (qi * bq + bq - 1) // bk + 1) if causal
+                           else nk for qi in range(sq // bq))
+        else:
+            computed = sum((qi * bq + bq - 1) // bk
+                           - max(qi * bq - window + 1, 0) // bk + 1
+                           for qi in range(sq // bq))
     return Schedule(bq, bk, *((side, bwd_step) if one_pass else rect),
                     block=block, kv_resident=kv_resident,
                     one_pass_backward=one_pass, tiles_computed=computed,
-                    tiles_causal=visible / tile)
+                    tiles_visible=visible / tile, window=window)
 
 
 _NEG = -1e9  # finite mask value, matches parallel/sequence.py
@@ -257,30 +322,51 @@ _NN = ((1,), (0,))      # a @ b
 _TN = ((0,), (0,))      # a.T @ b
 
 
+def _mask(q_dim, q0_less_k0, window):
+    """What ``_scores`` takes for a tile that needs a mask."""
+    return (q_dim, q0_less_k0) if window is None \
+        else (q_dim, q0_less_k0, window)
+
+
 def _scores(a, b, s_scale, diag):
     """Scaled scores of rows ``a`` against rows ``b``. ``diag`` is None
-    for a tile wholly below the diagonal, else (dim of the q rows,
-    position of q row 0 less position of k row 0): only such a tile
-    builds the iotas and the select."""
+    for a tile wholly below the diagonal (and inside the window), else
+    (dim of the q rows, position of q row 0 less position of k row 0)
+    and, under a window W, W (``_mask``): only such a tile builds the
+    iotas and the select."""
     s = _dot(a, b, _NT)
     if s_scale != 1.0:
         s = s * s_scale
     if diag is not None:
-        q_dim, q0_less_k0 = diag
+        q_dim, q0_less_k0, *window = diag
         ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_dim)
                  - jax.lax.broadcasted_iota(jnp.int32, s.shape, q_dim))
-        s = jnp.where(ahead > q0_less_k0, _NEG, s)   # kpos > qpos
+        masked = ahead > q0_less_k0                     # kpos > qpos
+        if window:                                      # qpos - kpos >= W
+            masked = masked | (ahead <= q0_less_k0 - window[0])
+        s = jnp.where(masked, _NEG, s)
     return s
 
 
-def _when_visible(compute, qi, ki, bq, bk):
-    """Streamed causal grid step: skip a tile above the diagonal, mask
-    one the diagonal cuts, run the rest bare."""
-    below = ki * bk + bk - 1 <= qi * bq
-    pl.when(below)(lambda: compute(False))
-    pl.when(jnp.logical_and(jnp.logical_not(below),
-                            ki * bk <= qi * bq + bq - 1))(
-        lambda: compute(True))
+def _when_visible(compute, qi, ki, bq, bk, window=None, valid=None):
+    """Streamed causal grid step: skip a tile above the diagonal (or
+    wholly behind the window, or not ``valid``), mask one the diagonal
+    or the window's edge cuts, run the rest bare."""
+    if window is None:
+        below = ki * bk + bk - 1 <= qi * bq
+        pl.when(below)(lambda: compute(False))
+        pl.when(jnp.logical_and(jnp.logical_not(below),
+                                ki * bk <= qi * bq + bq - 1))(
+            lambda: compute(True))
+        return
+    seen = (ki * bk <= qi * bq + bq - 1) \
+        & (ki * bk + bk - 1 >= qi * bq - window + 1)
+    if valid is not None:
+        seen = seen & valid
+    bare = seen & (ki * bk + bk - 1 <= qi * bq) \
+        & (ki * bk >= qi * bq + bq - window)
+    pl.when(bare)(lambda: compute(False))
+    pl.when(seen & jnp.logical_not(bare))(lambda: compute(True))
 
 
 # --------------------------------------------------------------------------
@@ -342,21 +428,27 @@ def _softmax_finish(o_ref, lse_ref, m_scr, l_scr, acc_scr):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, causal, nk, bq, bk):
+                m_scr, l_scr, acc_scr, *, scale, causal, nk, bq, bk,
+                window=None):
+    """One tile of the streamed forward. Under a ``window`` grid step j
+    is K block ``_first_k + j`` of the q block's band, ``nk`` steps."""
     qi, ki = pl.program_id(1), pl.program_id(2)
-    pl.when(ki == 0)(lambda: _softmax_init(m_scr, l_scr, acc_scr))
+    j = ki
+    if window is not None:
+        ki = _first_k(qi, bq, bk, window) + j
+    pl.when(j == 0)(lambda: _softmax_init(m_scr, l_scr, acc_scr))
 
     def compute(masked):
         q, s_scale = _fold_scale(q_ref[0], scale)
-        diag = (0, qi * bq - ki * bk) if masked else None
+        diag = _mask(0, qi * bq - ki * bk, window) if masked else None
         _softmax_step(q, [((0, bq), k_ref[0], v_ref[0], diag)], m_scr,
                       l_scr, acc_scr, s_scale)
 
     if causal:
-        _when_visible(compute, qi, ki, bq, bk)
+        _when_visible(compute, qi, ki, bq, bk, window)
     else:
         compute(False)
-    pl.when(ki == nk - 1)(
+    pl.when(j == nk - 1)(
         lambda: _softmax_finish(o_ref, lse_ref, m_scr, l_scr, acc_scr))
 
 
@@ -369,29 +461,57 @@ def _one_of(index, n, fn):
         pl.when(index == c)(functools.partial(fn, c))
 
 
-def _walk_to_diagonal(qi, bq, bk, step):
+def _walk_to_diagonal(qi, bq, bk, step, window=None):
     """The K rows q block ``qi`` (bq rows) of causal self-attention sees,
     as calls of ``step([(first row, rows, masked), ...])``: bk rows a
     step (a multiple of bq) in a loop while a step lies wholly below the
     diagonal; then ONE step with what is left below it and the bq x bq
-    block on it, which alone is masked. What is left is 0 .. bk/bq - 1
-    blocks: one static body each, so every slice has a static size."""
+    block on it, which alone is masked (``masked`` True). What is left
+    is 0 .. bk/bq - 1 blocks: one static body each, so every slice has a
+    static size.
+
+    Under a ``window`` W the walk starts at the block holding key
+    qi bq - W + 1: first ONE step with the blocks the window's edge cuts
+    (``masked`` ``EDGE``; 1 or 2 of them, ``_inside_and_edges``, fewer
+    near the sequence's start), then the loop over the blocks wholly
+    inside every row's window, then the diagonal's step. Blocks behind
+    the window are never named."""
     wide = bk // bq
-    full = qi // wide
-    jax.lax.fori_loop(
-        0, full, lambda t, c: (step([(t * bk, bk, False)]), c)[1], 0)
-    _one_of(qi - full * wide, wide, lambda left: step(
-        [(full * bk, left * bq, False)] * (left > 0)
+    if window is None:
+        full = qi // wide
+        jax.lax.fori_loop(
+            0, full, lambda t, c: (step([(t * bk, bk, False)]), c)[1], 0)
+        _one_of(qi - full * wide, wide, lambda left: step(
+            [(full * bk, left * bq, False)] * (left > 0)
+            + [(qi * bq, bq, True)]))
+        return
+    inside, edges = _inside_and_edges(bq, window)
+    n_in = jnp.minimum(qi, inside)
+    n_edge = jnp.clip(qi - inside, 0, edges)
+    first = qi - n_in                     # the first block wholly inside
+    for c in range(1, edges + 1):
+        pl.when(n_edge == c)(functools.partial(
+            lambda c: step([((first - c) * bq, c * bq, EDGE)]), c))
+    full = n_in // wide
+    jax.lax.fori_loop(0, full, lambda t, c: (
+        step([(first * bq + t * bk, bk, False)]), c)[1], 0)
+    _one_of(n_in - full * wide, wide, lambda left: step(
+        [(first * bq + full * bk, left * bq, False)] * (left > 0)
         + [(qi * bq, bq, True)]))
 
 
+#: ``_walk_to_diagonal``'s mark of a piece the window's edge cuts
+EDGE = "edge"
+
+
 def _walk_forward(q, s_scale, k_ref, v_ref, qi, m_scr, l_scr, acc_scr, *,
-                  bq, bk, block):
+                  bq, bk, block, window=None):
     """The online-softmax steps of q block ``qi`` (bq rows, counted from
     row 0 of ``k_ref``) of causal self-attention against K/V in VMEM
     (``_walk_to_diagonal``). The bq x bq block on the diagonal is cut
     into ``block``-sided squares: each group of ``block`` q rows takes
-    the K rows up to its own square, which alone is masked."""
+    the K rows up to its own square, which alone is masked. Blocks the
+    window's edge cuts are masked whole."""
 
     def rows(at, n):
         at = pl.multiple_of(at, block)
@@ -403,24 +523,35 @@ def _walk_forward(q, s_scale, k_ref, v_ref, qi, m_scr, l_scr, acc_scr, *,
             if not masked:
                 split.append(((0, bq), *rows(at, n), None))
                 continue
+            if masked is EDGE:
+                split.append(((0, bq), *rows(at, n),
+                              _mask(0, qi * bq - at, window)))
+                continue
             for lo in range(0, bq, block):
                 # q rows lo.. sit lo rows below the piece's first K row
                 split.append(((lo, lo + block), *rows(at, lo + block),
-                              (0, lo)))
+                              _mask(0, lo, window)))
         _softmax_step(q, split, m_scr, l_scr, acc_scr, s_scale)
 
-    _walk_to_diagonal(qi, bq, bk, step)
+    _walk_to_diagonal(qi, bq, bk, step, window)
 
 
 def _fwd_looped_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       m_scr, l_scr, acc_scr, *, scale, bq, bk, block):
+                       m_scr, l_scr, acc_scr, *, scale, bq, bk, block,
+                       window=None):
     """One q block of causal self-attention against the head's K/V in
     VMEM (``_walk_forward``)."""
     _softmax_init(m_scr, l_scr, acc_scr)
     q, s_scale = _fold_scale(q_ref[0], scale)
     _walk_forward(q, s_scale, k_ref, v_ref, pl.program_id(1), m_scr, l_scr,
-                  acc_scr, bq=bq, bk=bk, block=block)
+                  acc_scr, bq=bq, bk=bk, block=block, window=window)
     _softmax_finish(o_ref, lse_ref, m_scr, l_scr, acc_scr)
+
+
+def _name(kernel: str, window) -> str:
+    """A kernel's name in the trace: windowed calls run under names of
+    their own, which ``flash_attention_(fwd|dq|dkdv)`` does not match."""
+    return f"flash_{'attention' if window is None else 'window'}_{kernel}"
 
 
 @functools.lru_cache(maxsize=64)
@@ -438,6 +569,7 @@ def _fwd_call(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
     scratch = [pltpu.VMEM((bq, 1), jnp.float32),
                pltpu.VMEM((bq, 1), jnp.float32),
                pltpu.VMEM((bq, d), jnp.float32)]
+    window = sched.window
     if sched.kv_resident:
         q_spec = pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0))
         kv_spec = pl.BlockSpec((1, skv, d), lambda b, i: (b, 0, 0))
@@ -445,7 +577,7 @@ def _fwd_call(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
         # (sq, 1) column pads every value to a 128-lane row in HBM
         return pl.pallas_call(
             functools.partial(_fwd_looped_kernel, scale=scale, bq=bq,
-                              bk=bk, block=sched.block),
+                              bk=bk, block=sched.block, window=window),
             grid=(bh, nq),
             in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=[q_spec, pl.BlockSpec((1, 1, bq),
@@ -456,10 +588,16 @@ def _fwd_call(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
-            name="flash_attention_fwd",
+            name=_name("fwd", window),
         )
     q_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
-    if causal:
+    if window is not None:
+        # the band: K block _first_k + j, clamped at the diagonal
+        nk = _bands(sq, bq, bk, window)[0]
+        kv_spec = pl.BlockSpec((1, bk, d), lambda b, i, j: (
+            b, jnp.minimum(_first_k(i, bq, bk, window) + j,
+                           (i * bq + bq - 1) // bk), 0))
+    elif causal:
         # a tile above the diagonal re-names the last one below it: the
         # block index does not change, so nothing is fetched
         kv_spec = pl.BlockSpec(
@@ -469,7 +607,7 @@ def _fwd_call(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
         kv_spec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, nk=nk,
-                          bq=bq, bk=bk),
+                          bq=bq, bk=bk, window=window),
         grid=(bh, nq, nk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec,
@@ -478,15 +616,15 @@ def _fwd_call(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
                                                     jnp.float32)],
         scratch_shapes=scratch,
         interpret=interpret,
-        name="flash_attention_fwd",
+        name=_name("fwd", window),
     )
 
 
-def _fwd(q, k, v, scale, causal, interpret):
+def _fwd(q, k, v, scale, causal, interpret, window=None):
     from bigdl_tpu.observability import trace
     bh, sq, d = q.shape
     skv = k.shape[1]
-    sched = _schedule(causal, sq, skv, d, q.dtype.itemsize)
+    sched = _schedule(causal, sq, skv, d, q.dtype.itemsize, window)
     # python runs this when the kernel is traced for a compile, never in
     # a step: the schedule is static
     trace.instant("flash_schedule", cat="kernels", sq=sq, skv=skv, d=d,
@@ -502,16 +640,20 @@ def _fwd(q, k, v, scale, causal, interpret):
 # --------------------------------------------------------------------------
 
 def _backward_piece(q, do, s_scale, k_ref, v_ref, lse_ref, delta_ref,
-                    dq_scr, dk_scr, dv_scr, rows, lo, masked):
+                    dq_scr, dk_scr, dv_scr, rows, lo, masked, *,
+                    q0_less_k0=0, window=None):
     """Rows ``rows`` of K/V against q rows lo.. of the block, all of the
     backward: the tile is computed TRANSPOSED, k rows by q columns, so
     the q block's row statistics broadcast along sublanes from
     lane-dense (1, bq) rows, the q block and its dO are the stationary
     operand of four of the five matmuls however many K rows stream past
     them, dv and dk are plain matmuls and only dq contracts the tile's
-    first dim."""
+    first dim. A masked piece's first q row sits ``q0_less_k0`` rows
+    below its first K row (0 on the diagonal); ``window`` as
+    ``_mask``'s."""
     k, v = k_ref[0, rows, :], v_ref[0, rows, :]
-    st = _scores(k, q[lo:], s_scale, (1, 0) if masked else None)
+    st = _scores(k, q[lo:], s_scale,
+                 _mask(1, q0_less_k0, window) if masked else None)
     pt = jnp.exp(st - lse_ref[0, :, lo:])                   # (n, bq - lo)
     dv_scr[rows, :] = dv_scr[rows, :] + _dot(
         pt.astype(do.dtype), do[lo:], _NN)
@@ -522,31 +664,37 @@ def _backward_piece(q, do, s_scale, k_ref, v_ref, lse_ref, delta_ref,
 
 
 def _walk_backward(q, do, s_scale, k_ref, v_ref, lse_ref, delta_ref,
-                   dq_scr, dk_scr, dv_scr, qi, *, bq, bk, block):
+                   dq_scr, dk_scr, dv_scr, qi, *, bq, bk, block,
+                   window=None):
     """The backward of q block ``qi`` (counted from row 0 of ``k_ref``)
     of causal self-attention against K/V in VMEM (``_walk_to_diagonal``,
     ``_backward_piece``). The block on the diagonal goes a ``block`` of
-    K rows at a time, each against the q rows from its own square on."""
+    K rows at a time, each against the q rows from its own square on;
+    blocks the window's edge cuts go whole, against every q row."""
 
-    def piece(at, n, lo, masked):
+    def piece(at, n, lo, masked, q0_less_k0=0):
         _backward_piece(q, do, s_scale, k_ref, v_ref, lse_ref, delta_ref,
                         dq_scr, dk_scr, dv_scr,
-                        pl.ds(pl.multiple_of(at, block), n), lo, masked)
+                        pl.ds(pl.multiple_of(at, block), n), lo, masked,
+                        q0_less_k0=q0_less_k0, window=window)
 
     def step(pieces):
         for at, n, masked in pieces:
             if not masked:
                 piece(at, n, 0, False)
                 continue
+            if masked is EDGE:
+                piece(at, n, 0, True, qi * bq - at)
+                continue
             for lo in range(0, bq, block):
                 piece(at + lo, block, lo, True)
 
-    _walk_to_diagonal(qi, bq, bk, step)
+    _walk_to_diagonal(qi, bq, bk, step, window)
 
 
 def _dqdkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
-                   *, scale, nq, bq, bk, block):
+                   *, scale, nq, bq, bk, block, window=None):
     """One q block of causal self-attention against the head's K/V in
     VMEM, all of the backward in one pass (``_walk_backward``). dk and
     dv accumulate over the q blocks of a head in ``dk_scr`` /
@@ -561,7 +709,8 @@ def _dqdkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_scr[:] = jnp.zeros_like(dq_scr)
     q, s_scale = _fold_scale(q_ref[0], scale)
     _walk_backward(q, do_ref[0], s_scale, k_ref, v_ref, lse_ref, delta_ref,
-                   dq_scr, dk_scr, dv_scr, qi, bq=bq, bk=bk, block=block)
+                   dq_scr, dk_scr, dv_scr, qi, bq=bq, bk=bk, block=block,
+                   window=window)
     dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
     # dk saw q * scale when the scale folded; else it takes it here, once
@@ -573,10 +722,15 @@ def _dqdkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, scale, causal, nk, bq, bk):
+               dq_scr, *, scale, causal, nk, bq, bk, window=None):
+    """dq of one streamed tile; under a ``window`` grid step j is K
+    block ``_first_k + j``, as in ``_fwd_kernel``."""
     qi, ki = pl.program_id(1), pl.program_id(2)
+    j = ki
+    if window is not None:
+        ki = _first_k(qi, bq, bk, window) + j
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -584,27 +738,34 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         q, s_scale = _fold_scale(q_ref[0], scale)
         k = k_ref[0]
         s = _scores(q, k, s_scale,
-                    (0, qi * bq - ki * bk) if masked else None)
+                    _mask(0, qi * bq - ki * bk, window) if masked else None)
         p = jnp.exp(s - lse_ref[0])
         dp = _dot(do_ref[0], v_ref[0], _NT)
         ds = (p * (dp - delta_ref[0])).astype(k.dtype)
         dq_scr[:] = dq_scr[:] + _dot(ds, k, _NN)
 
     if causal:
-        _when_visible(compute, qi, ki, bq, bk)
+        _when_visible(compute, qi, ki, bq, bk, window)
     else:
         compute(False)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(j == nk - 1)
     def _finalize():
         dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal, nq, bq, bk):
+                 dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal, nq, bq, bk,
+                 window=None, q_blocks=None):
+    """dk and dv of one streamed tile; under a ``window`` grid step j is
+    q block (K block i's first) + j of ``nq`` steps, ``q_blocks`` in
+    all."""
     ki, qi = pl.program_id(1), pl.program_id(2)
+    j = qi
+    if window is not None:
+        qi = (ki * bk) // bq + j
 
-    @pl.when(qi == 0)
+    @pl.when(j == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -613,19 +774,21 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k, s_scale = _fold_scale(k_ref[0], scale)
         q, do = q_ref[0], do_ref[0]
         s = _scores(q, k, s_scale,
-                    (0, qi * bq - ki * bk) if masked else None)
+                    _mask(0, qi * bq - ki * bk, window) if masked else None)
         p = jnp.exp(s - lse_ref[0])                     # (BQ, BK)
         dv_scr[:] = dv_scr[:] + _dot(p.astype(do.dtype), do, _TN)
         dp = _dot(do, v_ref[0], _NT)
         ds = (p * (dp - delta_ref[0])).astype(q.dtype)
         dk_scr[:] = dk_scr[:] + _dot(ds, q, _TN)
 
-    if causal:
+    if window is not None:
+        _when_visible(compute, qi, ki, bq, bk, window, qi < q_blocks)
+    elif causal:
         _when_visible(compute, qi, ki, bq, bk)
     else:
         compute(False)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(j == nq - 1)
     def _finalize():
         dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -639,6 +802,7 @@ def _bwd_calls(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
     from jax.experimental.pallas import tpu as pltpu
     bq, bk = sched.bwd_bq, sched.bwd_bk
     nq, nk = sq // bq, skv // bk
+    window = sched.window
     q_shape = jax.ShapeDtypeStruct((bh, sq, d), dtype)
     kv_shape = jax.ShapeDtypeStruct((bh, skv, d), dtype)
     if sched.one_pass_backward:
@@ -648,7 +812,8 @@ def _bwd_calls(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
         stat_spec = pl.BlockSpec((1, 1, bq), lambda b, i: (b * nq + i, 0, 0))
         return (pl.pallas_call(
             functools.partial(_dqdkdv_kernel, scale=scale, nq=nq,
-                              bq=bq, bk=bk, block=sched.block),
+                              bq=bq, bk=bk, block=sched.block,
+                              window=window),
             grid=(bh, nq),
             in_specs=[q_spec, head_spec, head_spec, q_spec, stat_spec,
                       stat_spec],
@@ -661,12 +826,25 @@ def _bwd_calls(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
-            name="flash_attention_dqdkdv",
+            name=_name("dqdkdv", window),
         ),)
 
     # streamed; causal index maps re-name the nearest needed block where
     # a tile lies above the diagonal, so it is not fetched
-    if causal:
+    q_steps, k_steps = nq, nk
+    if window is not None:
+        # the grids span the bands; a step past a band's end re-names
+        # its last block, as a step above the diagonal does
+        k_steps, q_steps = _bands(sq, bq, bk, window)
+
+        def kv_of(i, j):
+            return jnp.minimum(_first_k(i, bq, bk, window) + j,
+                               (i * bq + bq - 1) // bk)
+
+        def q_of(i, j):
+            return jnp.minimum((i * bk) // bq + j,
+                               _last_q(i, bq, bk, window, nq))
+    elif causal:
         def kv_of(i, j):
             return jnp.minimum(j, (i * bq + bq - 1) // bk)
 
@@ -678,15 +856,15 @@ def _bwd_calls(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
     kv_spec_q = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, kv_of(i, j), 0))
     row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
     dq_call = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal, nk=nk,
-                          bq=bq, bk=bk),
-        grid=(bh, nq, nk),
+        functools.partial(_dq_kernel, scale=scale, causal=causal,
+                          nk=k_steps, bq=bq, bk=bk, window=window),
+        grid=(bh, nq, k_steps),
         in_specs=[q_spec, kv_spec_q, kv_spec_q, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=q_shape,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-        name="flash_attention_dq",
+        name=_name("dq", window),
     )
     # dk/dv: grid walks q blocks innermost for each k block
     q_spec_k = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, q_of(i, j), 0))
@@ -694,9 +872,10 @@ def _bwd_calls(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
     row_spec_k = pl.BlockSpec((1, bq, 1),
                               lambda b, i, j: (b, q_of(i, j), 0))
     dkdv_call = pl.pallas_call(
-        functools.partial(_dkdv_kernel, scale=scale, causal=causal, nq=nq,
-                          bq=bq, bk=bk),
-        grid=(bh, nk, nq),
+        functools.partial(_dkdv_kernel, scale=scale, causal=causal,
+                          nq=q_steps, bq=bq, bk=bk, window=window,
+                          q_blocks=nq),
+        grid=(bh, nk, q_steps),
         in_specs=[q_spec_k, kv_spec, kv_spec, q_spec_k, row_spec_k,
                   row_spec_k],
         out_specs=[kv_spec, kv_spec],
@@ -704,12 +883,12 @@ def _bwd_calls(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
-        name="flash_attention_dkdv",
+        name=_name("dkdv", window),
     )
     return dq_call, dkdv_call
 
 
-def _bwd(scale, causal, interpret, res, g):
+def _bwd(scale, causal, interpret, window, res, g):
     """VJP for (o, lse) outputs.
 
     The lse cotangent folds into the existing kernels: with lse an
@@ -721,7 +900,7 @@ def _bwd(scale, causal, interpret, res, g):
     g, g_lse = g
     bh, sq, d = q.shape
     skv = k.shape[1]
-    sched = _schedule(causal, sq, skv, d, q.dtype.itemsize)
+    sched = _schedule(causal, sq, skv, d, q.dtype.itemsize, window)
     calls = _bwd_calls(bh, sq, skv, d, q.dtype, scale, causal, interpret,
                        sched)
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -738,13 +917,13 @@ def _bwd(scale, causal, interpret, res, g):
 # public entry: (B, S, H, D) api matching parallel/sequence.py
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_bhsd(q, k, v, scale, causal, interpret):
-    return _fwd(q, k, v, scale, causal, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_bhsd(q, k, v, scale, causal, interpret, window):
+    return _fwd(q, k, v, scale, causal, interpret, window)
 
 
-def _flash_fwd(q, k, v, scale, causal, interpret):
-    o, lse = _fwd(q, k, v, scale, causal, interpret)
+def _flash_fwd(q, k, v, scale, causal, interpret, window):
+    o, lse = _fwd(q, k, v, scale, causal, interpret, window)
     return (o, lse), (q, k, v, o, lse)
 
 
@@ -752,7 +931,8 @@ _flash_bhsd.defvjp(_flash_fwd, _bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
-                    scale: float | None = None, interpret: bool = False):
+                    scale: float | None = None, interpret: bool = False,
+                    window: int | None = None):
     """Tiled online-softmax attention over (B, S, H, D).
 
     Drop-in for ``dot_product_attention`` (zero offsets); differentiable
@@ -761,15 +941,18 @@ def flash_attention(q, k, v, *, causal: bool = False,
     16) and a head_dim multiple of 64 (``flash_supported``); the
     schedule follows from the shapes (``_schedule``) unless an autotuned
     record (``bigdl_tpu/tuning``) overrides the tile sizes.
+    ``window`` W (causal self-attention only): query i sees keys
+    i - W < j <= i, under the ``flash_window_*`` kernels.
     """
     o, _ = flash_attention_with_lse(q, k, v, causal=causal, scale=scale,
-                                    interpret=interpret)
+                                    interpret=interpret, window=window)
     return o
 
 
 def flash_attention_with_lse(q, k, v, *, causal: bool = False,
                              scale: float | None = None,
-                             interpret: bool = False):
+                             interpret: bool = False,
+                             window: int | None = None):
     """``flash_attention`` that also returns the per-row logsumexp
     (B, S, H) of the scaled scores — the statistic blockwise/ring
     attention needs to merge partial results across sequence shards
@@ -782,7 +965,7 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = False,
         return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
 
     o, lse = _flash_bhsd(fold(q), fold(k), fold(v), scale, causal,
-                         interpret)
+                         interpret, window)
     o = o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     lse = lse.reshape(b, h, sq).transpose(0, 2, 1)
     return o, lse

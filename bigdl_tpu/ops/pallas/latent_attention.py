@@ -105,7 +105,7 @@ def latent_schedule(sq: int, dn: int, dr: int, dv: int,
             f"does not fit VMEM (resident {fwd} bytes forward, {bwd} "
             "backward)")
     return LatentSchedule(local.bq, local.bk, local.bwd_bk, local.block,
-                          fwd, bwd, local.tiles_computed, local.tiles_causal)
+                          fwd, bwd, local.tiles_computed, local.tiles_visible)
 
 
 def _score_tile(an, ar, bn, br, diag):

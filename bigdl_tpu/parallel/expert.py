@@ -59,9 +59,11 @@ MOE_STATE_KEYS = ("moe_aux", "moe_dropped_rank_frac",
 
 
 #: what ``ExpertShare``'s experts compute, by the weight stacks each kind
-#: holds: "silu" a SwiGLU (gate, up, down), "relu2" W_down relu(W_up x)^2
-#: (up, down: no gate)
+#: holds: "silu" a SwiGLU (gate, up, down), "relu" a ReGLU on the same
+#: three stacks, W_down(relu(W_gate x) * W_up x), "relu2" W_down
+#: relu(W_up x)^2 (up, down: no gate)
 EXPERT_WEIGHTS = {"silu": ("gate_weight", "up_weight", "down_weight"),
+                  "relu": ("gate_weight", "up_weight", "down_weight"),
                   "relu2": ("up_weight", "down_weight")}
 
 #: module-state keys ``ExpertShare`` maintains (floats, as above)
@@ -597,9 +599,18 @@ class ExpertShare(_Module):
     layer's shares it must be counted once.
 
     ``expert_act`` chooses the experts' kind at construction
-    (``EXPERT_WEIGHTS``): ``"silu"``, the SwiGLU above, or ``"relu2"``,
-    W_down,e relu(W_up,e x)^2 with no gate (Nemotron-H): two grouped
-    products a span, and a ``Relu2FFN`` shared expert."""
+    (``EXPERT_WEIGHTS``): ``"silu"``, the SwiGLU above; ``"relu"``, a
+    ReGLU W_down,e( relu(W_gate,e x) * (W_up,e x) ) on the same three
+    stacks and grouped products (SmallThinker), its shared expert a
+    ``GatedFFN(act="relu")``; or ``"relu2"``, W_down,e relu(W_up,e x)^2
+    with no gate (Nemotron-H): two grouped products a span, and a
+    ``Relu2FFN`` shared expert.
+
+    ``apply(..., route_from=r)`` routes each token by ``r`` (the same
+    shape as ``x``) and computes the experts on ``x``: a block whose
+    router reads another tensor than its experts (SmallThinker's router
+    reads the ATTENTION's normed input, its experts the normed stream
+    after it). Without it both read ``x``."""
 
     def __init__(self, d_model: int, d_ff: int, experts_total: int,
                  top_k: int, *, experts_held: int | None = None,
@@ -627,8 +638,9 @@ class ExpertShare(_Module):
         self.shared = None
         if shared_width:
             from bigdl_tpu.nn.linear import GatedFFN, Relu2FFN
-            self.shared = (GatedFFN if expert_act == "silu" else Relu2FFN)(
-                self.d_model, int(shared_width))
+            self.shared = Relu2FFN(self.d_model, int(shared_width)) \
+                if expert_act == "relu2" else \
+                GatedFFN(self.d_model, int(shared_width), act=expert_act)
 
     def init(self, rng):
         from bigdl_tpu.nn import init as init_mod
@@ -701,9 +713,11 @@ class ExpertShare(_Module):
             up, down = products
             return _combine(down(relu2(up(x))), cw, idx, at)
         gate, up, down = products
-        return _combine(down(jax.nn.silu(gate(x)) * up(x)), cw, idx, at)
+        act = jax.nn.relu if self.expert_act == "relu" else jax.nn.silu
+        return _combine(down(act(gate(x)) * up(x)), cw, idx, at)
 
-    def apply(self, params, state, x, *, training=False, rng=None):
+    def apply(self, params, state, x, *, training=False, rng=None,
+              route_from=None):
         from bigdl_tpu.observability import trace
         from bigdl_tpu.tensor import activation_dtype, compute_dtype
         d, k, held = x.shape[-1], self.top_k, self.experts_held
@@ -712,6 +726,9 @@ class ExpertShare(_Module):
                              f"{self.d_model}, got feature dim {d}")
         tokens = x.reshape(-1, d)
         t = tokens.shape[0]
+        if route_from is not None and route_from.shape != x.shape:
+            raise ValueError(f"ExpertShare: route_from {route_from.shape} "
+                             f"is not the experts' input's {x.shape}")
         first = _chunk_rows(t * k, held, self.experts_total,
                             self.bias_update_rate is not None)
         # python runs this when the layer is traced for a compile, never
@@ -727,7 +744,9 @@ class ExpertShare(_Module):
                       expert_act=self.expert_act)
         bias = state.get(BIAS_STATE_KEY)
         with jax.named_scope("moe_router"):
-            top, c = self.route(params, tokens, bias)
+            top, c = self.route(
+                params, tokens if route_from is None
+                else route_from.reshape(-1, d), bias)
             local = top - self.experts_offset
             here = (local >= 0) & (local < held)
             # assignments by expert held, those for elsewhere last

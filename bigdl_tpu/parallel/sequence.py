@@ -52,11 +52,16 @@ def _qkv_spec(mesh, axis, batch_axis):
 def dot_product_attention(q, k, v, *, causal: bool = False,
                           scale: float | None = None,
                           q_offset: int = 0, kv_offset: int = 0,
-                          flash: str | bool = "auto"):
+                          flash: str | bool = "auto",
+                          window: int | None = None):
     """Attention over (B, S, H, D).
 
     ``q_offset``/``kv_offset`` are the global positions of element 0 —
     how causal masking stays correct on sequence shards.
+
+    ``window`` W (causal only): query i sees keys j with i - W < j <= i,
+    the sliding window of docs/window_attention.md; the kernel runs it
+    as ``flash_window_*``, the XLA path masks ``i - j >= W`` too.
 
     ``flash="auto"`` routes to the fused Pallas kernel
     (ops/pallas/flash_attention.py) on TPU whenever shapes allow —
@@ -67,11 +72,15 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
     path); both share bf16-operand matmul rounding, so they agree to
     ~1e-3 under a temperate softmax.
     """
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"dot_product_attention: window={window} needs "
+                         f"causal=True and W >= 1 (causal={causal})")
     if flash:
         from bigdl_tpu.ops.pallas.flash_attention import (flash_attention,
                                                           flash_supported)
         offsets_ok = not causal or (q_offset == 0 and kv_offset == 0)
-        supported = offsets_ok and flash_supported(q, k)
+        supported = offsets_ok and flash_supported(q, k) and (
+            window is None or q.shape[1] == k.shape[1])
         if flash is True and not supported:
             raise ValueError(
                 f"flash=True but the kernel does not support this call: "
@@ -82,7 +91,7 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
         if supported:
             from bigdl_tpu.ops.pallas.per_shard import kernel_shards
             kernel = functools.partial(flash_attention, causal=causal,
-                                       scale=scale)
+                                       scale=scale, window=window)
             # under a multi-device mesh the kernel runs once per
             # (batch, head) shard — jax refuses to partition it itself
             shards = kernel_shards(q.shape, head_dim=2)
@@ -95,9 +104,20 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
     if causal:
         qpos = q_offset + jnp.arange(q.shape[1])[:, None]
         kpos = kv_offset + jnp.arange(k.shape[1])[None, :]
-        s = jnp.where((kpos > qpos)[None, None], _NEG, s)
+        hidden = kpos > qpos
+        if window is not None:
+            hidden = hidden | (qpos - kpos >= window)
+        s = jnp.where(hidden[None, None], _NEG, s)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(f32)).astype(q.dtype)
+
+
+def _no_window(name: str, window) -> None:
+    """The sequence-parallel cores take no window: refuse one, never
+    ignore it."""
+    if window is not None:
+        raise ValueError(f"{name}: a sliding window ({window}) is not "
+                         "supported across sequence shards (ROADMAP B6)")
 
 
 def _merge_blocks(o, lse, o_t, lse_t):
@@ -248,8 +268,11 @@ def ring_attention(q, k, v, *, causal: bool = False,
                    scale: float | None = None, axis: str = "seq",
                    mesh: Mesh | None = None, batch_axis="auto",
                    flash: str | bool = "auto", interpret: bool = False,
-                   kv_groups: int = 1):
+                   kv_groups: int = 1, window: int | None = None):
     """Sequence-parallel attention; q/k/v sharded on dim 1 over ``axis``.
+
+    A sliding ``window`` is refused by name: the ring has no schedule
+    that skips the hops wholly behind it yet (ROADMAP B6).
 
     Call eagerly with global arrays (this wrapper shards them) or use
     ``ring_attention_sharded`` inside an existing shard_map/pjit region.
@@ -260,6 +283,7 @@ def ring_attention(q, k, v, *, causal: bool = False,
     XLA online-softmax body; ``flash=True`` forces the kernel
     (``interpret=True`` for CPU testing).
     """
+    _no_window("ring_attention", window)
     mesh = mesh or get_mesh()
     n = mesh.shape[axis]
     if q.shape[1] % n or k.shape[1] % n:
@@ -305,8 +329,11 @@ def ring_attention_sharded(q, k, v, *, causal: bool = False,
 def ulysses_attention(q, k, v, *, causal: bool = False,
                       scale: float | None = None, axis: str = "seq",
                       mesh: Mesh | None = None, batch_axis="auto",
-                      kv_groups: int = 1):
+                      kv_groups: int = 1, window: int | None = None):
     """All-to-all sequence parallelism (DeepSpeed-Ulysses scheme).
+
+    A sliding ``window`` is refused by name (ROADMAP B6), as
+    ``ring_attention`` refuses it.
 
     Re-shards (B, S/N, H, D) -> (B, S, H/N, D) with one all_to_all, runs
     exact local attention over the full sequence for its head group, and
@@ -321,6 +348,7 @@ def ulysses_attention(q, k, v, *, causal: bool = False,
     chunk. Falls back to pre-widening when the kv heads don't divide
     the axis (e.g. MQA on a mesh wider than the kv-head count).
     """
+    _no_window("ulysses_attention", window)
     mesh = mesh or get_mesh()
     n = mesh.shape[axis]
     if q.shape[2] % n:

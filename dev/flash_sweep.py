@@ -174,7 +174,7 @@ def main(argv=None) -> int:
                              "kv_resident": sched.kv_resident,
                              "one_pass_backward": sched.one_pass_backward,
                              "waste": sched.tiles_computed
-                             / sched.tiles_causal,
+                             / sched.tiles_visible,
                              "est_vmem_mb": est(c) / 2 ** 20},
                             fa, shape, True, ref)
                 finally:

@@ -128,15 +128,15 @@ def test_schedule_wastes_the_diagonal_only():
     assert s.bq % s.block == 0
     n = 2048 // s.block
     assert s.tiles_computed == n * (n + 1) // 2
-    assert 1.0 < s.tiles_computed / s.tiles_causal <= 1.13
+    assert 1.0 < s.tiles_computed / s.tiles_visible <= 1.13
     # what streams keeps the rectangular menu; no mask, no waste
     r = _schedule(False, 2048, 2048, 64, 2)
     assert (r.bq, r.bk, r.block) == (512, 1024, 0) and not r.kv_resident
-    assert r.tiles_computed == r.tiles_causal == 8
+    assert r.tiles_computed == r.tiles_visible == 8
     # causal cross-length calls stream too: the parent's 1.5
     x = _schedule(True, 2048, 4096, 64, 2)
     assert not x.kv_resident and not x.one_pass_backward
-    assert x.tiles_computed / x.tiles_causal == pytest.approx(1.5, abs=1e-3)
+    assert x.tiles_computed / x.tiles_visible == pytest.approx(1.5, abs=1e-3)
 
 
 def test_schedule_follows_the_vmem_budget():
@@ -195,7 +195,7 @@ def test_schedule_is_stated_once_a_trace():
     assert a["kv_resident"] and a["one_pass_backward"]
     n = 512 // a["block"]
     assert a["tiles_computed"] == n * (n + 1) // 2
-    assert a["tiles_computed"] / a["tiles_causal"] == pytest.approx(
+    assert a["tiles_computed"] / a["tiles_visible"] == pytest.approx(
         a["tiles_computed"] * a["block"] ** 2 / (512 * 513 / 2))
 
 

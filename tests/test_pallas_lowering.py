@@ -154,6 +154,40 @@ class TestFlashCompilesForTheV5e:
         jax.jit(fn).lower(q, kv, kv).compile()
 
 
+class TestWindowCompilesForTheV5e:
+    """Mosaic's verdict on the sliding-window flash kernels at the
+    SmallThinker cell's shape (28 heads of 16384 x 128, window 4096),
+    forward and backward in one program, under their own names: the
+    looped forward walks from the window's first block (K/V of a head
+    resident), the backward streams over banded grids; and at a length
+    whose head fits, the one-pass backward. Within the kernels' VMEM
+    limit or Mosaic refuses them here."""
+
+    # (B, S, H, D, W) -> (one backward pass, Mosaic calls, names)
+    @pytest.mark.parametrize("shape,one_pass,names", [
+        ((1, 16384, 28, 128, 4096), False,
+         ("flash_window_fwd", "flash_window_dq", "flash_window_dkdv")),
+        ((1, 8192, 4, 128, 4096), True,
+         ("flash_window_fwd", "flash_window_dqdkdv")),
+        ((1, 16384, 2, 128, 3000), False,
+         ("flash_window_fwd", "flash_window_dq", "flash_window_dkdv")),
+    ], ids=["cell-s16384-w4096", "s8192-w4096", "s16384-w3000"])
+    def test_window_fwd_bwd(self, one_chip, shape, one_pass, names):
+        from bigdl_tpu.ops.pallas.flash_attention import _schedule
+        *qkv, window = shape
+        sched = _schedule(True, qkv[1], qkv[1], qkv[3], 2, window)
+        assert sched.kv_resident and sched.one_pass_backward == one_pass
+        fn = _scalar_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window), 3)
+        x = jax.ShapeDtypeStruct(tuple(qkv), BF16, sharding=one_chip)
+        text = jax.jit(fn).lower(x, x, x).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == \
+            len(names)
+        for name in names:
+            assert name in text, name
+        assert not re.search("flash_attention_(fwd|dq|dkdv)", text)
+
+
 class TestEvaCompilesForTheV5e:
     """Mosaic's verdict on the EVA kernels at the benchmark cell's shape
     (evabyte-6.5b.train.long: 32 heads of 16384 x 128, window 2048, chunk
