@@ -70,6 +70,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
@@ -888,7 +889,7 @@ def _bwd_calls(bh, sq, skv, d, dtype, scale, causal, interpret, sched):
     return dq_call, dkdv_call
 
 
-def _bwd(scale, causal, interpret, window, res, g):
+def _bwd(scale, causal, interpret, window, named, res, g):
     """VJP for (o, lse) outputs.
 
     The lse cotangent folds into the existing kernels: with lse an
@@ -917,13 +918,21 @@ def _bwd(scale, causal, interpret, window, res, g):
 # public entry: (B, S, H, D) api matching parallel/sequence.py
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bhsd(q, k, v, scale, causal, interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bhsd(q, k, v, scale, causal, interpret, window, named):
     return _fwd(q, k, v, scale, causal, interpret, window)
 
 
-def _flash_fwd(q, k, v, scale, causal, interpret, window):
+def _flash_fwd(q, k, v, scale, causal, interpret, window, named):
+    """A whole attention's output and row statistics (``named``) are
+    NAMED, in the folded layouts the backward reads: a recomputed block
+    keeps them (``optim/remat.py``, ``"per_block"``) and runs the kernel
+    once. Ring attention's per-hop partials name nothing: a block would
+    keep every hop's."""
     o, lse = _fwd(q, k, v, scale, causal, interpret, window)
+    if named:
+        o = checkpoint_name(o, "attention_out")
+        lse = checkpoint_name(lse, "attention_stats")
     return (o, lse), (q, k, v, o, lse)
 
 
@@ -944,9 +953,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
     ``window`` W (causal self-attention only): query i sees keys
     i - W < j <= i, under the ``flash_window_*`` kernels.
     """
-    o, _ = flash_attention_with_lse(q, k, v, causal=causal, scale=scale,
-                                    interpret=interpret, window=window)
-    return o
+    return _flash(q, k, v, causal, scale, interpret, window, True)[0]
 
 
 def flash_attention_with_lse(q, k, v, *, causal: bool = False,
@@ -956,8 +963,14 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = False,
     """``flash_attention`` that also returns the per-row logsumexp
     (B, S, H) of the scaled scores — the statistic blockwise/ring
     attention needs to merge partial results across sequence shards
-    (parallel/sequence.py). Both outputs are differentiable.
+    (parallel/sequence.py). Both outputs are differentiable. A partial
+    result names nothing: under ``"per_block"`` a recomputed block runs
+    the kernel again rather than keep each hop's output.
     """
+    return _flash(q, k, v, causal, scale, interpret, window, False)
+
+
+def _flash(q, k, v, causal, scale, interpret, window, named):
     b, sq, h, d = q.shape
     scale = scale if scale is not None else d ** -0.5
 
@@ -965,7 +978,7 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = False,
         return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
 
     o, lse = _flash_bhsd(fold(q), fold(k), fold(v), scale, causal,
-                         interpret, window)
+                         interpret, window, named)
     o = o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     lse = lse.reshape(b, h, sq).transpose(0, 2, 1)
     return o, lse

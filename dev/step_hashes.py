@@ -12,7 +12,11 @@ cell), lowered with ``lowering_platforms=("tpu",)``. A Pallas kernel's
 serialized body carries source file names and line numbers; each body
 is replaced by the sha256 of its assembly without them, so two
 checkouts at different paths, or a kernel file whose lines moved, hash
-alike when the programs are the same. ``--root`` imports the program
+alike when the programs are the same. A private function's numeric
+suffix counts every clash of names while the step was lowered, those of
+functions dropped later too: each is renumbered within its name, so a
+trace that made one more ``_where`` on the way, and kept the same
+program, hashes alike as well. ``--root`` imports the program
 and the benchmark from another checkout (a parent's, unpacked with
 ``git archive``).
 """
@@ -28,7 +32,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 def normalize(text: str) -> str:
     """The lowered text with each Mosaic body as its location-free
-    assembly's sha256."""
+    assembly's sha256 and each private function numbered within its
+    name."""
     from jax._src.lib.mlir import ir
 
     def body(m):
@@ -38,7 +43,14 @@ def normalize(text: str) -> str:
             asm = module.operation.get_asm(enable_debug_info=False)
         return "body:" + hashlib.sha256(asm.encode()).hexdigest()
 
-    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
+    text = re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
+    names, seen = {}, {}
+    for sym in re.findall(r"func\.func private @([A-Za-z0-9_]+)", text):
+        base = re.sub(r"_[0-9]+$", "", sym)
+        seen[base] = seen.get(base, -1) + 1
+        names[sym] = f"{base}.{seen[base]}"
+    return re.sub(r"@([A-Za-z0-9_]+)\b",
+                  lambda m: "@" + names.get(m.group(1), m.group(1)), text)
 
 
 def lowered_step(cell_name: str, topo):

@@ -21,7 +21,9 @@ from benchmarks import manifest, model_setup
 from benchmarks.builders import nemotron as builder
 from benchmarks.reference import nemotron as ref
 from bigdl_tpu.nn import linear, state_space
+from bigdl_tpu.ops.pallas import flash_attention as fa
 from bigdl_tpu.ops.pallas import ssd as kernels
+from bigdl_tpu.parallel import sequence as seq
 from bigdl_tpu.parallel.expert import ExpertShare
 from bigdl_tpu.tensor import DTypePolicy, policy_scope
 
@@ -420,6 +422,29 @@ def test_a_recomputed_block_runs_the_scan_forward_once(system, on_kernel,
     jaxpr = jax.make_jaxpr(jax.grad(_loss(model, data[:1],
                                           labels[:1])))(model.params).jaxpr
     assert kernel_calls(jaxpr) == dict(ssd_fwd=4, ssd_bwd=4)
+
+
+def test_a_recomputed_block_runs_the_flash_forward_once(system, on_kernel,
+                                                         monkeypatch,
+                                                         kernel_calls):
+    """The attention layer on the flash kernels, interpreted, at 128
+    tokens (the shortest sequence they tile): under ``per_block`` ONE
+    ``flash_attention_fwd`` (its output and row statistics are named
+    and kept) and one backward kernel, beside the scan's."""
+    kernel = fa.flash_attention
+    monkeypatch.setattr(seq, "dot_product_attention",
+                        lambda q, k, v, causal, window=None: kernel(
+                            q, k, v, causal=causal, window=window,
+                            interpret=True))
+    model, *_ = system
+    toks = np.random.default_rng(1).integers(1, CFG["vocab_size"] + 1,
+                                             size=(1, 129))
+    data, labels = toks[:, :-1], toks[:, 1:]
+    jaxpr = jax.make_jaxpr(jax.grad(_loss(model, data,
+                                          labels)))(model.params).jaxpr
+    assert kernel_calls(jaxpr) == dict(
+        ssd_fwd=4, ssd_bwd=4, flash_attention_fwd=1,
+        flash_attention_dqdkdv=1)
 
 
 def test_on_the_kernel_the_model_matches_the_chunked_form(system, both,

@@ -99,6 +99,37 @@ class TestRingAttention:
         with pytest.raises(ValueError, match="not divisible"):
             ring_attention(q, q, q, mesh=mesh)
 
+    def test_a_recomputed_block_keeps_nothing_of_the_flash_hops(self):
+        """Ring attention's per-hop ``flash_attention_with_lse`` names
+        nothing: under ``per_block``'s policy a recomputed block saves
+        what a checkpoint that keeps nothing saves, not each hop's
+        output. The whole attention on the kernel keeps its output and
+        row statistics. Abstract: nothing runs."""
+        from bigdl_tpu.ops.pallas.flash_attention import flash_attention
+        from bigdl_tpu.optim import remat
+        mesh = _seq_mesh()
+        q, k, v = _qkv(b=1, s=1024, h=2, d=64, seed=6)
+        kept = jax.checkpoint_policies.save_only_these_names(
+            *remat.KEPT_NAMES)
+        nothing = jax.checkpoint_policies.nothing_saveable
+
+        def held(core, policy):
+            fn = jax.checkpoint(lambda q, k, v: jnp.sum(core(q, k, v)),
+                                policy=policy)
+            return remat.saved_residual_bytes(fn, q, k, v)
+
+        def ring(q, k, v):
+            return ring_attention(q, k, v, causal=True, mesh=mesh,
+                                  flash=True, interpret=True)
+
+        def whole(q, k, v):
+            return flash_attention(q, k, v, causal=True, interpret=True)
+
+        assert held(ring, kept) == held(ring, nothing)
+        # o (2 heads, 1024, 64) and lse (2 heads, 1024), float32
+        assert held(whole, kept) - held(whole, nothing) == \
+            2 * 1024 * 64 * 4 + 2 * 1024 * 4
+
 
 class TestUlyssesAttention:
     @pytest.mark.parametrize("causal", [False, True])

@@ -429,6 +429,55 @@ def test_the_model_as_built(system):
         decode_meta(model)
 
 
+@pytest.fixture()
+def on_kernels(monkeypatch):
+    """The model's attention on the flash kernels, interpreted: the path
+    it takes on the TPU."""
+    kernel = fa.flash_attention
+    monkeypatch.setattr(seq, "dot_product_attention",
+                        lambda q, k, v, causal, window=None: kernel(
+                            q, k, v, causal=causal, window=window,
+                            interpret=True))
+
+
+def _tokens():
+    """One row of 128 tokens: the shortest sequence the kernels tile."""
+    toks = np.random.default_rng(1).integers(1, CFG["vocab_size"] + 1,
+                                             size=(1, 129))
+    return toks[:, :-1].astype(np.int32), toks[:, 1:].astype(np.int32)
+
+
+def test_a_recomputed_block_runs_the_attention_forward_once(
+        system, on_kernels, kernel_calls):
+    """In the gradient's jaxpr under ``per_block`` each window layer has
+    ONE ``flash_window_fwd`` and each global layer ONE
+    ``flash_attention_fwd`` (what the kernel made is named and kept),
+    beside one backward kernel each; without the names the forward
+    would run twice."""
+    model, *_ = system
+    assert model.remat_policy == "per_block"
+    calls = kernel_calls(jax.make_jaxpr(jax.grad(_loss(
+        model, *_tokens())))(model.params).jaxpr)
+    assert calls == dict(flash_window_fwd=6, flash_window_dqdkdv=6,
+                         flash_attention_fwd=2, flash_attention_dqdkdv=2)
+
+
+def test_on_the_kernels_recomputation_is_bit_identical_to_none(system,
+                                                               on_kernels):
+    model, *_ = system
+    plain = builder.build(CFG).set_remat(None)
+    plain.sync(model.params, model.state)
+    data, labels = _tokens()
+    with_remat = jax.value_and_grad(_loss(model, data, labels))(
+        model.params)
+    without = jax.value_and_grad(_loss(plain, data, labels))(model.params)
+    leaves = jax.tree.leaves_with_path(with_remat)
+    assert len(leaves) == 1 + 8 * 10 + 3
+    for (path, a), b in zip(leaves, jax.tree.leaves(without)):
+        np.testing.assert_array_equal(a, b, err_msg=jax.tree_util.keystr(
+            path))
+
+
 def test_the_layout_is_stated_once_at_build():
     from bigdl_tpu.observability import trace
     trace.clear()
